@@ -41,8 +41,9 @@ from mmlspark_tpu.core.table import DataTable
 from mmlspark_tpu.observe import (MetricData, get_logger, pipeline_timing,
                                   profile, run_telemetry, stage_timing)
 
-# persistent XLA compilation cache (MMLSPARK_TPU_COMPILATION_CACHE): wired
-# before any model compiles so warm restarts skip recompiles entirely
+# persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR, else the
+# fixed in-checkout directory): wired before any model compiles so warm
+# restarts skip recompiles entirely
 from mmlspark_tpu.config import setup_compilation_cache as _setup_cc
 
 _setup_cc()

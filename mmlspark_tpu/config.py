@@ -160,35 +160,29 @@ TELEMETRY_DIR = register(
         "stream + run_summary.json land here when the block passes no "
         "dir. Unset + no explicit dir: in-memory ring only, no files.")
 
-COMPILATION_CACHE = register(
-    "MMLSPARK_TPU_COMPILATION_CACHE", default=None,
-    doc="Directory for JAX's persistent XLA compilation cache; when set, "
-        "warm restarts (resume-after-preemption, repeated bench runs) load "
-        "compiled executables from disk instead of re-lowering "
-        "(docs/performance.md). Unset: in-memory jit cache only.")
+# the persistent XLA compilation cache when JAX_COMPILATION_CACHE_DIR does
+# not place it: one fixed directory beside the package (the cache key
+# includes the path, so a directory that moves never hits)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
 
 
-def setup_compilation_cache() -> Any:
-    """Point JAX's persistent compilation cache at the configured directory.
+def setup_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Called at package import (mmlspark_tpu/__init__.py) and safe to call
-    again after `set('MMLSPARK_TPU_COMPILATION_CACHE', ...)`.  Returns the
-    effective directory, or None when the knob is unset or this JAX build
-    has no persistent-cache support (older builds: silently skipped — the
-    cache is an optimization, never a requirement).
+    Called at package import (mmlspark_tpu/__init__.py).  The directory is
+    placed from outside: where `JAX_COMPILATION_CACHE_DIR` is set, JAX's own
+    handling of it stands and nothing here names a directory; where it is
+    not, the cache lives at `CHECKOUT_CACHE_DIR`.  Warm restarts
+    (resume-after-preemption, a second run of the same command) then load
+    compiled executables from disk instead of re-compiling.
     """
-    path = COMPILATION_CACHE.current()
-    if not path:
-        return None
-    path = os.path.abspath(os.path.expanduser(str(path)))
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache every executable: the default thresholds skip sub-second
-        # compiles, but warm-restart wins here come precisely from the many
-        # small per-shape programs the scoring/training loops accumulate
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        return None
-    return path
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    # cache every executable: the default thresholds skip sub-second
+    # compiles, but warm-restart wins here come precisely from the many
+    # small per-shape programs the scoring/training loops accumulate
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir

@@ -56,10 +56,7 @@ def run_lbfgs(loss_fn, init_params, max_iter: int, tol: float):
         _, state = carry
         count = optax.tree_utils.tree_get(state, "count")
         grad = optax.tree_utils.tree_get(state, "grad")
-        # tree_norm arrived in optax 0.2.4; tree_l2_norm is the older name
-        norm_fn = getattr(optax.tree_utils, "tree_norm",
-                          optax.tree_utils.tree_l2_norm)
-        err = norm_fn(grad)
+        err = optax.tree_utils.tree_norm(grad)
         return (count == 0) | ((count < max_iter) & (err >= tol))
 
     final_params, _ = jax.lax.while_loop(cont, step,

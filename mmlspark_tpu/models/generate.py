@@ -2436,6 +2436,7 @@ class TextGenerator(Transformer):
     def set_bundle(self, bundle: "ModelBundle") -> "TextGenerator":
         self._bundle = bundle
         self._compiled.clear()
+        self._device_vars = {}
         return self
 
     def set_draft_bundle(self, bundle) -> "TextGenerator":

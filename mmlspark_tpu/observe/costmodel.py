@@ -70,8 +70,6 @@ def extract_cost(compiled) -> Optional[dict]:
     None when the backend provides no cost model (never raises)."""
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         flops = cost.get("flops")
         byts = cost.get("bytes accessed")
         if not flops and not byts:
@@ -221,11 +219,8 @@ def program_summary(costs: dict, times: dict,
 
 
 def device_peaks() -> tuple:
-    """(peak_flops, peak_hbm_bw) of the default device, either None when
-    unknown — one lazy import point for the summary/exposition callers."""
-    try:
-        from mmlspark_tpu.utils.perf import (device_peak_flops,
-                                             device_peak_hbm_bw)
-        return device_peak_flops(), device_peak_hbm_bw()
-    except Exception:
-        return None, None
+    """(peak_flops, peak_hbm_bw) of the default device, both None on the
+    CPU — one lazy import point for the summary/exposition callers."""
+    from mmlspark_tpu.utils.perf import (device_peak_flops,
+                                         device_peak_hbm_bw)
+    return device_peak_flops(), device_peak_hbm_bw()

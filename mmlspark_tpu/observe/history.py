@@ -60,7 +60,7 @@ MAD_K = 4.0             # noise widening: k * 1.4826 * MAD / |median|
 
 # verdict directions by field-name shape; fields matching neither are
 # tracked in the store but get no verdict (attribution fields like
-# stage_*_s and link_* ride bench lines without being quality claims)
+# stage_*_s ride bench lines without being quality claims)
 _HIGHER = ("value", "mfu", "device_mfu", "accuracy", "agreement",
            "hbm_bw_util")
 _HIGHER_SUFFIX = ("_per_sec", "_per_chip", "_speedup", "_agreement",

@@ -28,11 +28,11 @@ belongs to head i // D), and the softmax weights are expanded back through
 its transpose.  Scores and statistics live in a 128-lane tile (one lane
 per head, padded with NEG_INF), so H <= 128.
 
-Off TPU, for window shapes that don't tile the blocks, or inside a
+On the CPU, for windows with no sublane-aligned divisor, or inside a
 shard_map manual region, the wrapper falls back to the reference — the
-engine's CPU tier-1 path exercises exactly that checked fallback, while
-parity tests drive the kernel itself through the interpreter
-(`interpret=True`).
+engine's CPU tier-1 path exercises exactly that path, while parity tests
+drive the kernel itself (through the interpreter on the CPU, compiled on
+a TPU).
 """
 
 from __future__ import annotations
@@ -261,12 +261,80 @@ def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
     return out.reshape(b, h, d)
 
 
+# Default K/V block: the decode engine's cache-chunk granularity
+# (models/generate.DEFAULT_CACHE_CHUNK), so every window the engine opens
+# at its default chunk — odd multiples of 128 included (384, 640, 1152 …)
+# — tiles with ONE block shape.  A fully-masked block is an exact no-op in
+# the online-softmax fold, so with one block shape a row's result does
+# not depend on how wide a window its batch neighbours forced.
+DEFAULT_BLOCK_K = 128
+
+
+def _fit_block_k(window: int, block_k: int, sublane: int) -> Optional[int]:
+    """The largest K/V block <= `block_k` that tiles `window` and whose
+    row count is a multiple of the cache dtype's sublane tile; None when
+    the window has no such divisor."""
+    for cand in range(min(block_k, window) // sublane * sublane, 0,
+                      -sublane):
+        if window % cand == 0:
+            return cand
+    return None
+
+
+def _plan(q, k_cache, k_scale, v_scale, block_k: int, interpret: bool):
+    """(block_k, None) when the fused kernel can read this window, else
+    (None, reason) — the one fallback ladder both wrappers share."""
+    h = q.shape[1]
+    l = k_cache.shape[1]
+    if _in_manual_region(q):
+        return None, ("shard_map manual region (the partitioner owns "
+                      "placement)")
+    if (k_scale is None) != (v_scale is None):
+        return None, "mixed quantization (k_scale xor v_scale)"
+    if h > _STATS_LANES:
+        return None, f"n_heads {h} exceeds the {_STATS_LANES}-lane stats tile"
+    # mosaic sublane tiles: (8, 128) f32 / (16, 128) bf16 / (32, 128) int8
+    # — the K/V block's sublane dim is block_k (the interpreter takes any)
+    sub = 1 if interpret else {jnp.int8.dtype: 32, jnp.bfloat16.dtype: 16
+                               }.get(k_cache.dtype, 8)
+    fit = _fit_block_k(l, block_k, sub)
+    if fit is None:
+        return None, (f"window {l} has no divisor <= block_k {block_k} "
+                      f"that is a multiple of the {k_cache.dtype} sublane "
+                      f"tile ({sub}); round the window")
+    return fit, None
+
+
+def _read(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
+          block_k: int, interpret: Optional[bool], emit_stats: bool):
+    """The dispatch both public wrappers share: the CPU's quiet reference
+    path, the fallback ladder, else the kernel."""
+    reference = (single_query_attention_stats if emit_stats
+                 else single_query_attention)
+    b, _, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+    if interpret is None:
+        if _auto_interpret():
+            # the CPU: the reference is the intended path (quiet)
+            return reference(q, k_cache, v_cache, visible, scale, k_scale,
+                             v_scale)
+        interpret = False
+    fit, reason = _plan(q, k_cache, k_scale, v_scale, block_k, interpret)
+    if reason is not None:
+        _warn_reference_fallback(reason, b, k_cache.shape[1], block_k,
+                                 interpret)
+        return reference(q, k_cache, v_cache, visible, scale, k_scale,
+                         v_scale)
+    return _fused_forward(q, k_cache, v_cache, visible, scale, k_scale,
+                          v_scale, fit, interpret, emit_stats=emit_stats)
+
+
 def fused_single_query_attention(q: jax.Array, k_cache: jax.Array,
                                  v_cache: jax.Array, visible: jax.Array,
                                  scale: Optional[float] = None,
                                  k_scale: Optional[jax.Array] = None,
                                  v_scale: Optional[jax.Array] = None,
-                                 *, block_k: int = 256,
+                                 *, block_k: int = DEFAULT_BLOCK_K,
                                  interpret: Optional[bool] = None
                                  ) -> jax.Array:
     """`single_query_attention` with a fused Pallas cache read on TPU.
@@ -278,49 +346,19 @@ def fused_single_query_attention(q: jax.Array, k_cache: jax.Array,
     pins the parity per dtype, and scripts/lint.py requires that registry
     entry for any `pallas_call` site in ops/.
 
-    `interpret=None` resolves by platform: real TPU compiles the kernel,
-    anything else takes the reference path (the interpreter inside a
-    decode scan would be pure overhead — tier-1 CPU runs cover the
-    fallback).  `interpret=True` forces the kernel through the Pallas
-    interpreter — the parity tests' mode.  Shapes that don't tile
-    (window % block_k, sublane-tile violations on real TPU, H > 128,
-    shard_map manual regions) fall back with a deduped warning.
+    `block_k` is an upper bound: the kernel streams the window in the
+    largest block under it that tiles the window (`_fit_block_k`).
+    `interpret=None` resolves by platform: an accelerator compiles the
+    kernel, the CPU takes the reference path (the interpreter inside a
+    decode scan would be pure overhead — tier-1 CPU runs cover that
+    path).  `interpret=True` forces the kernel through the Pallas
+    interpreter — the parity tests' mode on the CPU.  Windows with no
+    sublane-aligned divisor, H > 128 and shard_map manual regions fall
+    back to the reference with a deduped warning, recorded in
+    `_warned_fallbacks`.
     """
-    b, h, d = q.shape
-    l = k_cache.shape[1]
-    scale_ = scale if scale is not None else d ** -0.5
-    block_k = min(block_k, l)
-    if interpret is None:
-        if _auto_interpret():
-            # no real TPU: the reference is the intended path (quiet)
-            return single_query_attention(q, k_cache, v_cache, visible,
-                                          scale_, k_scale, v_scale)
-        interpret = False
-
-    reason = None
-    if _in_manual_region(q):
-        reason = "shard_map manual region (the partitioner owns placement)"
-    elif (k_scale is None) != (v_scale is None):
-        reason = "mixed quantization (k_scale xor v_scale)"
-    elif h > _STATS_LANES:
-        reason = f"n_heads {h} exceeds the {_STATS_LANES}-lane stats tile"
-    elif l % block_k:
-        reason = (f"window {l} does not tile block_k {block_k} (round the "
-                  "window to a block multiple or shrink block_k)")
-    elif not interpret:
-        # mosaic sublane tiles: (8, 128) f32 / (16, 128) bf16 / (32, 128)
-        # int8 — the K/V block's sublane dim is block_k
-        sub = {jnp.int8.dtype: 32, jnp.bfloat16.dtype: 16}.get(
-            k_cache.dtype, 8)
-        if block_k % sub:
-            reason = (f"block_k {block_k} is not a multiple of the "
-                      f"{k_cache.dtype} sublane tile ({sub})")
-    if reason is not None:
-        _warn_reference_fallback(reason, b, l, block_k, interpret)
-        return single_query_attention(q, k_cache, v_cache, visible, scale_,
-                                      k_scale, v_scale)
-    return _fused_forward(q, k_cache, v_cache, visible, scale_, k_scale,
-                          v_scale, block_k, interpret)
+    return _read(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
+                 block_k, interpret, emit_stats=False)
 
 
 def fused_single_query_attention_stats(q: jax.Array, k_cache: jax.Array,
@@ -329,7 +367,7 @@ def fused_single_query_attention_stats(q: jax.Array, k_cache: jax.Array,
                                        scale: Optional[float] = None,
                                        k_scale: Optional[jax.Array] = None,
                                        v_scale: Optional[jax.Array] = None,
-                                       *, block_k: int = 256,
+                                       *, block_k: int = DEFAULT_BLOCK_K,
                                        interpret: Optional[bool] = None):
     """`single_query_attention_stats` with the fused cache read on TPU.
 
@@ -342,39 +380,8 @@ def fused_single_query_attention_stats(q: jax.Array, k_cache: jax.Array,
     l == 0, the merge identity.  Fallback ladder matches the normalized
     wrapper exactly, landing on the XLA-composed reference stats.
     """
-    b, h, d = q.shape
-    l = k_cache.shape[1]
-    scale_ = scale if scale is not None else d ** -0.5
-    block_k = min(block_k, l)
-    if interpret is None:
-        if _auto_interpret():
-            return single_query_attention_stats(q, k_cache, v_cache,
-                                                visible, scale_, k_scale,
-                                                v_scale)
-        interpret = False
-
-    reason = None
-    if _in_manual_region(q):
-        reason = "shard_map manual region (the partitioner owns placement)"
-    elif (k_scale is None) != (v_scale is None):
-        reason = "mixed quantization (k_scale xor v_scale)"
-    elif h > _STATS_LANES:
-        reason = f"n_heads {h} exceeds the {_STATS_LANES}-lane stats tile"
-    elif l % block_k:
-        reason = (f"window {l} does not tile block_k {block_k} (round the "
-                  "window to a block multiple or shrink block_k)")
-    elif not interpret:
-        sub = {jnp.int8.dtype: 32, jnp.bfloat16.dtype: 16}.get(
-            k_cache.dtype, 8)
-        if block_k % sub:
-            reason = (f"block_k {block_k} is not a multiple of the "
-                      f"{k_cache.dtype} sublane tile ({sub})")
-    if reason is not None:
-        _warn_reference_fallback(reason, b, l, block_k, interpret)
-        return single_query_attention_stats(q, k_cache, v_cache, visible,
-                                            scale_, k_scale, v_scale)
-    return _fused_forward(q, k_cache, v_cache, visible, scale_, k_scale,
-                          v_scale, block_k, interpret, emit_stats=True)
+    return _read(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
+                 block_k, interpret, emit_stats=True)
 
 
 __all__ = ["fused_single_query_attention",

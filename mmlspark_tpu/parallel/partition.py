@@ -322,7 +322,7 @@ def use_mesh(mesh: Optional[Mesh]):
     Wrapped around jit DISPATCH sites (Trainer step, TPUModel apply,
     DecodeEngine segments): tracing happens inside the first call, so the
     hints bake this mesh into that mesh's compiled program.  None is a
-    no-op context (hints fall back to any ambient `with mesh:` scope).
+    no-op context (hints fall back to any ambient `jax.set_mesh` scope).
     """
     if mesh is None:
         yield None
@@ -337,20 +337,15 @@ def use_mesh(mesh: Optional[Mesh]):
         stack.pop()
 
 
-def active_mesh() -> Optional[Mesh]:
+def active_mesh():
     """The mesh shard_constraint hints currently target: the innermost
-    use_mesh scope, else jax's ambient `with mesh:` context, else None."""
+    use_mesh scope, else jax's ambient `jax.set_mesh` scope (seen as its
+    AbstractMesh — the only form readable while tracing), else None."""
     stack = getattr(_local, "mesh_stack", None)
     if stack:
         return stack[-1]
-    try:
-        from jax.interpreters import pxla
-        env_mesh = pxla.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
+    ambient = jax.sharding.get_abstract_mesh()
+    return None if ambient.empty else ambient
 
 
 def shard_constraint(x: Any, spec: P) -> Any:
@@ -358,9 +353,10 @@ def shard_constraint(x: Any, spec: P) -> Any:
 
     The ONE sanctioned constraint call site (scripts/lint.py): forwards
     state where a value should live, and the mesh in scope decides what
-    that means.  No active mesh, a mesh lacking the named axes, or a
-    shape the spec cannot tile -> the value passes through untouched, so
-    the same module code runs on a laptop CPU and a dp x mp slice.
+    that means.  No active mesh, a mesh lacking the named axes, a
+    shard_map region that holds them manually, or a shape the spec cannot
+    tile -> the value passes through untouched, so the same module code
+    runs on a laptop CPU and a dp x mp slice.
     """
     mesh = active_mesh()
     if mesh is None:
@@ -368,13 +364,12 @@ def shard_constraint(x: Any, spec: P) -> Any:
     axes = _axes_of(spec)
     if not axes or not axes.issubset(set(mesh.axis_names)):
         return x
+    if axes & set(jax.sharding.get_abstract_mesh().manual_axes):
+        return x  # inside shard_map the region's specs own these axes
     s = compatible_spec(spec, np.shape(x), mesh)
     if len(s) == 0 and len(spec) != 0:
         return x  # demoted: the hint cannot tile this shape on this mesh
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, s))
-    except Exception:
-        return x  # a hint must never take down a forward it only advises
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, s))
 
 
 def expert_constraint(x: Any, axis: str) -> Any:

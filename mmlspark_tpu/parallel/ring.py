@@ -27,31 +27,19 @@ from mmlspark_tpu.ops.attention import attention, ring_attention, ulysses_attent
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
 from mmlspark_tpu.parallel.partition import named_sharding
 
-try:  # jax >= 0.8 top-level API; the experimental path is deprecated
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-
 def _shard_map(fn, *, mesh, in_specs, out_specs):
     """`shard_map` with replication checking off — the repo-wide seam.
 
     The replication checker has no rule for `checkpoint_name` (the remat
     tag the seq-parallel LM forward emits) or `pallas_call` (the flash
-    kernel ring_flash rotates) on the pinned jax build, so every sharded
-    region here runs unchecked: out_specs state the replication facts the
-    checker would otherwise verify.  The kwarg spelling moved across jax
-    versions (`check_rep` -> `check_vma`), so probe newest-first and fall
-    through to a bare call on builds that dropped the knob entirely.
+    kernel ring_flash rotates), so every sharded region here runs
+    unchecked: out_specs state the replication facts the checker would
+    otherwise verify.  With checking off, array types inside the region
+    carry no varying-manual-axes either (ops/flash_attention
+    `_in_manual_region` reads False there).
     """
-    for kwarg in ("check_vma", "check_rep"):
-        try:
-            return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **{kwarg: False})
-        except TypeError:
-            continue
-    return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def seq_parallel_attention(mesh: Mesh, q, k, v, causal: bool = False,

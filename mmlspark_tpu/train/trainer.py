@@ -125,9 +125,9 @@ def build_optimizer(cfg: TrainerConfig, total_steps: int,
     base rate and may be a TRACED scalar — the population trainer
     (train/sweep.py) passes each sweep member's rate through `vmap`, so
     one compiled step trains N members at N different learning rates.
-    The chain structure is identical either way, which is what makes a
-    vmapped member's update arithmetic byte-compatible with a plain
-    Trainer fit at the same rate."""
+    The chain structure is identical either way, so a vmapped member's
+    update arithmetic matches a plain Trainer fit at the same rate to
+    float32 rounding (two XLA programs; tests/test_sweep.py)."""
     base = cfg.learning_rate if learning_rate is None else learning_rate
     if cfg.lr_schedule == "constant":
         lr = base

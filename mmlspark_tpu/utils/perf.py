@@ -45,30 +45,34 @@ _PEAK_HBM_BPS: list[tuple[str, float]] = [
 ]
 
 
-def device_peak_hbm_bw(device: Optional[Any] = None) -> Optional[float]:
-    """HBM peak bytes/sec for `device` (default: first device); None if
-    unknown (CPU / unrecognized kinds) — callers should then omit
-    bandwidth-utilization fields rather than fabricate them."""
+def _peak(table: list, device: Optional[Any], what: str) -> Optional[float]:
+    """`table`'s entry for `device` (default: first device).  None on the
+    CPU platform, which has no peak worth a utilization; an accelerator
+    whose kind is not in the table is an error, not a dropped field."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, bw in _PEAK_HBM_BPS:
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind.lower()
+    for key, value in table:
         if key in kind:
-            return bw
-    return None
+            return value
+    raise ValueError(
+        f"no {what} peak recorded for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to utils/perf.py with its "
+        "source")
+
+
+def device_peak_hbm_bw(device: Optional[Any] = None) -> Optional[float]:
+    """HBM peak bytes/sec for `device` (default: first device); None on
+    the CPU — callers then omit bandwidth-utilization fields."""
+    return _peak(_PEAK_HBM_BPS, device, "HBM bandwidth")
 
 
 def device_peak_flops(device: Optional[Any] = None) -> Optional[float]:
-    """bf16 peak FLOP/s for `device` (default: first device); None if unknown
-    (CPU / unrecognized kinds) — callers should then omit MFU rather than
-    fabricate it."""
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, peak in _PEAK_BF16:
-        if key in kind:
-            return peak
-    return None
+    """bf16 peak FLOP/s for `device` (default: first device); None on the
+    CPU — callers then omit MFU."""
+    return _peak(_PEAK_BF16, device, "bf16 FLOP/s")
 
 
 def forward_flops(bundle, input_shape: tuple, dtype=np.float32) -> Optional[float]:
@@ -88,8 +92,6 @@ def forward_flops(bundle, input_shape: tuple, dtype=np.float32) -> Optional[floa
         compiled = jax.jit(fwd).lower(
             var_shapes, jax.ShapeDtypeStruct(input_shape, dtype)).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         flops = cost.get("flops")
         return float(flops) if flops else None
     except Exception:

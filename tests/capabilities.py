@@ -1,18 +1,12 @@
 """Session-cached environment capability probes (conftest's
 `requires_env` marker).
 
-A handful of tier-1 tests exercise constructs this image's jax build (or
-its process environment) cannot run: multiprocess CPU collectives, the
-`jax.lax.pcast` varying-cast, and the pip-installed package.  Before this
-fixture they ERRORED at setup — a known-broken wall of tracebacks that
-buried real regressions.  Each probe here answers "can this environment
-run the construct at all" once per session (lru_cache), so the tests SKIP
-with an explicit, actionable reason instead.
-
-(The former `shard_map_checkpoint_name` / `shard_map_pallas` probes are
-retired: parallel/ring.py's `_shard_map` compat wrapper now degrades to
-`check_rep=False` on builds without those replication rules, so the
-seq-parallel tests run everywhere instead of skipping.)
+A handful of tier-1 tests exercise constructs this process environment
+cannot always run: multiprocess CPU collectives, a model-parallel mesh,
+the pip-installed package, data-service worker subprocesses.  Each probe
+here answers "can this environment run the construct at all" once per
+session (lru_cache), so those tests SKIP with an explicit, actionable
+reason instead of erroring at setup.
 
 Probes are deliberately minimal — the smallest program that trips the
 same missing capability the real test would, never the workload itself —
@@ -47,17 +41,6 @@ def probe(name: str) -> tuple:
     except Exception as e:  # a probe must never take the suite down
         return False, f"probe raised {type(e).__name__}: {e}"
     return (reason is None), (reason or "")
-
-
-def _probe_lax_pcast():
-    """parallel/pipeline.py marks its shard_map scan carry stage-varying
-    via `jax.lax.pcast`; older jax builds don't ship it."""
-    import jax
-    if not hasattr(jax.lax, "pcast"):
-        return ("jax.lax.pcast unavailable in this jax build (the "
-                "pipeline-parallel scan carry needs the varying cast)")
-    return None
-
 
 
 def _probe_mp2():
@@ -193,7 +176,6 @@ def _probe_data_service_workers():
 
 
 _PROBES = {
-    "lax_pcast": _probe_lax_pcast,
     "mp2": _probe_mp2,
     "multiprocess_collectives": _probe_multiprocess_collectives,
     "package_installed": _probe_package_installed,

@@ -23,6 +23,12 @@ else:
 
 import jax  # noqa: F401  (backend must initialize after the pinning above)
 
+# tier-1 runs without the persistent compilation cache the package turns on
+# (config.setup_compilation_cache): a cold cache costs the suite ~30 s of
+# writes against its 870 s budget (measured, PR 21), and a warm one would
+# make a test's outcome depend on what an earlier run left in the checkout
+jax.config.update("jax_enable_compilation_cache", False)
+
 import numpy as np
 import pytest
 

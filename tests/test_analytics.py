@@ -457,7 +457,6 @@ def test_history_direction_inference():
     assert direction("telemetry_overhead") == -1
     assert direction("int8_device_speedup") == 1
     assert direction("stage_host_s") is None      # attribution, not quality
-    assert direction("link_h2d_MBps") is None     # weather, not code
 
 
 def test_history_quiet_across_identical_runs(tmp_path):

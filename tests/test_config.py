@@ -1,6 +1,8 @@
 """Config registry (reference Configuration.scala:18-51 +
 tools/config.sh:53-60 defvar framework)."""
 
+import os
+
 import pytest
 
 from mmlspark_tpu import config
@@ -70,24 +72,35 @@ def test_every_env_read_goes_through_registry():
 def test_prefetch_vars_registered():
     import mmlspark_tpu.parallel.prefetch  # noqa: F401  (registers on import)
     names = {d["name"] for d in config.describe()}
-    assert {"MMLSPARK_TPU_PREFETCH_DEPTH", "MMLSPARK_TPU_PREFETCH_WORKERS",
-            "MMLSPARK_TPU_COMPILATION_CACHE"} <= names
+    assert {"MMLSPARK_TPU_PREFETCH_DEPTH",
+            "MMLSPARK_TPU_PREFETCH_WORKERS"} <= names
     assert config.get("MMLSPARK_TPU_PREFETCH_DEPTH") == 8
 
 
-def test_compilation_cache_wiring(tmp_path):
-    """setup_compilation_cache points JAX's persistent XLA cache at the
-    configured directory (warm restarts skip recompiles); unset = no-op."""
+def test_compilation_cache_wiring(tmp_path, monkeypatch):
+    """The persistent XLA cache is placed from outside: with
+    JAX_COMPILATION_CACHE_DIR set the program names no directory (JAX's
+    own handling stands); unset, it is the fixed in-checkout path."""
     import jax
 
     prev = jax.config.jax_compilation_cache_dir
-    assert config.setup_compilation_cache() is None  # unset: untouched
-    cache_dir = str(tmp_path / "xla-cache")
-    config.set("MMLSPARK_TPU_COMPILATION_CACHE", cache_dir)
+    updates = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        updates.append(name)
+        real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
     try:
-        effective = config.setup_compilation_cache()
-        assert effective == cache_dir
-        assert jax.config.jax_compilation_cache_dir == cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert config.setup_compilation_cache() == prev
+        assert "jax_compilation_cache_dir" not in updates
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.dirname(os.path.dirname(config.__file__))
+        fixed = os.path.join(checkout, ".jax_cache")
+        assert config.setup_compilation_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
     finally:
-        config.set("MMLSPARK_TPU_COMPILATION_CACHE", None)
-        jax.config.update("jax_compilation_cache_dir", prev)
+        real_update("jax_compilation_cache_dir", prev)

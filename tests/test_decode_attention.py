@@ -1,8 +1,9 @@
 """Fused single-query attention vs the reference cache read
 (ops/decode_attention.py vs ops/attention.single_query_attention).
 
-Runs the kernel through the Pallas interpreter on CPU (`interpret=True`);
-on a real TPU the same cases compile it.  This file is the registered
+Runs the kernel through the Pallas interpreter on the CPU; on a TPU
+(`MMLSPARK_TPU_TEST_PLATFORM=tpu`) the same cases compile it with Mosaic
+(`INTERPRET` below).  This file is the registered
 parity suite for the module's `pallas_call` site (scripts/lint.py's
 pallas-parity registry)."""
 
@@ -12,10 +13,12 @@ import numpy as np
 import pytest
 
 from mmlspark_tpu.ops.attention import single_query_attention
-from mmlspark_tpu.ops.decode_attention import fused_single_query_attention
+from mmlspark_tpu.ops.decode_attention import (_fit_block_k,
+                                               fused_single_query_attention)
 from mmlspark_tpu.quant.quantize import quantize_kv
 
-ON_TPU = "tpu" in getattr(jax.devices()[0], "device_kind", "").lower()
+ON_TPU = jax.devices()[0].platform == "tpu"
+INTERPRET = not ON_TPU
 TOL = dict(rtol=1e-2, atol=1e-2) if ON_TPU else dict(rtol=2e-5, atol=2e-5)
 
 
@@ -43,7 +46,7 @@ def _assert_parity(q, k, v, visible, k_scale=None, v_scale=None,
                                  v_scale=v_scale)
     got = fused_single_query_attention(q, k, v, visible, k_scale=k_scale,
                                        v_scale=v_scale, block_k=block_k,
-                                       interpret=True)
+                                       interpret=INTERPRET)
     assert got.dtype == jnp.float32 and got.shape == ref.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **tol)
 
@@ -107,24 +110,34 @@ def test_scale_override():
     q, k, v, visible = _case(seed=6)
     ref = single_query_attention(q, k, v, visible, scale=0.25)
     got = fused_single_query_attention(q, k, v, visible, scale=0.25,
-                                       interpret=True, block_k=64)
+                                       interpret=INTERPRET, block_k=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **TOL)
 
 
-def test_non_tiling_window_falls_back():
-    """A window that doesn't tile block_k must agree exactly with the
-    reference (it IS the reference, via the checked fallback)."""
+def test_non_tiling_window_takes_a_smaller_block():
+    """A window block_k does not divide is read in the largest block under
+    block_k that does (96 = 2 x 48), not handed to the reference."""
     q, k, v, visible = _case(l=96, seed=7)
-    ref = single_query_attention(q, k, v, visible)
-    got = fused_single_query_attention(q, k, v, visible, block_k=64,
-                                       interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
+    _assert_parity(q, k, v, visible, block_k=64)
 
 
-def test_auto_interpret_off_tpu_is_reference():
-    """interpret=None on a non-TPU host resolves to the reference path —
-    the tier-1 fallback the engine's decode step relies on."""
+def test_fit_block_k():
+    """Every window the decode engine opens at its default chunk tiles
+    the default block, odd multiples of 128 included; a window with no
+    sublane-aligned divisor has no fit (the wrapper then falls back,
+    loudly)."""
+    for window in (128, 256, 384, 640, 1152):
+        assert _fit_block_k(window, 128, 16) == 128
+        assert _fit_block_k(window, 128, 32) == 128
+    assert _fit_block_k(384, 256, 32) == 192
+    assert _fit_block_k(96, 64, 8) == 48
+    assert _fit_block_k(48, 128, 16) == 48
+    assert _fit_block_k(48, 128, 32) is None
+
+
+def test_auto_interpret_on_cpu_is_reference():
+    """interpret=None on the CPU resolves to the reference path — what
+    the engine's decode step runs in tier-1."""
     if ON_TPU:
         pytest.skip("auto mode compiles the kernel on TPU")
     q, k, v, visible = _case(seed=8)
@@ -198,10 +211,10 @@ def test_fused_stats_merge_matches_whole_window():
         fused_single_query_attention_stats)
     q, k, v, visible = _case(seed=11)
     ref = fused_single_query_attention(q, k, v, visible, block_k=64,
-                                       interpret=True)
+                                       interpret=INTERPRET)
     got = _merge_halves(
         lambda *a, **kw: fused_single_query_attention_stats(
-            *a, block_k=32, interpret=True, **kw),
+            *a, block_k=32, interpret=INTERPRET, **kw),
         q, k, v, visible)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **TOL)
 
@@ -215,7 +228,7 @@ def test_fused_stats_fully_masked_shard_is_identity():
     q, k, v, visible = _case(seed=12)
     masked = jnp.zeros_like(visible)
     acc, m, lsum = fused_single_query_attention_stats(
-        q, k, v, masked, block_k=64, interpret=True)
+        q, k, v, masked, block_k=64, interpret=INTERPRET)
     assert float(jnp.max(jnp.abs(acc))) == 0.0
     assert float(jnp.max(lsum)) == 0.0
     assert bool(jnp.all(m <= -1e30))

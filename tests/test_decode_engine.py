@@ -307,3 +307,26 @@ def test_engine_over_mesh_matches_single_device(lm_bundle):
             table)["out"]
         for a, b in zip(single, meshed):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_set_bundle_under_mesh_drops_placed_weights(lm, lm_bundle):
+    """Swapping the bundle of a meshed TextGenerator must re-place the
+    weights: the per-mesh device copy is keyed by mesh only, so a stale
+    entry would keep generating from the previous bundle."""
+    from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    other = ModelBundle.init(lm[0], (1, 8), seed=1)
+    rows = np.empty(2, object)
+    rows[0] = np.arange(4, dtype=np.int32)
+    rows[1] = np.arange(6, dtype=np.int32) + 2
+    table = DataTable({"prompt": rows})
+    want = TextGenerator(other, inputCol="prompt", outputCol="out",
+                         maxNewTokens=5).transform(table)["out"]
+    gen = TextGenerator(lm_bundle, inputCol="prompt", outputCol="out",
+                        maxNewTokens=5).set_mesh(make_mesh(MeshSpec(data=8)))
+    before = gen.transform(table)["out"]
+    after = gen.set_bundle(other).transform(table)["out"]
+    assert any(not np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(before, after))
+    for a, b in zip(want, after):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

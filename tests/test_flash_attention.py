@@ -1,7 +1,7 @@
 """Pallas flash attention vs the dense reference (ops/flash_attention.py).
 
 Runs in pallas interpreter mode on the CPU mesh; on a real TPU the same
-tests compile the kernel (interpret auto-detects the device kind)."""
+tests compile the kernel (interpret resolves by platform)."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +14,7 @@ from mmlspark_tpu.ops.flash_attention import flash_attention
 # On real TPU the MXU's default-precision f32 matmul rounds differently in
 # the blocked kernel vs the dense einsum (~1e-3 absolute); in interpreter
 # mode (CPU suite) both paths are exact f32.
-ON_TPU = "tpu" in getattr(jax.devices()[0], "device_kind", "").lower()
+ON_TPU = jax.devices()[0].platform == "tpu"
 TOL = dict(rtol=1e-2, atol=1e-2) if ON_TPU else dict(rtol=2e-5, atol=2e-5)
 
 

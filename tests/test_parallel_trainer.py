@@ -48,7 +48,6 @@ def pp_trainer_run(tmp_path_factory):
 
 
 @pytest.mark.budget(180)
-@pytest.mark.requires_env("lax_pcast")
 def test_pp_fit_produces_loadable_transformer_bundle(pp_trainer_run):
     trainer, bundle, _, _ = pp_trainer_run
     assert bundle.architecture == "TransformerLM"
@@ -60,7 +59,6 @@ def test_pp_fit_produces_loadable_transformer_bundle(pp_trainer_run):
     assert logits.shape == (4, 12, 32)
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_pp_bundle_matches_pipeline_forward(pp_trainer_run):
     """Converter parity: the sequential TransformerLM forward of the
     emitted bundle equals the pipelined forward of the live state."""
@@ -75,7 +73,6 @@ def test_pp_bundle_matches_pipeline_forward(pp_trainer_run):
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_pp_stage_weights_sharded_in_state(pp_trainer_run):
     trainer, _, _, _ = pp_trainer_run
     leaf = jax.tree_util.tree_leaves(trainer._last_state.params["blocks"])[0]
@@ -83,7 +80,6 @@ def test_pp_stage_weights_sharded_in_state(pp_trainer_run):
     assert trainer._last_state.params["head"].sharding.is_fully_replicated
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_pp_checkpoint_restore_roundtrip(pp_trainer_run):
     trainer, _, ckpt, _ = pp_trainer_run
     assert os.path.exists(os.path.join(ckpt, "checkpoint.msgpack"))
@@ -95,7 +91,6 @@ def test_pp_checkpoint_restore_roundtrip(pp_trainer_run):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_pp_bundle_scores_through_tpumodel(pp_trainer_run):
     _, bundle, _, mesh = pp_trainer_run
     scorer = TPUModel(bundle, inputCol="tokens", outputCol="scores",
@@ -105,7 +100,6 @@ def test_pp_bundle_scores_through_tpumodel(pp_trainer_run):
     assert np.isfinite(scored["scores"]).all()
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_pp_warm_start_from_bundle(pp_trainer_run):
     """Fine-tuning a pipeline run from its own bundle resumes the step
     count and converts the flax variables back into the stacked tree."""

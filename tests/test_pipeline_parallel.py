@@ -34,7 +34,6 @@ def setup(pp_mesh):
     return params, jax.device_put(tokens, batch_sharding(pp_mesh))
 
 
-@pytest.mark.requires_env("lax_pcast")
 @pytest.mark.parametrize("n_micro", [1, 2, 4])
 def test_pipeline_matches_sequential(setup, pp_mesh, n_micro):
     """Every microbatch count must reproduce the sequential stack bit-for-
@@ -120,7 +119,6 @@ def test_bubble_fraction():
     assert count_pipeline_bubble(8, 1) == 0.0
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_multilayer_stage_matches_sequential(pp_mesh):
     """L_local > 1: eight layers over four stages, so the scan over a
     stage's STACKED local layers (two per stage) actually runs — the
@@ -138,7 +136,6 @@ def test_multilayer_stage_matches_sequential(pp_mesh):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.requires_env("lax_pcast")
 def test_pipeline_bf16_matches_sequential(pp_mesh):
     """PP x bf16: the schedule must be numerics-preserving in the compute
     dtype the real workloads use (params stay f32; block compute bf16)."""

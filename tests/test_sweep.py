@@ -1,14 +1,15 @@
 """Population training (train/sweep.py): the vmapped hyperparameter sweep.
 
 Pins the contracts the auto-ML surface rides on: a vmapped member's
-update arithmetic is byte-identical to a plain Trainer fit from the same
-init; member curves are independent of the population size (fold_in init
+update arithmetic matches a plain Trainer fit from the same init to
+float32 rounding; member curves are independent of the population size (fold_in init
 keys); the halving mask freezes culled members exactly; the winner
 unstacks into an ordinary bundle that round-trips through
 save_bundle/TPUModel; and a mid-sweep population checkpoint resumes to
 the uninterrupted run's final state.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -37,7 +38,6 @@ def _data(n=96, d=8, classes=3, seed=0):
 
 
 def _tree_equal(a, b):
-    import jax
     la = jax.tree_util.tree_leaves(a)
     lb = jax.tree_util.tree_leaves(b)
     assert len(la) == len(lb)
@@ -45,11 +45,14 @@ def _tree_equal(a, b):
                for u, v in zip(la, lb))
 
 
-def test_population_n1_byte_identical_to_plain_trainer():
+def test_population_n1_matches_plain_trainer():
     """One vmapped member IS a plain Trainer fit: warm-starting the
     sequential trainer from the member's fold_in init, every parameter
-    byte matches after the full run (same data order, same optax chain,
-    the learning rate merely riding in as a vmapped scalar)."""
+    agrees to float32 rounding after the full run (same data order, same
+    optax chain, the learning rate merely riding in as a vmapped scalar).
+    Not byte equality: the vmapped and the plain step are two XLA
+    programs, and jaxlib 0.9's CPU backend orders their float ops
+    differently (measured: <= 6e-8 absolute after 3 Adam epochs)."""
     cfg = _cfg()
     x, y = _data()
     pt = PopulationTrainer(cfg, 1)
@@ -59,7 +62,12 @@ def test_population_n1_byte_identical_to_plain_trainer():
 
     seq = Trainer(cfg)
     bundle = seq.fit_arrays(x, y, initial_bundle=init)
-    assert _tree_equal(pop_params, bundle.variables["params"])
+    got = jax.tree_util.tree_leaves(pop_params)
+    want = jax.tree_util.tree_leaves(bundle.variables["params"])
+    assert len(got) == len(want)
+    for u, v in zip(got, want):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(v),
+                                   rtol=0, atol=1e-6)
 
 
 def test_member_curve_independent_of_population_size():

@@ -23,9 +23,12 @@ overlapped phases each report their full cost: totals are thread-seconds,
 not wall, and under a healthy pipeline their sum EXCEEDS wall time —
 that excess is exactly the overlap the prefetcher buys.
 
-Zero-cost when inactive (the `stage_timing` pattern): hot loops call
+Near-free when inactive (the `stage_timing` pattern): hot loops call
 `active_timings()` once per pass and skip span bookkeeping entirely when
-no `pipeline_timing()` block is active.  Worker threads never see the
+no `pipeline_timing()` block is active; what stays is `span_on`'s
+`jax.profiler.TraceAnnotation` (`mmlspark_tpu.<stage>`), which puts every
+span on the profiler's clock when a profiler session is on and records
+nothing when none is.  Worker threads never see the
 consumer's contextvars, so collectors are captured ONCE on the consumer
 thread and passed explicitly into staging closures via `span_on`.
 """
@@ -37,6 +40,8 @@ import contextvars
 import threading
 import time
 from typing import Iterator, Optional
+
+from mmlspark_tpu.observe.profiler import SPAN_PREFIX, annotate
 
 STAGES = ("host", "transfer", "compute", "drain")
 # generation phases (models/generate.py DecodeEngine); reported by
@@ -135,9 +140,12 @@ def monotonic() -> float:
 
 @contextlib.contextmanager
 def span_on(timings: Optional[PipelineTimings], stage: str) -> Iterator[None]:
-    """Span against a captured collector; no-op (and near-free) for None."""
-    if timings is None:
-        yield
-        return
-    with timings.span(stage):
-        yield
+    """Span against a captured collector, and `mmlspark_tpu.<stage>` in a
+    running profiler session's host plane (observe/profiler.annotate) —
+    also for None, where nothing else is recorded."""
+    with annotate(SPAN_PREFIX + stage):
+        if timings is None:
+            yield
+        else:
+            with timings.span(stage):
+                yield

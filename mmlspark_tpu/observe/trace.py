@@ -29,9 +29,12 @@ worker threads never inherit contextvars — hot loops capture
 and pass both into staging closures, recording worker-side spans with
 `tracer.span(name, parent=handle)` explicitly.
 
-Zero-cost when inactive (the `active_timings()` pattern): `trace_span` /
-`trace_event` read one contextvar and return immediately when no tracer
-is active, so instrumented hot loops pay a single None-check per pass.
+Near-free when inactive (the `active_timings()` pattern): `trace_span` /
+`trace_event` read one contextvar and record nothing when no tracer is
+active.  `trace_span` and `span_on_tracer` also enter a
+`jax.profiler.TraceAnnotation` (`mmlspark_tpu.<name>`), tracer or none,
+so a `jax.profiler` session shows the same spans beside the device's
+ops; with no session on that is a TraceMe that records nothing.
 """
 
 from __future__ import annotations
@@ -44,9 +47,10 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Iterator, Optional
+from typing import Iterator, Optional
 
 from mmlspark_tpu import config
+from mmlspark_tpu.observe.profiler import SPAN_PREFIX, annotate
 
 DEFAULT_RING = 4096  # completed records kept in memory (the JSONL sink,
 # when configured, has already persisted everything that scrolls off)
@@ -289,19 +293,21 @@ def tracing(tracer: Tracer) -> Iterator[Tracer]:
 @contextlib.contextmanager
 def trace_span(name: str, cat: str = "span", **attrs) -> Iterator[Optional[Span]]:
     """Ambient span: parents under the enclosing trace_span on this
-    thread, yields the open Span (or None, near-free, when no tracer is
-    active — the hot-loop fast path)."""
-    tracer = _tracer_var.get()
-    if tracer is None:
-        yield None
-        return
-    sp = tracer.span(name, parent=_span_var.get(), cat=cat, **attrs)
-    token = _span_var.set(sp.span_id)
-    try:
-        with sp:
-            yield sp
-    finally:
-        _span_var.reset(token)
+    thread, yields the open Span (or None when no tracer is active — the
+    hot-loop fast path).  Either way the block is `mmlspark_tpu.<name>`
+    in a running profiler session (observe/profiler.annotate)."""
+    with annotate(SPAN_PREFIX + name):
+        tracer = _tracer_var.get()
+        if tracer is None:
+            yield None
+            return
+        sp = tracer.span(name, parent=_span_var.get(), cat=cat, **attrs)
+        token = _span_var.set(sp.span_id)
+        try:
+            with sp:
+                yield sp
+        finally:
+            _span_var.reset(token)
 
 
 def trace_event(name: str, cat: str = "event", **attrs) -> Optional[dict]:
@@ -324,14 +330,19 @@ def span_scope(span_id: Optional[int]) -> Iterator[None]:
         _span_var.reset(token)
 
 
+@contextlib.contextmanager
 def span_on_tracer(tracer: Optional[Tracer], name: str,
                    parent: Optional[int] = None, cat: str = "span",
-                   **attrs) -> Any:
-    """Span against a captured tracer handle; no-op context for None —
-    the worker-thread counterpart of spans.span_on."""
-    if tracer is None:
-        return contextlib.nullcontext()
-    return tracer.span(name, parent=parent, cat=cat, **attrs)
+                   **attrs) -> Iterator[Optional[Span]]:
+    """Span against a captured tracer handle (yields None for None) — the
+    worker-thread counterpart of spans.span_on, and like it
+    `mmlspark_tpu.<name>` in a running profiler session either way."""
+    with annotate(SPAN_PREFIX + name):
+        if tracer is None:
+            yield None
+        else:
+            with tracer.span(name, parent=parent, cat=cat, **attrs) as sp:
+                yield sp
 
 
 # -- distributed trace context (fleet-wide request tracing) -----------------

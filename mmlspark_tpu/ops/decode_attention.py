@@ -254,6 +254,7 @@ def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
             pltpu.VMEM((8, _STATS_LANES), jnp.float32),  # normalizer / head
         ],
         interpret=interpret,
+        name="decode_sqa",
     )(*args)
     if emit_stats:
         acc, m, lsum = out

@@ -163,6 +163,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((block_q, 128), jnp.float32),  # normalizer (lane-bcast)
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qoff, koff, q3, k3, v3)
     if with_lse:
         out, lse = results
@@ -325,6 +326,7 @@ def _flash_backward(q, k, v, do, lse, delta, causal, scale, block_q,
         out_shape=sds((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qoff, koff, q3, k3, v3, do3, lse2, delta2)
 
     dk3, dv3 = pl.pallas_call(
@@ -342,6 +344,7 @@ def _flash_backward(q, k, v, do, lse, delta, causal, scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qoff, koff, q3, k3, v3, do3, lse2, delta2)
 
     unshape_q = lambda a: a.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

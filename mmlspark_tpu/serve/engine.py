@@ -38,6 +38,7 @@ just `_tick` + a condition wait).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 from typing import Optional
@@ -292,6 +293,10 @@ class _Group:
 # engine lifecycle states
 CREATED, READY, DRAINING, STOPPED = "created", "ready", "draining", "stopped"
 
+# finished requests whose latency and time to first token feed the
+# percentiles of `stats()` (the newest; the counters keep the totals)
+_PERCENTILE_SAMPLES = 4096
+
 
 def _assemble_prefix_row(chunks: list) -> list:
     """Concatenate a prefix hit's per-chunk pool payloads back into one
@@ -386,8 +391,12 @@ class ServingEngine:
         self._wake = threading.Condition()
         self._next_id = 0
         self._id_lock = threading.Lock()
-        self._latencies: list[float] = []
-        self._counts: dict[str, int] = {}
+        # the newest samples only: `stats()` re-reads them on every call
+        self._latencies: collections.deque = collections.deque(
+            maxlen=_PERCENTILE_SAMPLES)
+        self._ttfts: collections.deque = collections.deque(
+            maxlen=_PERCENTILE_SAMPLES)
+        self._counts: dict[str, float] = {}
         self._counts_lock = threading.Lock()
         self._drain_deadline: Optional[float] = None
         self._thread = None            # set by lifecycle.start_engine
@@ -736,11 +745,26 @@ class ServingEngine:
         return req
 
     # -- accounting --------------------------------------------------------
-    def _count(self, name: str, n: int = 1) -> None:
+    def _count(self, name: str, n: float = 1) -> None:
+        self._count_all({name: n})
+
+    def _count_all(self, deltas: dict) -> None:
         # front-end threads (submit) and the loop thread both count;
         # the lock keeps read-modify-write updates from losing increments
         with self._counts_lock:
-            self._counts[name] = self._counts.get(name, 0) + n
+            for name, n in deltas.items():
+                self._counts[name] = self._counts.get(name, 0) + n
+
+    def _fetch(self, *arrays) -> list:
+        """Bring a program's results to the host: where the scheduler
+        thread waits for the device.  The enclosing prefill / segment
+        span's self time is then dispatch and argument upload, and this
+        child (`fetch_wait_s`) the wait."""
+        t0 = monotonic()
+        with span_on_tracer(self._tracer, "serve.fetch", cat="serve"):
+            out = [np.asarray(a) for a in arrays]
+        self._count("fetch_wait_s", monotonic() - t0)
+        return out
 
     def _record_serve(self, event: dict) -> None:
         if self._run is not None:
@@ -886,6 +910,17 @@ class ServingEngine:
         segment, harvest.  Returns True when any work was done (the loop
         idles on False).  Synchronous and sleep-free: tests drive it
         directly under a VirtualClock."""
+        t0 = monotonic()
+        # profiler-only (no tracer handle), like `serve.idle_wait`: an
+        # idle engine passes here a hundred times a second, which would
+        # scroll the run's ring of records
+        with span_on_tracer(None, "serve.tick"):
+            worked = self._pass()
+        self._count("tick_s", monotonic() - t0)
+        return worked
+
+    def _pass(self) -> bool:
+        """The pass itself; `_tick` times it."""
         if (self._guard is not None and self._guard.triggered
                 and self._state == READY):
             # SIGTERM arrived (PreemptionGuard flag): drain, never die
@@ -936,7 +971,7 @@ class ServingEngine:
             free = g.free_slots()
             if not free:
                 continue
-            reqs = self.admission.take(bucket, len(free), lane)
+            reqs = self._take(bucket, len(free), lane)
             if reqs:
                 slots = free[:len(reqs)]
                 if self._prefix is not None and lane == "primary":
@@ -967,23 +1002,43 @@ class ServingEngine:
                 del self._groups[(bucket, lane)]
         return worked
 
+    def _take(self, bucket: int, n: int, lane: str) -> list:
+        """Step 4's pull from the admission queue; the wait of each
+        request taken ends here (`joined`, `queue_wait_s`)."""
+        with span_on_tracer(self._tracer, "serve.admit", cat="serve",
+                            bucket=bucket, lane=lane):
+            reqs = self.admission.take(bucket, n, lane)
+            if reqs:
+                now = self.now()
+                self._count_all({
+                    "joined": len(reqs),
+                    "queue_wait_s": sum(now - r.arrival for r in reqs)})
+        return reqs
+
     def _cohort(self, g: _Group, reqs: list) -> tuple:
         """Pack a join cohort: padded to a power of two (capped at
-        capacity) so join batches reuse a handful of compiled shapes."""
-        k = len(reqs)
-        n = 1
-        while n < k:
-            n *= 2
-        n = min(n, g.capacity)
-        prompts = np.zeros((n, g.bucket), np.int32)
-        true_len = np.ones(n, np.int32)
-        live = np.zeros(n, bool)
-        ids = np.zeros(n, np.int32)
-        for j, req in enumerate(reqs):
-            prompts[j, :req.true_len] = req.prompt
-            true_len[j] = req.true_len
-            live[j] = True
-            ids[j] = req.id
+        capacity) so join batches reuse a handful of compiled shapes.
+        Counts the prompt tokens the prefill is for beside the positions
+        it computes (`prefill_tokens_true`, `prefill_tokens_padded`)."""
+        with span_on_tracer(self._tracer, "serve.admit", cat="serve",
+                            bucket=g.bucket, joins=len(reqs)):
+            k = len(reqs)
+            n = 1
+            while n < k:
+                n *= 2
+            n = min(n, g.capacity)
+            prompts = np.zeros((n, g.bucket), np.int32)
+            true_len = np.ones(n, np.int32)
+            live = np.zeros(n, bool)
+            ids = np.zeros(n, np.int32)
+            for j, req in enumerate(reqs):
+                prompts[j, :req.true_len] = req.prompt
+                true_len[j] = req.true_len
+                live[j] = True
+                ids[j] = req.id
+            self._count_all({
+                "prefill_tokens_true": int(true_len[live].sum()),
+                "prefill_tokens_padded": n * g.bucket})
         return prompts, true_len, live, ids
 
     def _join(self, g: _Group, lane: str, reqs: list, slots: list) -> None:
@@ -997,8 +1052,10 @@ class ServingEngine:
                             joins=len(reqs), lane=lane):
             tok, done, caches = eng.serve_prefill(
                 variables, prompts, true_len, live, self._row_keys(ids))
-            tok_h = np.asarray(tok)
-        self.estimator.observe_prefill(g.bucket, monotonic() - t0)
+            [tok_h] = self._fetch(tok)
+        elapsed = monotonic() - t0
+        self._count("prefill_s", elapsed)
+        self.estimator.observe_prefill(g.bucket, elapsed)
         self._splice(g, lane, reqs, slots, list(range(len(reqs))),
                      tok_h, caches, prompts)
 
@@ -1050,6 +1107,7 @@ class ServingEngine:
         prompts[0, :req.true_len] = req.prompt
         true_len = np.asarray([req.true_len], np.int32)
         ids = np.asarray([req.id], np.int32)
+        self._count_resume_tokens(g, req, matched)
         t0 = monotonic()
         try:
             with span_on_tracer(self._tracer, "serve.prefill_resume",
@@ -1060,12 +1118,21 @@ class ServingEngine:
                     variables, prompts, true_len, matched,
                     _assemble_prefix_row(hit.rows), np.ones(1, bool),
                     self._row_keys(ids))
-                tok_h = np.asarray(tok)
-            self.estimator.observe_prefill(g.bucket, monotonic() - t0)
+                [tok_h] = self._fetch(tok)
+            elapsed = monotonic() - t0
+            self._count("prefill_s", elapsed)
+            self.estimator.observe_prefill(g.bucket, elapsed)
             self._splice(g, lane, [req], [slot], [0], tok_h, caches,
                          prompts)
         finally:
             self._prefix.release(hit)
+
+    def _count_resume_tokens(self, g: _Group, req: Request,
+                             matched: int) -> None:
+        # a resumed row prefills the suffix past its donor prefix only
+        self._count_all({
+            "prefill_tokens_true": int(req.true_len) - matched,
+            "prefill_tokens_padded": g.bucket - matched})
 
     def _start_chunked_resume(self, g: _Group, lane: str, req: Request,
                               slot: int, hit) -> None:
@@ -1078,6 +1145,7 @@ class ServingEngine:
         matched = hit.n_tokens
         prompts = np.zeros((1, g.bucket), np.int32)
         prompts[0, :req.true_len] = req.prompt
+        self._count_resume_tokens(g, req, matched)
         g.reserved.add(slot)
         state = eng.serve_resume_init(_assemble_prefix_row(hit.rows),
                                       g.bucket)
@@ -1126,7 +1194,9 @@ class ServingEngine:
             job["state"] = eng.serve_prefill_chunk(
                 variables, job["prompts"], job["true_len"], job["index"],
                 job["state"])
-        job["elapsed"] += monotonic() - t0
+        elapsed = monotonic() - t0
+        self._count("prefill_s", elapsed)
+        job["elapsed"] += elapsed
         if self._run is not None:
             rec = {"event": "prefill_chunk", "bucket": g.bucket,
                    "lane": lane, "index": job["index"],
@@ -1145,8 +1215,10 @@ class ServingEngine:
         t0 = monotonic()
         tok, done, caches = eng.serve_prefill_finish(
             job["state"], job["live"], self._row_keys(job["ids"]))
-        tok_h = np.asarray(tok)
-        job["elapsed"] += monotonic() - t0
+        [tok_h] = self._fetch(tok)
+        elapsed = monotonic() - t0
+        self._count("prefill_s", elapsed)
+        job["elapsed"] += elapsed
         self.estimator.observe_prefill(g.bucket, job["elapsed"])
         # requests whose deadline passed while their prompt was still
         # chunking: finish as timeouts, splice only the survivors
@@ -1176,55 +1248,58 @@ class ServingEngine:
         resident slot, and each engine request ends `handoff` — the
         router's fleet request stays open until a decode replica splices
         the shipped rows and finishes the decode attempt."""
-        eng = self._engines[lane]
-        if self.role == "prefill" and self.handoff_export is not None:
-            now = self.now()
-            self.handoff_export(bucket=g.bucket, lane=lane, reqs=reqs,
-                                src=src, tok_h=tok_h, caches=caches)
-            for req in reqs:
-                self._count("handoffs")
-                trace_event("serve.handoff_out", cat="serve",
-                            request=req.id, bucket=g.bucket, lane=lane,
+        with span_on_tracer(self._tracer, "serve.splice", cat="serve",
+                            bucket=g.bucket, lane=lane, joins=len(reqs)):
+            eng = self._engines[lane]
+            if self.role == "prefill" and self.handoff_export is not None:
+                now = self.now()
+                self.handoff_export(bucket=g.bucket, lane=lane, reqs=reqs,
+                                    src=src, tok_h=tok_h, caches=caches)
+                for req in reqs:
+                    self._count("handoffs")
+                    trace_event("serve.handoff_out", cat="serve",
+                                request=req.id, bucket=g.bucket, lane=lane,
+                                **self._trace_fields(req))
+                    req.finish(HANDOFF, now)
+                return
+            if g.caches is None:
+                g.caches = self._empty_caches(eng.module, g.capacity,
+                                              g.bucket,
+                                              kind=eng.cache_dtype)
+            g.caches = DecodeEngine.merge_cache_rows(
+                g.caches, caches, slots, src, mesh=eng.mesh)
+            if eng.spec_tokens:
+                dc = eng.serve_draft_prefill(self._draft_vars, prompts)
+                if g.draft_caches is None:
+                    g.draft_caches = self._empty_caches(
+                        eng.draft_module, g.capacity, g.bucket)
+                g.draft_caches = DecodeEngine.merge_cache_rows(
+                    g.draft_caches, dc, slots, src, mesh=eng.mesh)
+            for j, (req, slot) in zip(src, zip(reqs, slots)):
+                g.rows[slot] = req
+                g.tok[slot] = tok_h[j]
+                g.true_len[slot] = req.true_len
+                g.budget[slot] = req.max_new_tokens
+                g.t_row[slot] = 0
+                g.row_ids[slot] = req.id
+                g.done[slot] = False
+                trace_event("serve.join", cat="serve", request=req.id,
+                            bucket=g.bucket, slot=slot, lane=lane,
                             **self._trace_fields(req))
-                req.finish(HANDOFF, now)
-            return
-        if g.caches is None:
-            g.caches = self._empty_caches(eng.module, g.capacity,
-                                          g.bucket,
-                                          kind=eng.cache_dtype)
-        g.caches = DecodeEngine.merge_cache_rows(
-            g.caches, caches, slots, src, mesh=eng.mesh)
-        if eng.spec_tokens:
-            dc = eng.serve_draft_prefill(self._draft_vars, prompts)
-            if g.draft_caches is None:
-                g.draft_caches = self._empty_caches(
-                    eng.draft_module, g.capacity, g.bucket)
-            g.draft_caches = DecodeEngine.merge_cache_rows(
-                g.draft_caches, dc, slots, src, mesh=eng.mesh)
-        for j, (req, slot) in zip(src, zip(reqs, slots)):
-            g.rows[slot] = req
-            g.tok[slot] = tok_h[j]
-            g.true_len[slot] = req.true_len
-            g.budget[slot] = req.max_new_tokens
-            g.t_row[slot] = 0
-            g.row_ids[slot] = req.id
-            g.done[slot] = False
-            trace_event("serve.join", cat="serve", request=req.id,
-                        bucket=g.bucket, slot=slot, lane=lane,
-                        **self._trace_fields(req))
-            self._record_serve({"event": "join", "request": req.id,
-                                "bucket": g.bucket, "slot": slot,
-                                "lane": lane, **self._trace_fields(req)})
-            if self._run is not None:
+                self._record_serve({"event": "join", "request": req.id,
+                                    "bucket": g.bucket, "slot": slot,
+                                    "lane": lane, **self._trace_fields(req)})
                 # attempt-level TTFT: arrival at THIS engine to its first
                 # emitted token (the fleet-level TTFT, arrival at the
                 # router to the decode-tier splice, lands in handoff.py)
-                self._run.observe_hist("serve.ttft_s",
-                                       self.now() - req.arrival)
-            self._emit(g, slot, [int(tok_h[j])])
-        if self._prefix is not None and lane == "primary":
-            self._insert_prefix_rows(reqs, src, caches)
-            self._gauge_prefix()
+                ttft = self.now() - req.arrival
+                self._ttfts.append(ttft)
+                if self._run is not None:
+                    self._run.observe_hist("serve.ttft_s", ttft)
+                self._emit(g, slot, [int(tok_h[j])])
+            if self._prefix is not None and lane == "primary":
+                self._insert_prefix_rows(reqs, src, caches)
+                self._gauge_prefix()
 
     def _insert_prefix_rows(self, reqs: list, src: list, caches) -> None:
         """Pool each freshly spliced request's prompt-prefix slots: the
@@ -1365,6 +1440,9 @@ class ServingEngine:
         live = g.live_slots()
         max_t = int(g.t_row[live].max()) if live else 0
         window = eng.serve_window(g.bucket, max_t, seg)
+        # the segment reads the whole cache width for every slot, whatever
+        # the masks (a resident cache never shrinks: `serve_step`)
+        read = max(window, int(g.caches[0][0].shape[1]))
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.segment", cat="serve",
                             bucket=g.bucket, lane=lane, seg_len=seg,
@@ -1374,9 +1452,7 @@ class ServingEngine:
                 variables, g.caches, np.asarray(g.tok),
                 np.asarray(g.done), g.true_len, g.budget, g.bucket,
                 g.t_row, self._group_keys(g), seg, window)
-            toks_h = np.asarray(toks)
-            tok_h = np.asarray(tok)
-            done_h = np.asarray(done)
+            toks_h, tok_h, done_h = self._fetch(toks, tok, done)
         elapsed = monotonic() - t0
         self.estimator.observe_step(g.bucket, elapsed / seg)
         self._record_serve({"event": "segment", "bucket": g.bucket,
@@ -1388,12 +1464,30 @@ class ServingEngine:
         g.caches = caches
         g.tok = tok_h.astype(np.int32)
         g.done = done_h.astype(bool)
-        for i in live:
-            if g.rows[i] is None:
-                continue
-            self._emit(g, i, toks_h[i].tolist())
-            if g.rows[i] is not None:
-                g.t_row[i] += seg
+        # a row is live for the steps whose tokens it keeps: one that
+        # stops or spends its budget inside the segment is frozen for
+        # the rest.  At its s-th step it sees its prompt and
+        # t_row + s generated slots
+        steps_live = keys_live = 0
+        with span_on_tracer(self._tracer, "serve.emit", cat="serve",
+                            bucket=g.bucket, rows=len(live)):
+            for i in live:
+                req = g.rows[i]
+                if req is None:
+                    continue
+                had = len(req.tokens)
+                seen = int(g.true_len[i] + g.t_row[i])
+                self._emit(g, i, toks_h[i].tolist())
+                kept = len(req.tokens) - had
+                steps_live += kept
+                keys_live += kept * seen + kept * (kept + 1) // 2
+                if g.rows[i] is not None:
+                    g.t_row[i] += seg
+        self._count_all({
+            "slot_steps_live": steps_live,
+            "slot_steps_capacity": g.capacity * seg,
+            "decode_keys_live": keys_live,
+            "decode_keys_read": g.capacity * seg * read})
         if self._run is not None:
             self._run.gauge("serve.queue_depth", self.admission.pending())
             self._run.gauge("serve.in_flight", self.in_flight())
@@ -1421,11 +1515,8 @@ class ServingEngine:
                 np.asarray(g.tok), np.asarray(g.done), g.true_len,
                 g.budget, g.bucket, g.t_row, g.spec_rounds,
                 self._group_keys(g), window)
-            toks_h = np.asarray(toks)
-            counts_h = np.asarray(counts)
-            tok_h = np.asarray(tok)
-            done_h = np.asarray(done)
-            accepted_h = np.asarray(accepted)
+            toks_h, counts_h, tok_h, done_h, accepted_h = self._fetch(
+                toks, counts, tok, done, accepted)
         elapsed = monotonic() - t0
         g.spec_rounds += 1
         emitted = int(counts_h[live].sum())
@@ -1480,14 +1571,17 @@ class ServingEngine:
                 self._finish_drain()
                 return
             if not worked:
-                with self._wake:
+                with span_on_tracer(None, "serve.idle_wait"), self._wake:
                     self._wake.wait(timeout=0.01)
 
     # -- stats -------------------------------------------------------------
-    def _percentile(self, q: float) -> Optional[float]:
-        if not self._latencies:
+    @staticmethod
+    def _percentile(samples, q: float) -> Optional[float]:
+        # list(): a snapshot, the loop thread appends meanwhile
+        samples = list(samples)
+        if not samples:
             return None
-        return float(np.percentile(np.asarray(self._latencies), q))
+        return float(np.percentile(np.asarray(samples), q))
 
     def stats(self) -> dict:
         """Counts + latency percentiles (seconds) + breaker state — the
@@ -1500,8 +1594,11 @@ class ServingEngine:
         if self._prefix is not None:
             out["prefix"] = self._prefix.stats()
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):
-            p = self._percentile(q)
+            p = self._percentile(self._latencies, q)
             out[f"latency_{name}_s"] = round(p, 6) if p is not None else None
+        for name, q in (("p50", 50), ("p95", 95)):
+            p = self._percentile(self._ttfts, q)
+            out[f"ttft_{name}_s"] = round(p, 6) if p is not None else None
         return out
 
     def prefix_stats(self) -> Optional[dict]:
@@ -1513,7 +1610,7 @@ class ServingEngine:
         if self._run is None:
             return
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):
-            p = self._percentile(q)
+            p = self._percentile(self._latencies, q)
             if p is not None:
                 self._run.gauge(f"serve.latency_{name}_ms", p * 1e3)
         for key in ("admitted", "shed", "ok", "timeout", "cancelled",

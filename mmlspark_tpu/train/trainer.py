@@ -736,7 +736,9 @@ class Trainer:
             if cur_epoch is None or not losses:
                 return
             n_batches = len(losses)
-            epoch_loss = float(np.sum(jax.device_get(losses)))
+            # the consumer thread's wait for the epoch's steps in flight
+            with span_on(timings, "drain"):
+                epoch_loss = float(np.sum(jax.device_get(losses)))
             rec = {"epoch": cur_epoch,
                    "loss": epoch_loss / max(n_batches, 1),
                    "wall_s": monotonic() - t0}

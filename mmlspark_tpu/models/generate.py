@@ -2430,7 +2430,7 @@ class TextGenerator(Transformer):
         self._draft_bundle = None
         self._compiled: dict = {}
         self._mesh = None
-        self._device_vars: dict = {}   # per-mesh replicated weights
+        self._device_vars: dict = {}   # placed weights by mesh (None: off-mesh)
         self._draft_device_vars: dict = {}
 
     def set_bundle(self, bundle: "ModelBundle") -> "TextGenerator":
@@ -2510,35 +2510,28 @@ class TextGenerator(Transformer):
         return self._compiled[key]
 
     def _device_variables(self):
-        """Weights placed once per mesh (the TPUModel discipline):
+        """Weights placed once per mesh (`bridge.place_weights`, the
+        TPUModel discipline): off-mesh (key None) on the default device,
         replicated on a dp-only mesh, partition-rule sharded when the
         mesh has a model axis (the bundle's own rule set when it carries
-        one, DEFAULT_RULES otherwise)."""
-        if self._mesh is None:
-            return self._bundle.variables
+        one, DEFAULT_RULES otherwise).  No jitted call is handed the
+        bundle's host tree, which it would upload anew each time."""
         if self._mesh not in self._device_vars:
-            if self._mesh.shape.get("model", 1) > 1:
-                from mmlspark_tpu.parallel.partition import (
-                    UNMATCHED_REPLICATE, shard_tree)
-                self._device_vars[self._mesh] = shard_tree(
-                    self._bundle.variables, self._mesh,
-                    self._bundle.partition_rules(),
-                    on_unmatched=UNMATCHED_REPLICATE)
-            else:
-                from mmlspark_tpu.parallel.bridge import replicate_tree
-                self._device_vars[self._mesh] = replicate_tree(
-                    self._bundle.variables, self._mesh)
+            from mmlspark_tpu.parallel.bridge import place_weights
+            self._device_vars[self._mesh] = place_weights(
+                self._bundle.variables, self._mesh,
+                self._bundle.partition_rules())
         return self._device_vars[self._mesh]
 
     def _draft_device_variables(self):
-        """Draft weights always replicate (the draft is small by design;
-        its cache rides the data axis only — DRAFT_KV_CACHE_SPEC)."""
-        if self._mesh is None:
-            return self._draft_bundle.variables
+        """Draft weights, placed once per mesh like the target's but
+        always whole on every device (the draft is small by design; its
+        cache rides the data axis only — DRAFT_KV_CACHE_SPEC)."""
         if self._mesh not in self._draft_device_vars:
-            from mmlspark_tpu.parallel.bridge import replicate_tree
-            self._draft_device_vars[self._mesh] = replicate_tree(
-                self._draft_bundle.variables, self._mesh)
+            from mmlspark_tpu.parallel.bridge import place_weights
+            self._draft_device_vars[self._mesh] = place_weights(
+                self._draft_bundle.variables, self._mesh,
+                replicate_only=True)
         return self._draft_device_vars[self._mesh]
 
     def _transform_beam(self, rows: list, out: list) -> None:

@@ -15,7 +15,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-from mmlspark_tpu.parallel.mesh import DATA_AXIS, batch_sharding, replicated
+from mmlspark_tpu.parallel.mesh import (DATA_AXIS, MODEL_AXIS, batch_sharding,
+                                        replicated)
 
 
 def pad_to_multiple(arr: np.ndarray, multiple: int,
@@ -193,6 +194,28 @@ def replicate_tree(tree: Any, mesh: Mesh) -> Any:
     """Replicate a pytree (model weights) across the mesh."""
     sharding = replicated(mesh)
     return jax.tree_util.tree_map(lambda x: jax.device_put(x, sharding), tree)
+
+
+def place_weights(variables: Any, mesh: Optional[Mesh] = None,
+                  partition_rules: Optional[Sequence] = None, *,
+                  replicate_only: bool = False) -> Any:
+    """Put a weight tree on the device(s) ONCE, for every later jitted
+    call to take as it is: off-mesh each leaf goes to the default device
+    (a plain `jax.device_put`, dtype kept; no mesh is built, so the
+    decode engine's `mesh is None` choices stand), on a data-only mesh
+    the tree is replicated, and with a model axis > 1 it is sharded by
+    `partition_rules` (None = DEFAULT_RULES; unmatched leaves replicate).
+    `replicate_only` keeps a small tree (a speculative draft) whole on
+    every device of any mesh.  A host tree handed to a jitted call
+    instead is uploaded again on every call."""
+    if mesh is None:
+        return jax.tree_util.tree_map(jax.device_put, variables)
+    if mesh.shape.get(MODEL_AXIS, 1) > 1 and not replicate_only:
+        from mmlspark_tpu.parallel.partition import (UNMATCHED_REPLICATE,
+                                                     shard_tree)
+        return shard_tree(variables, mesh, partition_rules,
+                          on_unmatched=UNMATCHED_REPLICATE)
+    return replicate_tree(variables, mesh)
 
 
 def stack_trees(trees: Sequence[Any]) -> Any:

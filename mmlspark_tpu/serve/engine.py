@@ -298,6 +298,13 @@ CREATED, READY, DRAINING, STOPPED = "created", "ready", "draining", "stopped"
 _PERCENTILE_SAMPLES = 4096
 
 
+def _device_bytes(tree) -> int:
+    """Bytes of a tree's leaves that live on a device (a host leaf
+    counts 0: it would be uploaded by every call that takes it)."""
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree)
+               if isinstance(x, jax.Array))
+
+
 def _assemble_prefix_row(chunks: list) -> list:
     """Concatenate a prefix hit's per-chunk pool payloads back into one
     cache row (slot axis 1), layer by layer — both cache layouts ride
@@ -327,9 +334,10 @@ class ServingEngine:
         self._clock = clock
         self._bundle = bundle
         self._module = bundle.module()
-        # serving over a device mesh: weights are placed once (replicated
-        # at mp=1, partition-rule sharded when the mesh has a model axis)
-        # and every DecodeEngine program traces its KV hints against it
+        # weights are placed once (_place_variables): off-mesh on the
+        # default device; over a device mesh replicated at mp=1,
+        # partition-rule sharded when the mesh has a model axis, and every
+        # DecodeEngine program traces its KV hints against it
         if mesh is not None and int(mesh.shape.get("seq", 1)) > 1:
             raise ValueError(
                 "ServingEngine does not support a seq-sharded mesh "
@@ -347,6 +355,10 @@ class ServingEngine:
                 "(zoo.truncated_draft_bundle builds one)")
         self._draft_module = (draft_bundle.module()
                               if self.cfg.spec_tokens else None)
+        # telemetry handles captured ONCE, on the constructing thread
+        # (the loop thread never sees the caller's contextvars)
+        self._run = active_run()
+        self._tracer = self._run.tracer if self._run is not None else None
         self._draft_vars = (self._place_replicated(draft_bundle)
                             if self.cfg.spec_tokens else None)
         self._engines = {"primary": self._decode_engine(self._module)}
@@ -358,7 +370,7 @@ class ServingEngine:
                     "degraded bundle must share the primary vocabulary")
             self._engines["degraded"] = self._decode_engine(deg)
             self._variables["degraded"] = self._place_variables(
-                degraded_bundle)
+                degraded_bundle, "degraded")
         self.estimator = StepTimeEstimator()
         self.breaker = MissRateBreaker(
             "serve", window=self.cfg.miss_window,
@@ -401,10 +413,6 @@ class ServingEngine:
         self._drain_deadline: Optional[float] = None
         self._thread = None            # set by lifecycle.start_engine
         self._guard = None             # PreemptionGuard, set by lifecycle
-        # telemetry handles captured ONCE, on the constructing thread
-        # (the loop thread never sees the caller's contextvars)
-        self._run = active_run()
-        self._tracer = self._run.tracer if self._run is not None else None
         self._base_key = jax.random.key(self.cfg.seed)
         # jitted so repeated folds (every join) don't re-trace the vmap;
         # compiled once per cohort size
@@ -423,29 +431,29 @@ class ServingEngine:
             draft_module=self._draft_module,
             spec_tokens=self.cfg.spec_tokens)
 
-    def _place_replicated(self, bundle):
-        """Draft weights replicate on any mesh (the draft is small; its
-        cache rides the data axis only — parallel/partition.py
-        DRAFT_KV_CACHE_SPEC)."""
-        if self._mesh is None:
-            return bundle.variables
-        from mmlspark_tpu.parallel.bridge import replicate_tree
-        return replicate_tree(bundle.variables, self._mesh)
+    def _place_variables(self, bundle, lane: str = "primary", *,
+                         replicate_only: bool = False):
+        """A lane's weights are placed ONCE, here (`bridge.place_weights`):
+        off-mesh on the default device, under a mesh replicated (dp-only)
+        or partition-rule sharded (mp >= 2; the bundle's own rules, else
+        DEFAULT_RULES).  Every jitted call is handed the placed tree, so
+        none uploads it again; it stays resident until the engine stops."""
+        from mmlspark_tpu.parallel.bridge import place_weights
+        t0 = monotonic()
+        placed = jax.block_until_ready(place_weights(
+            bundle.variables, self._mesh, bundle.partition_rules(),
+            replicate_only=replicate_only))
+        self._record_serve({"event": "weights_placed", "lane": lane,
+                            "bytes": _device_bytes(placed),
+                            "seconds": round(monotonic() - t0, 3)})
+        return placed
 
-    def _place_variables(self, bundle):
-        """One-time weight placement for a lane: host tree off-mesh,
-        replicated on a dp-only mesh, partition-rule sharded (the
-        bundle's own rules, else DEFAULT_RULES) at mp >= 2."""
-        if self._mesh is None:
-            return bundle.variables
-        if self._mesh.shape.get("model", 1) > 1:
-            from mmlspark_tpu.parallel.partition import (
-                UNMATCHED_REPLICATE, shard_tree)
-            return shard_tree(bundle.variables, self._mesh,
-                              bundle.partition_rules(),
-                              on_unmatched=UNMATCHED_REPLICATE)
-        from mmlspark_tpu.parallel.bridge import replicate_tree
-        return replicate_tree(bundle.variables, self._mesh)
+    def _place_replicated(self, bundle):
+        """Draft weights, placed once: on the default device off-mesh,
+        whole on every device of any mesh (the draft is small; its cache
+        rides the data axis only — parallel/partition.py
+        DRAFT_KV_CACHE_SPEC)."""
+        return self._place_variables(bundle, "draft", replicate_only=True)
 
     # -- lifecycle ---------------------------------------------------------
     def now(self) -> float:
@@ -616,6 +624,10 @@ class ServingEngine:
 
     def _finish_drain(self) -> None:
         self._state = STOPPED
+        # the placed weights die with the engine, not with whoever still
+        # holds the stopped object (a thread, a closure, the HTTP server)
+        self._variables = {}
+        self._draft_vars = None
         trace_event("serve.drain_end", cat="serve")
         self._record_serve({"event": "drain_end",
                             "counts": dict(self._counts)})
@@ -1591,6 +1603,8 @@ class ServingEngine:
         out["queued"] = self.admission.pending()
         out["state"] = self._state
         out["breaker_state"] = self.breaker.state
+        out["weights_device_bytes"] = _device_bytes(
+            (self._variables, self._draft_vars))   # lanes and the draft
         if self._prefix is not None:
             out["prefix"] = self._prefix.stats()
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):

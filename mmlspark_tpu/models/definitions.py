@@ -362,12 +362,15 @@ class TransformerLM(nn.Module, NodeMixin):
 # reconstructs it (the analogue of CNTK's self-describing .model files).
 # --------------------------------------------------------------------------
 
+from mmlspark_tpu.models.hybrid_lm import HybridLM  # noqa: E402
+
 MODEL_REGISTRY: dict[str, Callable[..., nn.Module]] = {
     "MLPClassifier": MLPClassifier,
     "LinearModel": LinearModel,
     "ConvNetCIFAR10": ConvNetCIFAR10,
     "ResNet": ResNet,
     "TransformerLM": TransformerLM,
+    "HybridLM": HybridLM,
 }
 
 
@@ -377,9 +380,7 @@ def build_model(name: str, config: Optional[dict] = None) -> nn.Module:
     cfg = dict(config or {})
     if isinstance(cfg.get("dtype"), str):
         cfg["dtype"] = jnp.dtype(cfg["dtype"]).type
-    if "stage_sizes" in cfg:
-        cfg["stage_sizes"] = tuple(cfg["stage_sizes"])
-    for k in ("hidden_sizes", "widths"):
+    for k in ("stage_sizes", "layer_types", "hidden_sizes", "widths"):
         if k in cfg:
             cfg[k] = tuple(cfg[k])
     return MODEL_REGISTRY[name](**cfg)

@@ -69,6 +69,7 @@ near-tie greedy choices may legitimately resolve differently.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -79,6 +80,8 @@ from jax import lax
 from mmlspark_tpu.core.params import Param
 from mmlspark_tpu.core.pipeline import Transformer
 from mmlspark_tpu.models.bundle import load_bundle, save_bundle
+from mmlspark_tpu.models.definitions import TransformerLM
+from mmlspark_tpu.models.hybrid_lm import FIXED, WINDOW, HybridDecoding, HybridLM
 from mmlspark_tpu.observe.costmodel import capture_program_cost
 from mmlspark_tpu.observe.spans import active_timings, span_on
 from mmlspark_tpu.observe.telemetry import active_run
@@ -293,14 +296,41 @@ def _seq_prefill_block(module, bp: dict, x: jax.Array, dtype,
     return x + _mlp(module, bp, h2, dtype), k, v
 
 
-def _check_generatable(module) -> None:
-    if type(module).__name__ != "TransformerLM":
+# The architectures each decode path accepts.  The full-cache per-length
+# programs (`make_generate_fn`, beam search) and the draft of a speculating
+# engine are written against TransformerLM's block; `DecodeEngine` also
+# takes a model that states its own layers' decoding (`_decoding_for`).
+_FULL_CACHE_ARCHITECTURES = (TransformerLM,)
+_ENGINE_ARCHITECTURES = (TransformerLM, HybridLM)
+
+
+def _check_generatable(module, accepted=_FULL_CACHE_ARCHITECTURES,
+                       what: str = "generate() and beam search") -> None:
+    if not isinstance(module, accepted):
         raise ValueError(
-            f"generate() decodes TransformerLM models, got "
+            f"{what} decode "
+            f"{' and '.join(a.__name__ for a in accepted)} models, got "
             f"{type(module).__name__}")
     # any attention EXECUTION strategy trains the same weights; decode
     # always attends q against the cache, so attn_impl needs no check.
     # MoE blocks decode too: _mlp re-applies the real MoEMLP module.
+
+
+def _decoding_for(module):
+    """How `DecodeEngine` decodes `module`, decided once when the engine
+    is built: None for TransformerLM (the block functions of this file),
+    else the model's own statement of its layers (`HybridDecoding`)."""
+    _check_generatable(module, _ENGINE_ARCHITECTURES, "DecodeEngine")
+    return HybridDecoding(module) if isinstance(module, HybridLM) else None
+
+
+def _kv_state(module, rows: int, window: int, hint) -> list:
+    """Zero K and V windows of a TransformerLM, one pair a layer."""
+    dh = module.d_model // module.n_heads
+    shape = (rows, window, module.n_heads, dh)
+    return [(hint(jnp.zeros(shape, module.dtype)),
+             hint(jnp.zeros(shape, module.dtype)))
+            for _ in range(module.n_layers)]
 
 
 def filter_logits(logits: jax.Array, top_k: Optional[int] = None,
@@ -353,12 +383,7 @@ def _prefill(params, prompts, module, prompt_len: int):
             f"prompts have length {prompts.shape[1]} but this compiled "
             f"decode program was built for prompt_len={prompt_len}")
     b = prompts.shape[0]
-    dh = module.d_model // module.n_heads
-    caches = [(_hint_kv(jnp.zeros((b, module.max_len, module.n_heads, dh),
-                                  module.dtype)),
-               _hint_kv(jnp.zeros((b, module.max_len, module.n_heads, dh),
-                                  module.dtype)))
-              for _ in range(module.n_layers)]
+    caches = _kv_state(module, b, module.max_len, _hint_kv)
     logits, caches = _forward_with_cache(params, prompts, caches, 0, module)
     return logits[:, -1], caches
 
@@ -950,15 +975,36 @@ def _grow_cache(cache: jax.Array, window: int) -> jax.Array:
     return jnp.pad(cache, pad)
 
 
-@jax.jit
-def _merge_cache_rows_jit(dst_caches, src_caches, di, si):
-    window = max(dst_caches[0][0].shape[1], src_caches[0][0].shape[1])
+def _grow_state(caches: list, window: int, kinds=None,
+                hint=_hint_kv) -> list:
+    """`_grow_cache` over every layer's leaves.  `kinds` names each
+    layer's state kind (None: every layer a window, TransformerLM's): a
+    FIXED layer's leaves are row-indexed only and pass through."""
+    return [layer if kinds is not None and kinds[i] == FIXED
+            else tuple(hint(_grow_cache(c, window)) for c in layer)
+            for i, layer in enumerate(caches)]
+
+
+def _state_window(caches: list, kinds=None) -> int:
+    """Slots the state's window layers hold (0: it has none)."""
+    for i, layer in enumerate(caches):
+        if kinds is None or kinds[i] == WINDOW:
+            return int(layer[0].shape[1])
+    return 0
+
+
+@functools.partial(jax.jit, static_argnames=("kinds",))
+def _merge_cache_rows_jit(dst_caches, src_caches, di, si, kinds=None):
+    window = max(_state_window(dst_caches, kinds),
+                 _state_window(src_caches, kinds))
+    dst_caches = _grow_state(dst_caches, window, kinds, lambda c: c)
+    src_caches = _grow_state(src_caches, window, kinds, lambda c: c)
     merged = []
-    for dst_layer, src_layer in zip(dst_caches, src_caches):
-        merged.append(tuple(
-            _hint_kv(_grow_cache(d, window).at[di].set(
-                _grow_cache(s, window)[si]))
-            for d, s in zip(dst_layer, src_layer)))
+    for i, (dst_layer, src_layer) in enumerate(zip(dst_caches, src_caches)):
+        hint = (_hint_kv if kinds is None or kinds[i] == WINDOW
+                else lambda c: c)
+        merged.append(tuple(hint(d.at[di].set(s[si]))
+                            for d, s in zip(dst_layer, src_layer)))
     return merged
 
 
@@ -1106,7 +1152,27 @@ class DecodeEngine:
                  min_new_tokens: int = 1,
                  prefill_chunk: Optional[int] = None,
                  draft_module=None, spec_tokens: int = 0):
-        _check_generatable(module)
+        decoding = _decoding_for(module)
+        if decoding is not None:
+            # what a model that states its own decoding does not carry
+            # over yet refuses here, by name; nothing falls back
+            name = type(module).__name__
+            if cache_dtype == "int8":
+                raise ValueError(
+                    f"cache_dtype='int8' is not supported for {name}: "
+                    "its fixed per-row state has no quantized layout")
+            if draft_module is not None or spec_tokens:
+                raise ValueError(
+                    f"speculative decoding is not supported for {name}: "
+                    "the multi-token verify forward has no per-row "
+                    "fixed-state path")
+            if mesh is not None and (
+                    int(mesh.shape.get(SEQ_AXIS, 1)) > 1
+                    or int(mesh.shape.get(MODEL_AXIS, 1)) > 1):
+                raise ValueError(
+                    f"a mesh with model>1 or seq>1 is not supported for "
+                    f"{name}: its expert stacks and state have no "
+                    "partition rules (a data-only mesh works)")
         if cache_dtype not in ("model", "int8"):
             raise ValueError(
                 f"unknown cache_dtype '{cache_dtype}' (model | int8)")
@@ -1200,6 +1266,16 @@ class DecodeEngine:
                     f"seq axis ({seq_shards}) so every prompt bucket "
                     "shards evenly")
         self.module = module
+        # each layer's state kind (a K/V window that grows by `chunk`, or
+        # a fixed per-row leaf) and the names of what the programs count
+        # on the device: asked of the model once, here
+        self._decoding = decoding
+        self.state_kinds = (decoding.state_kinds if decoding is not None
+                            else (WINDOW,) * module.n_layers)
+        self.count_names = (decoding.count_names if decoding is not None
+                            else ())
+        self.counts_out: list = []   # the last program's device counts
+        self._chunk_counts: list = []
         self.max_new_tokens = max_new_tokens
         self.stop_tokens = stop_tokens
         self.chunk = chunk
@@ -1237,20 +1313,59 @@ class DecodeEngine:
                 return is_stop(tok)
             return is_stop(tok) & (new_count >= min_new)
 
+        # The model behind the programs.  TransformerLM runs this file's
+        # block functions; another model its own (`_decoding_for`).  Each
+        # call returns `counts`, a tuple of device counters that rides
+        # with the tokens: () for TransformerLM, which counts nothing.
+        kinds = self.state_kinds
+        if decoding is None:
+            def new_state(b, w):
+                return _kv_state(module, b, w, _hint_kv)
+
+            def run_prompt(params, tokens, caches, start, true_len, live):
+                logits, caches = _forward_with_cache(params, tokens, caches,
+                                                     start, module)
+                return logits, caches, ()
+
+            def to_logits(params, last):
+                return last
+
+            def run_step(params, tok, pos, slot, caches, visible, live):
+                logits, caches = _decode_step(params, tok, pos, slot,
+                                              caches, visible, module,
+                                              cache_dtype, fused)
+                return logits, caches, ()
+
+            def run_step_rows(params, tok, pos, slots, caches, visible,
+                              live):
+                logits, caches = _decode_step_rows(
+                    params, tok, pos, slots, caches, visible, module,
+                    cache_dtype, fused)
+                return logits, caches, ()
+            no_counts = ()
+        else:
+            new_state = decoding.empty_state
+            run_prompt = decoding.run_prompt
+            to_logits = decoding.head
+            # the uniform-slot step is the per-row step with equal slots
+            run_step = run_step_rows = decoding.run_step_rows
+            no_counts = (jnp.zeros(len(self.count_names), jnp.float32),)
+
+        def add_counts(counts, new):
+            return tuple(c + n for c, n in zip(counts, new))
+
+        def grow(caches, window):
+            return _grow_state(caches, window, kinds)
+
         def prefill_impl(variables, prompts, true_len, live, row_keys):
             params = variables["params"]
             b, p = prompts.shape
             w0 = _round_up(p + 1, chunk)
-            dh = module.d_model // module.n_heads
-            caches = [(_hint_kv(jnp.zeros((b, w0, module.n_heads, dh),
-                                          module.dtype)),
-                       _hint_kv(jnp.zeros((b, w0, module.n_heads, dh),
-                                          module.dtype)))
-                      for _ in range(module.n_layers)]
-            logits, caches = _forward_with_cache(params, prompts, caches,
-                                                 0, module)
-            last = jnp.take_along_axis(
-                logits, (true_len - 1)[:, None, None], axis=1)[:, 0]
+            caches = new_state(b, w0)
+            feats, caches, counts = run_prompt(params, prompts, caches, 0,
+                                               true_len, live)
+            last = to_logits(params, jnp.take_along_axis(
+                feats, (true_len - 1)[:, None, None], axis=1)[:, 0])
             tok = sample(last, row_keys, 0)
             done = ~live | stop_gate(tok, 1)
             if cache_dtype == "int8":
@@ -1260,33 +1375,32 @@ class DecodeEngine:
                 caches = [tuple(_hint_kv(c)
                                 for c in _quantize_cache(kc, vc))
                           for kc, vc in caches]
-            return tok, done, caches
+            return (tok, done, caches) + counts
 
         def segment_impl(seg_len, window, variables, caches, tok, done,
                          true_len, bucket, t0, row_keys):
             params = variables["params"]
-            caches = [tuple(_hint_kv(_grow_cache(c, window)) for c in layer)
-                      for layer in caches]
+            caches = grow(caches, window)
             slots = jnp.arange(window)
 
             def step(carry, s_off):
-                tok, done, caches = carry
+                tok, done, caches, counts = carry
                 t = t0 + s_off
                 slot = bucket + t
                 pos = true_len + t
                 visible = ((slots[None, :] < true_len[:, None])
                            | ((slots[None, :] >= bucket)
                               & (slots[None, :] <= slot)))
-                logits, caches = _decode_step(params, tok, pos, slot,
-                                              caches, visible, module,
-                                              cache_dtype, fused)
+                logits, caches, new = run_step(params, tok, pos, slot,
+                                               caches, visible, ~done)
                 nxt = sample(logits, row_keys, t + 1)
                 nxt = jnp.where(done, tok, nxt)
-                return (nxt, done | stop_gate(nxt, t + 2), caches), tok
+                return (nxt, done | stop_gate(nxt, t + 2), caches,
+                        add_counts(counts, new)), tok
 
-            (tok, done, caches), toks = lax.scan(
-                step, (tok, done, caches), jnp.arange(seg_len))
-            return caches, toks.transpose(1, 0), tok, done
+            (tok, done, caches, counts), toks = lax.scan(
+                step, (tok, done, caches, no_counts), jnp.arange(seg_len))
+            return (caches, toks.transpose(1, 0), tok, done) + counts
 
         if seq_shards > 1:
             # SEQ-SHARDED engine: replace the prefill/segment impls with
@@ -1422,30 +1536,28 @@ class DecodeEngine:
             own cache row only and their emissions repeat the frozen
             token (the engine's per-row emit counters ignore them)."""
             params = variables["params"]
-            caches = [tuple(_hint_kv(_grow_cache(c, window)) for c in layer)
-                      for layer in caches]
+            caches = grow(caches, window)
             slots_axis = jnp.arange(window)
             max_pos = module.max_len - 1
 
             def step(carry, s_off):
-                tok, done, caches = carry
+                tok, done, caches, counts = carry
                 t = t_row + s_off                     # (B,) per-row step
                 slot = jnp.minimum(bucket + t, window - 1)
                 pos = jnp.minimum(true_len + t, max_pos)
                 visible = ((slots_axis[None, :] < true_len[:, None])
                            | ((slots_axis[None, :] >= bucket)
                               & (slots_axis[None, :] <= slot[:, None])))
-                logits, caches = _decode_step_rows(
-                    params, tok, pos, slot, caches, visible, module,
-                    cache_dtype, fused)
+                logits, caches, new = run_step_rows(
+                    params, tok, pos, slot, caches, visible, ~done)
                 nxt = row_sample(logits, row_keys, t + 1)
                 nxt = jnp.where(done, tok, nxt)
                 done = done | stop_gate(nxt, t + 2) | (t + 1 >= budget)
-                return (nxt, done, caches), nxt
+                return (nxt, done, caches, add_counts(counts, new)), nxt
 
-            (tok, done, caches), toks = lax.scan(
-                step, (tok, done, caches), jnp.arange(seg_len))
-            return caches, toks.transpose(1, 0), tok, done
+            (tok, done, caches, counts), toks = lax.scan(
+                step, (tok, done, caches, no_counts), jnp.arange(seg_len))
+            return (caches, toks.transpose(1, 0), tok, done) + counts
 
         def prefill_chunk0_impl(w0, variables, tokens, true_len):
             """First chunk of a CHUNKED prefill (offset 0): allocates the
@@ -1455,18 +1567,13 @@ class DecodeEngine:
             long prompt stops stalling running requests."""
             params = variables["params"]
             b, cl = tokens.shape
-            dh = module.d_model // module.n_heads
-            caches = [(_hint_kv(jnp.zeros((b, w0, module.n_heads, dh),
-                                          module.dtype)),
-                       _hint_kv(jnp.zeros((b, w0, module.n_heads, dh),
-                                          module.dtype)))
-                      for _ in range(module.n_layers)]
-            logits, caches = _forward_with_cache(params, tokens, caches,
-                                                 0, module)
+            caches = new_state(b, w0)
+            feats, caches, counts = run_prompt(
+                params, tokens, caches, 0, true_len, jnp.ones(b, bool))
             idx = jnp.clip(true_len - 1, 0, cl - 1)
-            last = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1)[:, 0]
-            return caches, last
+            last = to_logits(params, jnp.take_along_axis(
+                feats, idx[:, None, None], axis=1)[:, 0])
+            return (caches, last) + counts
 
         def prefill_chunk_impl(variables, tokens, caches, last, true_len,
                                c0):
@@ -1476,15 +1583,15 @@ class DecodeEngine:
             Rows whose last prompt token falls inside this chunk update
             the running last-position logits."""
             params = variables["params"]
-            cl = tokens.shape[1]
-            logits, caches = _forward_with_cache(params, tokens, caches,
-                                                 c0, module)
+            b, cl = tokens.shape
+            feats, caches, counts = run_prompt(
+                params, tokens, caches, c0, true_len, jnp.ones(b, bool))
             idx = jnp.clip(true_len - 1 - c0, 0, cl - 1)
-            cand = jnp.take_along_axis(
-                logits, idx[:, None, None], axis=1)[:, 0]
+            cand = to_logits(params, jnp.take_along_axis(
+                feats, idx[:, None, None], axis=1)[:, 0])
             here = (true_len - 1 >= c0) & (true_len - 1 < c0 + cl)
             last = jnp.where(here[:, None], cand, last)
-            return caches, last
+            return (caches, last) + counts
 
         def prefill_finish_impl(caches, last, live, row_keys):
             """Close a chunked prefill: sample the first token and (int8
@@ -1536,12 +1643,7 @@ class DecodeEngine:
             params = draft_variables["params"]
             b, p = prompts.shape
             w0 = _round_up(p + 1, chunk)
-            dh = dm.d_model // dm.n_heads
-            caches = [(_hint_draft_kv(jnp.zeros((b, w0, dm.n_heads, dh),
-                                                dm.dtype)),
-                       _hint_draft_kv(jnp.zeros((b, w0, dm.n_heads, dh),
-                                                dm.dtype)))
-                      for _ in range(dm.n_layers)]
+            caches = _kv_state(dm, b, w0, _hint_draft_kv)
             _, caches = _forward_with_cache(params, prompts, caches, 0,
                                             dm)
             return caches
@@ -1813,7 +1915,7 @@ class DecodeEngine:
         self._refuse_seq("serve_prefill")
         b, p = prompts.shape
         key = ("prefill", b, p)
-        tok, done, caches = self._prefill(
+        tok, done, caches, *self.counts_out = self._prefill(
             variables, jnp.asarray(prompts), jnp.asarray(true_len),
             jnp.asarray(live), row_keys)
         self._program(*key)
@@ -1830,19 +1932,54 @@ class DecodeEngine:
         (`serve_window`)."""
         self._refuse_seq("serve_step")
         b = int(tok.shape[0])
-        w_in = int(caches[0][0].shape[1])
+        w_in = self.state_window(caches)
         # a resident cache never shrinks: joins after long-running rows
         # completed can ask for a smaller cover than the batch already
         # holds — the segment then just attends the existing width
         window = max(int(window), w_in)
         key = ("serve_segment", b, w_in, window, seg_len)
-        out = self._serve_segment(
+        caches, toks, tok, done, *self.counts_out = self._serve_segment(
             seg_len, window, variables, caches, tok, done,
             jnp.asarray(true_len), jnp.asarray(budget, jnp.int32),
             jnp.asarray(bucket, jnp.int32),
             jnp.asarray(t_row, jnp.int32), row_keys)
         self._program(*key)
-        return out
+        return caches, toks, tok, done
+
+    def state_window(self, caches) -> int:
+        """Slots a state's window layers hold now."""
+        return _state_window(caches, self.state_kinds)
+
+    def empty_state(self, rows: int, bucket: int, draft: bool = False):
+        """Zero resident state for `rows` rows of a bucket's batch, at
+        the bucket's first window (`draft`: the draft model's, always
+        model-dtype K/V): the allocation the programs make, made for the
+        serving engine's resident batch."""
+        window = _round_up(bucket + 1, self.chunk)
+        unhinted = lambda c: c
+        if draft:
+            return _kv_state(self.draft_module, rows, window, unhinted)
+        if self._decoding is not None:
+            return self._decoding.empty_state(rows, window)
+        if self.cache_dtype == "int8":
+            # int8 payloads + f32 per-(row, slot, head) scales, matching
+            # _quantize_cache's 4-tuple
+            m = self.module
+            shape = (rows, window, m.n_heads, m.d_model // m.n_heads)
+            return [(jnp.zeros(shape, jnp.int8),
+                     jnp.zeros(shape[:3], jnp.float32),
+                     jnp.zeros(shape, jnp.int8),
+                     jnp.zeros(shape[:3], jnp.float32))
+                    for _ in range(m.n_layers)]
+        return _kv_state(self.module, rows, window, unhinted)
+
+    def state_bytes(self, caches) -> dict:
+        """Bytes a state holds in its window layers and in its fixed
+        ones."""
+        total = {WINDOW: 0, FIXED: 0}
+        for kind, layer in zip(self.state_kinds, caches):
+            total[kind] += sum(int(leaf.nbytes) for leaf in layer)
+        return total
 
     def serve_window(self, bucket: int, max_t: int, seg_len: int) -> int:
         """The chunk-rounded cache window covering a segment whose oldest
@@ -1877,15 +2014,24 @@ class DecodeEngine:
         tl = jnp.asarray(true_len)
         tokens = jnp.asarray(prompts[:, index * cl:(index + 1) * cl])
         if index == 0:
-            state = self._prefill_chunk0(w0, variables, tokens, tl)
+            out = self._prefill_chunk0(w0, variables, tokens, tl)
             self._program("prefill_chunk0", b, cl, w0)
         else:
             caches, last = state
-            state = self._prefill_chunk(variables, tokens, caches, last,
-                                        tl, jnp.asarray(index * cl,
-                                                        jnp.int32))
+            out = self._prefill_chunk(variables, tokens, caches, last, tl,
+                                      jnp.asarray(index * cl, jnp.int32))
             self._program("prefill_chunk", b, cl, w0)
-        return state
+        return self._keep_chunk_counts(out)
+
+    def _keep_chunk_counts(self, out) -> tuple:
+        """A chunk program's `(caches, last, *counts)`: the counts wait,
+        summed on the device, for the prefill's finish (whose fetch brings
+        them); the `(caches, last)` state goes on."""
+        caches, last, *counts = out
+        self._chunk_counts = ([a + b for a, b in zip(self._chunk_counts,
+                                                     counts)]
+                              if self._chunk_counts else counts)
+        return caches, last
 
     def serve_prefill_finish(self, state, live, row_keys):
         """Close a chunked serve prefill: the same (tok, done, caches)
@@ -1893,11 +2039,12 @@ class DecodeEngine:
         self._refuse_seq("serve_prefill_finish")
         caches, last = state
         b = int(last.shape[0])
-        w0 = int(caches[0][0].shape[1])
+        w0 = self.state_window(caches)
         tok, done, caches = self._prefill_finish(caches, last,
                                                  jnp.asarray(live),
                                                  row_keys)
         self._program("prefill_finish", b, w0)
+        self.counts_out, self._chunk_counts = self._chunk_counts, []
         return tok, done, caches
 
     def serve_resume_chunks(self, bucket: int, prefix_len: int) -> int:
@@ -1945,7 +2092,7 @@ class DecodeEngine:
                 f"prefix_len ({prefix_len}) must be inside the bucket "
                 f"({p})")
         caches, last = self.serve_resume_init(row_caches, p)
-        w0 = int(caches[0][0].shape[1])
+        w0 = self.state_window(caches)
         tokens = jnp.asarray(prompts[:, prefix_len:])
         state = self._prefill_chunk(
             variables, tokens, caches, last, jnp.asarray(true_len),
@@ -1992,14 +2139,16 @@ class DecodeEngine:
 
     @staticmethod
     def merge_cache_rows(dst_caches, src_caches, dst_rows, src_rows,
-                         mesh=None):
+                         mesh=None, kinds=None):
         """Splice cohort cache rows into a resident batch: row
         `src_rows[i]` of `src_caches` replaces row `dst_rows[i]` of
         `dst_caches`.  Both sides are grown to the wider window first
         (zero-pad, `_grow_cache`), so a freshly prefilled cohort joins a
         long-running batch without recompiling anything.  Works for both
         cache layouts (2-tuple model-dtype, 4-tuple int8): every leaf is
-        row-indexed on axis 0.  One jitted program per (windows, rows)
+        row-indexed on axis 0; `kinds` (an engine's `.state_kinds`) names
+        the layers whose leaves are FIXED and so are row-indexed only.
+        One jitted program per (windows, rows)
         shape class — a join is a handful of fused scatters, not a
         cascade of eager ops.  Pass `mesh` (an engine's `.mesh`) so the
         merge program's KV hints trace against it — sharded resident
@@ -2014,7 +2163,8 @@ class DecodeEngine:
         di = jnp.asarray(dst_rows, jnp.int32)
         si = jnp.asarray(src_rows, jnp.int32)
         with use_mesh(mesh):
-            return _merge_cache_rows_jit(dst_caches, src_caches, di, si)
+            return _merge_cache_rows_jit(dst_caches, src_caches, di, si,
+                                         kinds=kinds)
 
     @property
     def compiled_programs(self) -> int:
@@ -2043,18 +2193,16 @@ class DecodeEngine:
         tl = jnp.asarray(true_len)
         with trace_span("decode.prefill_chunk", cat="bucket", bucket=p,
                         batch=b, chunk=cl, index=0):
-            state = self._prefill_chunk0(w0, variables, prompts[:, :cl],
-                                         tl)
+            caches, last, *_ = self._prefill_chunk0(
+                w0, variables, prompts[:, :cl], tl)
         self._program("prefill_chunk0", b, cl, w0)
         for ci in range(1, p // cl):
-            caches, last = state
             with trace_span("decode.prefill_chunk", cat="bucket",
                             bucket=p, batch=b, chunk=cl, index=ci):
-                state = self._prefill_chunk(
+                caches, last, *_ = self._prefill_chunk(
                     variables, prompts[:, ci * cl:(ci + 1) * cl],
                     caches, last, tl, jnp.asarray(ci * cl, jnp.int32))
             self._program("prefill_chunk", b, cl, w0)
-        caches, last = state
         tok, done, caches = self._prefill_finish(
             caches, last, jnp.asarray(live), row_keys)
         self._program("prefill_finish", b, w0)
@@ -2143,7 +2291,7 @@ class DecodeEngine:
                 with span_on(timings, "prefill"), \
                         trace_span("decode.prefill", cat="bucket",
                                    bucket=p, batch=b) as psp:
-                    tok, done, caches = self._prefill(*pf_args)
+                    tok, done, caches, *_ = self._prefill(*pf_args)
                     if timings is not None:
                         jax.block_until_ready(tok)
                 self._program(*pf_key)
@@ -2199,7 +2347,8 @@ class DecodeEngine:
                                     occupancy=round(
                                         (p + t0 + seg_len) / window, 3)) \
                             as ssp:
-                        caches, toks, tok, done = self._segment(*seg_args)
+                        caches, toks, tok, done, *_ = self._segment(
+                            *seg_args)
                     self._program(*seg_key)
                     if run is not None and ssp is not None:
                         if seg_key in self._program_costs:
@@ -2651,7 +2800,7 @@ def naive_generate(module, variables, prompts, max_new_tokens: int) -> np.ndarra
     """Recompute-everything greedy decoding through the ordinary module
     forward — O(N * S^2) work, no cache.  The parity oracle for
     `generate`; never the product path."""
-    _check_generatable(module)
+    _check_generatable(module, _ENGINE_ARCHITECTURES, "naive_generate")
     toks = jnp.asarray(prompts, jnp.int32)
     for _ in range(max_new_tokens):
         logits = module.apply(variables, toks)

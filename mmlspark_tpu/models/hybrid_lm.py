@@ -1,0 +1,446 @@
+"""A decoder whose layers are a per-layer list: short-convolution and
+grouped-attention mixers over dense or routed-expert gated MLPs.
+
+`HybridLM` is the registered architecture (`build_model("HybridLM", ...)`).
+Its layers are the plain functions below, each `(params, x, ..., state
+view) -> (y, new state)`; the flax module (for `init`, `Trainer`,
+`TPUModel`) and the decode programs of `models/generate.py` (through
+`HybridDecoding`) call THESE functions, so a layer is stated once.
+
+    x0 = E[tokens]                                (no position table)
+    x  = x + mixer_i(norm(x));  x = x + ffn_i(norm(x))     per layer
+    logits = norm(x_L) @ E^T                      (head tied to E, or its own)
+
+  * `norm` is RMSNorm: x * rsqrt(mean(x^2) + eps) * g, in float32.
+  * a `conv` mixer: [B, C, z] = W_in h; u = B * z; c_t = sum_j k_j
+    u_{t-K+1+j} per channel (depthwise, causal, no activation);
+    y = W_out (C * c).  A row carries u at its last K-1 positions.
+  * a `full_attention` mixer: q, k, v projections with `n_kv_heads` <=
+    `n_heads`; RMSNorm over each head of q and k, then rotary positions
+    (half-split pairing) over the whole head; each KV head serves
+    n_heads / n_kv_heads query heads; causal softmax.  A row carries a
+    window of K and V.
+  * the first `n_dense_layers` layers have a gated MLP W2 (silu(W1 h) *
+    W3 h); every other layer has routed experts (`ops/moe.routed_experts`:
+    sigmoid scores, a selection bias, top-k, renormalised, dropless).
+
+No bias anywhere.  Parameters are float32, products run in `dtype`.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+from mmlspark_tpu.ops.moe import routed_experts
+
+NEG_INF = -1e30
+CONV, ATTENTION = "conv", "full_attention"
+WINDOW, FIXED = "window", "fixed"      # the two kinds of per-row state
+# prompt length from which a whole-prompt prefill runs the flash kernel
+# (models/generate.py keeps the same threshold for TransformerLM)
+PREFILL_FLASH_MIN = 512
+
+# what the decode programs count on the device, in this order (the keys
+# `ServingEngine.stats()` gains)
+COUNT_NAMES = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
+               "moe_load_max", "moe_load_mean")
+
+
+@dataclasses.dataclass
+class StateView:
+    """How one call reads and writes the rows' state.
+
+    `write_at`: a scalar (every row writes its segment's K/V from that
+    slot on: a prefill or a prompt chunk) or a `(B,)` vector (row r
+    writes at its own slot: a decode step).  `visible`: `(B, S, W)` bool,
+    the slots each query may read, or None for "causal over the segment
+    itself" (a whole prompt from slot 0).  `n_valid`: `(B,)`, how many of
+    the segment's tokens are the row's own (the rest is bucket padding):
+    the convolution state is taken there, at the row's true length.
+    `valid`: `(B, S)` bool, the tokens the counters count."""
+
+    write_at: Any
+    visible: Optional[jax.Array]
+    n_valid: jax.Array
+    valid: Optional[jax.Array] = None
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float, dtype) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * scale).astype(dtype)
+
+
+def rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over the whole head, half-split pairing: x
+    (B, S, H, D), positions (B, S)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[..., None, None] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
+def _dot(x: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
+    return x.astype(dtype) @ kernel.astype(dtype)
+
+
+def short_conv(p: dict, h: jax.Array, state, view: Optional[StateView],
+               dtype):
+    """The gated short convolution over normalized `h` (B, S, d); `state`
+    is the row's last K-1 columns of u, (B, K-1, d), or None (a plain
+    forward: nothing before the segment)."""
+    with jax.named_scope("short_conv"):
+        b, s, d = h.shape
+        gate_b, gate_c, z = jnp.split(_dot(h, p["conv_in"], dtype), 3, -1)
+        u = gate_b * z
+        taps = p["conv_taps"].astype(jnp.float32)           # (K, d)
+        k = taps.shape[0]
+        before = (jnp.zeros((b, k - 1, d), dtype) if state is None
+                  else state.astype(dtype))
+        ext = jnp.concatenate([before, u], axis=1)          # (B, K-1+S, d)
+        ext32 = ext.astype(jnp.float32)
+        conv = sum(taps[j] * ext32[:, j:j + s] for j in range(k))
+        y = _dot(gate_c * conv.astype(dtype), p["conv_out"], dtype)
+        if state is None:
+            return y, None
+        # the state a row keeps is u at ITS last K-1 positions: n_valid
+        # of this segment's tokens are its own, the rest pad the bucket
+        kept = jax.vmap(lambda e, n: lax.dynamic_slice_in_dim(
+            e, n, k - 1, axis=0))(ext, view.n_valid)
+        return y, kept.astype(state.dtype)
+
+
+def _row_write(cache: jax.Array, update: jax.Array, slots: jax.Array):
+    zeros = (0,) * (cache.ndim - 2)
+    return jax.vmap(lambda c, u, s: lax.dynamic_update_slice(
+        c, u, (s,) + zeros))(cache, update, slots)
+
+
+def _grouped_attention(q, k, v, visible, scale: float):
+    """q (B, S, H, D) against k, v (B, L, KV, D), H a multiple of KV; query
+    head i reads KV head i // (H / KV).  `visible` (B, S, L) or (S, L).
+    Scores and sums in float32."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    q = q.reshape(b, s, kv, h // kv, d).astype(jnp.float32)
+    scores = jnp.einsum("bskgd,blkd->bkgsl", q,
+                        k.astype(jnp.float32)) * scale
+    mask = visible[:, None, None] if visible.ndim == 3 else visible
+    probs = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+    out = jnp.einsum("bkgsl,blkd->bskgd", probs, v.astype(jnp.float32))
+    return out.reshape(b, s, h, d)
+
+
+def grouped_attention(p: dict, h: jax.Array, positions: jax.Array, state,
+                      view: Optional[StateView], *, n_heads: int,
+                      n_kv_heads: int, rope_theta: float, eps: float,
+                      dtype):
+    """Grouped-KV attention over normalized `h` (B, S, d); `state` is the
+    row's (K, V) window, each (B, W, n_kv_heads, D), or None."""
+    with jax.named_scope("attn"):
+        b, s, d = h.shape
+        dh = d // n_heads
+        q = _dot(h, p["wq"], dtype).reshape(b, s, n_heads, dh)
+        k = _dot(h, p["wk"], dtype).reshape(b, s, n_kv_heads, dh)
+        v = _dot(h, p["wv"], dtype).reshape(b, s, n_kv_heads, dh)
+        q = rotary(rms_norm(q, p["q_norm"], eps, dtype), positions,
+                   rope_theta)
+        k = rotary(rms_norm(k, p["k_norm"], eps, dtype), positions,
+                   rope_theta)
+        scale = dh ** -0.5
+        causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        if state is None:
+            o = _grouped_attention(q, k, v, causal, scale)
+            return _dot(o.reshape(b, s, d), p["wo"], dtype), None
+        k_cache, v_cache = state
+        at = view.write_at
+        if jnp.ndim(at) == 0:
+            k_cache = lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype), (0, at, 0, 0))
+            v_cache = lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype), (0, at, 0, 0))
+        else:
+            k_cache = _row_write(k_cache, k.astype(k_cache.dtype), at)
+            v_cache = _row_write(v_cache, v.astype(v_cache.dtype), at)
+        if view.visible is not None:
+            o = _grouped_attention(q, k_cache, v_cache, view.visible, scale)
+        elif s >= PREFILL_FLASH_MIN:
+            # a whole prompt from slot 0: causal attention over the
+            # segment itself, so the flash kernel never builds (S, S)
+            # scores.  It wants one KV head a query head: K and V are
+            # widened for this one read (the cache keeps n_kv_heads)
+            from mmlspark_tpu.ops.flash_attention import flash_attention
+            wide = lambda t: jnp.repeat(t, n_heads // n_kv_heads, axis=2)
+            o = flash_attention(q, wide(k), wide(v), causal=True)
+        else:
+            o = _grouped_attention(q, k, v, causal, scale)
+        return (_dot(o.reshape(b, s, d), p["wo"], dtype),
+                (k_cache, v_cache))
+
+
+def gated_mlp(p: dict, h: jax.Array, dtype) -> jax.Array:
+    return _dot(jax.nn.silu(_dot(h, p["w1"], dtype))
+                * _dot(h, p["w3"], dtype), p["w2"], dtype)
+
+
+def expert_mlp(p: dict, h: jax.Array, valid, *, top_k: int, dtype):
+    """Routed experts over (B, S, d): `(y, load (E,))`."""
+    b, s, d = h.shape
+    y, load = routed_experts(
+        h.reshape(b * s, d), p["router"], p["expert_bias"], p["w1"],
+        p["w3"], p["w2"], top_k=top_k, dtype=dtype,
+        valid=None if valid is None else valid.reshape(b * s))
+    return y.reshape(b, s, d), load
+
+
+def layer_params_shapes(module, i: int) -> dict:
+    """Names and shapes of layer i's parameters (all float32)."""
+    d = module.d_model
+    dh = d // module.n_heads
+    shapes = {"op_norm": (d,), "ffn_norm": (d,)}
+    if module.layer_types[i] == CONV:
+        shapes.update(conv_in=(d, 3 * d), conv_taps=(module.conv_kernel, d),
+                      conv_out=(d, d))
+    else:
+        kv = module.n_kv_heads * dh
+        shapes.update(wq=(d, d), wk=(d, kv), wv=(d, kv), wo=(d, d),
+                      q_norm=(dh,), k_norm=(dh,))
+    if i < module.n_dense_layers:
+        w = module.mlp_width
+        shapes.update(w1=(d, w), w3=(d, w), w2=(w, d))
+    else:
+        e, w = module.n_experts, module.expert_width
+        shapes.update(router=(d, e), expert_bias=(e,), w1=(e, d, w),
+                      w3=(e, d, w), w2=(e, w, d))
+    return shapes
+
+
+def apply_layer(module, i: int, p: dict, x: jax.Array, positions, state,
+                view: Optional[StateView]):
+    """Layer i over the residual stream x (B, S, d): `(x, new state,
+    load)`; `load` is the experts' assignment count (E,), or None for a
+    dense layer."""
+    dtype, eps = module.dtype, module.norm_eps
+    h = rms_norm(x, p["op_norm"], eps, dtype)
+    if module.layer_types[i] == CONV:
+        y, state = short_conv(p, h, None if state is None else state[0],
+                              view, dtype)
+        state = None if state is None else (state,)
+    else:
+        y, state = grouped_attention(
+            p, h, positions, state, view, n_heads=module.n_heads,
+            n_kv_heads=module.n_kv_heads, rope_theta=module.rope_theta,
+            eps=eps, dtype=dtype)
+    x = x + y
+    h = rms_norm(x, p["ffn_norm"], eps, dtype)
+    if i < module.n_dense_layers:
+        return x + gated_mlp(p, h, dtype), state, None
+    y, load = expert_mlp(p, h, None if view is None else view.valid,
+                         top_k=module.experts_per_token, dtype=dtype)
+    return x + y, state, load
+
+
+def hidden_states(module, params: dict, tokens: jax.Array, positions,
+                  state=None, view: Optional[StateView] = None):
+    """The model up to its final norm: `(x (B, S, d), new state, loads)`;
+    `state` is a list of per-layer tuples (or None: a plain forward) and
+    `loads` the expert layers' assignment counts, stacked (n, E)."""
+    x = params["embed"][tokens].astype(module.dtype)
+    new_state, loads = [], []
+    for i in range(module.n_layers):
+        x, layer_state, load = apply_layer(
+            module, i, params[f"layer{i}"], x, positions,
+            None if state is None else state[i], view)
+        new_state.append(layer_state)
+        if load is not None:
+            loads.append(load)
+    x = rms_norm(x, params["out_norm"], module.norm_eps, module.dtype)
+    loads = (jnp.stack(loads) if loads
+             else jnp.zeros((0, module.n_experts), jnp.float32))
+    return x, (None if state is None else new_state), loads
+
+
+def head(module, params: dict, x: jax.Array) -> jax.Array:
+    """Logits, float32, of normalized hidden states (..., d)."""
+    kernel = (params["embed"].T if module.tie_embeddings
+              else params["head"])
+    return _dot(x, kernel, module.dtype).astype(jnp.float32)
+
+
+def _fan_in(name: str, shape: tuple) -> int:
+    """Fan-in of a leaf for `init` (0: a scale, initialised to one).  An
+    expert stack's fan-in is one expert's; the selection bias starts at
+    zero-mean noise of its own small scale."""
+    if name.endswith("norm"):
+        return 0
+    if name == "expert_bias":
+        return 10_000
+    if name == "conv_taps":
+        return shape[0]
+    return shape[-2]
+
+
+class _Leaves(nn.Module):
+    """Declares a flat group of float32 parameters and returns them."""
+
+    shapes: Any       # ((name, shape), ...)
+
+    @nn.compact
+    def __call__(self) -> dict:
+        out = {}
+        for name, shape in self.shapes:
+            fan_in = _fan_in(name, shape)
+            init = (nn.initializers.normal(fan_in ** -0.5) if fan_in
+                    else nn.initializers.ones)
+            out[name] = self.param(name, init, shape, jnp.float32)
+        return out
+
+
+class HybridLM(nn.Module):
+    """The decoder of the module docstring.  `layer_types` lists each
+    layer's mixer (`conv` | `full_attention`); `max_len` caps the
+    positions a decode may reach (rotary positions need no table)."""
+
+    vocab_size: int = 256
+    d_model: int = 128
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    layer_types: tuple = (CONV, ATTENTION)
+    n_dense_layers: int = 1
+    mlp_width: int = 512
+    n_experts: int = 8
+    experts_per_token: int = 2
+    expert_width: int = 128
+    conv_kernel: int = 3
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    tie_embeddings: bool = True
+    max_len: int = 2048
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - {CONV, ATTENTION}
+        if unknown:
+            raise ValueError(f"unknown layer types {sorted(unknown)} "
+                             f"({CONV} | {ATTENTION})")
+        if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"d_model {self.d_model} / n_heads {self.n_heads} / "
+                f"n_kv_heads {self.n_kv_heads} do not divide")
+        super().__post_init__()
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @nn.compact
+    def __call__(self, tokens):
+        leaves = lambda shapes, name: _Leaves(tuple(shapes.items()),
+                                              name=name)()
+        top = {"embed": (self.vocab_size, self.d_model),
+               "out_norm": (self.d_model,)}
+        if not self.tie_embeddings:
+            top["head"] = (self.d_model, self.vocab_size)
+        params = {}
+        for i in range(self.n_layers):
+            params[f"layer{i}"] = leaves(layer_params_shapes(self, i),
+                                         f"layer{i}")
+        # the top-level leaves live beside the layers' groups
+        for name, shape in top.items():
+            init = (nn.initializers.ones if name == "out_norm"
+                    else nn.initializers.normal(
+                        1.0 if name == "embed" else shape[0] ** -0.5))
+            params[name] = self.param(name, init, shape, jnp.float32)
+        b, s = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+        x, _, _ = hidden_states(self, params, tokens, positions)
+        return head(self, params, x)
+
+
+class HybridDecoding:
+    """What `DecodeEngine` asks of a `HybridLM`, once, when it is built:
+    its layers' state kinds and shapes, and the three calls its programs
+    make.  Every call runs `hidden_states` above."""
+
+    count_names = COUNT_NAMES
+
+    def __init__(self, module: HybridLM):
+        self.module = module
+        self.state_kinds = tuple(
+            FIXED if kind == CONV else WINDOW for kind in module.layer_types)
+
+    def empty_state(self, rows: int, window: int) -> list:
+        """Zero state for `rows` rows: a window layer's K and V, (rows,
+        window, n_kv_heads, D) each, or a fixed layer's last K-1
+        convolution columns, (rows, K-1, d)."""
+        m = self.module
+        dh = m.d_model // m.n_heads
+        kv = (rows, window, m.n_kv_heads, dh)
+        fixed = (rows, m.conv_kernel - 1, m.d_model)
+        return [(jnp.zeros(kv, m.dtype), jnp.zeros(kv, m.dtype))
+                if kind == WINDOW else (jnp.zeros(fixed, m.dtype),)
+                for kind in self.state_kinds]
+
+    def _prompt_counts(self, loads):
+        """A prefill's counts: assignments, and each expert layer's
+        fullest expert beside the mean."""
+        return jnp.stack([loads.sum(), 0.0, 0.0, loads.max(-1).sum(),
+                          loads.mean(-1).sum()])
+
+    def _step_counts(self, loads):
+        """A decode step's counts: assignments, and the experts that got
+        one beside all that a step could touch (none where no row is
+        live)."""
+        slots = loads.size * (loads.sum() > 0)
+        return jnp.stack([loads.sum(), (loads > 0).sum().astype(jnp.float32),
+                          slots.astype(jnp.float32), 0.0, 0.0])
+
+    def run_prompt(self, params, tokens, state, start, true_len, live):
+        """A prompt segment of right-padded rows from slot `start` on
+        (0 for a whole prompt): `(normalized hidden states (B, S, d), new
+        state, counts)`.  Positions are the slots; a row's tokens past
+        its `true_len` are padding: they never enter its convolution
+        state, and causality keeps them from its true tokens."""
+        b, s = tokens.shape
+        at = start + jnp.arange(s)
+        whole = isinstance(start, int) and start == 0
+        window = next((layer[0].shape[1] for layer, kind
+                       in zip(state, self.state_kinds) if kind == WINDOW),
+                      s)
+        visible = None if whole else jnp.broadcast_to(
+            jnp.arange(window)[None, :] <= at[:, None], (b, s, window))
+        valid = (at[None, :] < true_len[:, None]) & live[:, None]
+        view = StateView(write_at=start, visible=visible,
+                         n_valid=jnp.clip(true_len - start, 0, s),
+                         valid=valid)
+        x, state, loads = hidden_states(
+            self.module, params, tokens, jnp.broadcast_to(at, (b, s)),
+            state, view)
+        return x, state, (self._prompt_counts(loads),)
+
+    def run_step_rows(self, params, tok, pos, slots, state, visible, live):
+        """One decode token a row: per-row positions `pos`, write `slots`
+        and visibility (B, W): `(logits (B, V), new state, counts)`."""
+        b = tok.shape[0]
+        slots = jnp.broadcast_to(slots, (b,))
+        view = StateView(write_at=slots, visible=visible[:, None],
+                         n_valid=jnp.ones(b, jnp.int32),
+                         valid=live[:, None])
+        x, state, loads = hidden_states(
+            self.module, params, tok[:, None], pos[:, None], state, view)
+        return (head(self.module, params, x[:, 0]), state,
+                (self._step_counts(loads),))
+
+    def head(self, params, x):
+        return head(self.module, params, x)

@@ -220,3 +220,71 @@ def expert_parallel_rules(params: dict, mesh,
         return named_sharding(mesh, P())
 
     return jax.tree_util.tree_map_with_path(rule, params)
+
+
+# --------------------------------------------------------------------------
+# Experts as deployed: dropless token-choice routing
+# --------------------------------------------------------------------------
+
+def route_tokens(x: jax.Array, router_kernel: jax.Array,
+                 router_bias: jax.Array, top_k: int):
+    """Token-choice routing with a sigmoid score: `(chosen (T, k) int32,
+    weights (T, k) float32, margin (T,) float32)`.
+
+    Scores `s = sigmoid(x W_r)` are float32 (operands and accumulation);
+    the `top_k` experts are those with the highest `s + bias` (the bias
+    enters the choice only), and a token's weights are its chosen scores
+    over their sum (+ 1e-6).  `margin` is the k-th selection score less
+    the (k+1)-th: how close the token was to another choice."""
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        ranked, order = jax.lax.top_k(
+            scores + router_bias.astype(jnp.float32), top_k + 1)
+        chosen = order[:, :top_k]
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+        return chosen, weights, ranked[:, top_k - 1] - ranked[:, top_k]
+
+
+def routed_experts(x: jax.Array, router_kernel: jax.Array,
+                   router_bias: jax.Array, w1: jax.Array, w3: jax.Array,
+                   w2: jax.Array, *, top_k: int, dtype=jnp.bfloat16,
+                   valid: Optional[jax.Array] = None):
+    """Dropless routed gated experts over `(T, d)` tokens: returns
+    `(y (T, d), load (E,) float32)`.
+
+    `y_t = sum over the token's top_k experts e of w_te * W2_e (silu(W1_e
+    x_t) * W3_e x_t)` with `route_tokens`' choice and weights; `w1`, `w3`
+    are stacked `(E, d, w)`, `w2` `(E, w, d)`.  The `T * top_k`
+    assignments are sorted by expert and each projection is ONE grouped
+    product (`jax.lax.ragged_dot`) over the sorted rows: no token is
+    dropped, nothing is padded to a capacity, and a token's output does
+    not depend on which other tokens share the call (each row meets its
+    own experts' weights only).  The same function serves a prefill's
+    thousands of tokens and a decode step's handful of rows.
+
+    `load` counts the assignments each expert received, over the tokens
+    `valid` marks (all, when None): what the serving counters read."""
+    t, d = x.shape
+    n_experts = w1.shape[0]
+    chosen, weights, _ = route_tokens(x, router_kernel, router_bias, top_k)
+    with jax.named_scope("moe.experts"):
+        flat = chosen.reshape(-1)
+        # stable: an expert's rows keep token order, so the grouping is
+        # one deterministic function of the choice
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+        rows = x.astype(dtype)[order // top_k]
+        cast = lambda w: w.astype(dtype)
+        hidden = (jax.nn.silu(jax.lax.ragged_dot(rows, cast(w1), sizes))
+                  * jax.lax.ragged_dot(rows, cast(w3), sizes))
+        out = jax.lax.ragged_dot(hidden, cast(w2), sizes)
+        # back to (token, choice) order; the k parts add up in float32
+        out = out[jnp.argsort(order)].reshape(t, top_k, d)
+        y = (out.astype(jnp.float32) * weights[..., None]).sum(1)
+    counted = jnp.ones(t, bool) if valid is None else valid
+    load = jnp.zeros(n_experts, jnp.float32).at[flat].add(
+        jnp.repeat(counted, top_k).astype(jnp.float32))
+    return y.astype(dtype), load

@@ -47,8 +47,8 @@ import jax
 import numpy as np
 
 from mmlspark_tpu import config
-from mmlspark_tpu.models.generate import (DEFAULT_CACHE_CHUNK, DecodeEngine,
-                                          _round_up)
+from mmlspark_tpu.models.generate import (DEFAULT_CACHE_CHUNK, FIXED, WINDOW,
+                                          DecodeEngine)
 from mmlspark_tpu.observe.logging import get_logger
 from mmlspark_tpu.observe.metrics import inc_counter
 from mmlspark_tpu.observe.spans import monotonic
@@ -362,6 +362,20 @@ class ServingEngine:
         self._draft_vars = (self._place_replicated(draft_bundle)
                             if self.cfg.spec_tokens else None)
         self._engines = {"primary": self._decode_engine(self._module)}
+        if FIXED in self._engines["primary"].state_kinds:
+            # a fixed per-row state cannot be cut at a prompt prefix or
+            # paged by window chunk: what rests on either refuses here
+            name = type(self._module).__name__
+            if self.cfg.prefix_cache:
+                raise ValueError(
+                    f"the prefix cache (and resume) is not supported for "
+                    f"{name}: its rows hold a fixed state beside the K/V "
+                    "window, and the pool keeps window chunks only")
+            if self.cfg.role != "colocated":
+                raise ValueError(
+                    f"tiered roles and KV handoff are not supported for "
+                    f"{name} (role={self.cfg.role!r}): the handoff pages "
+                    "window chunks only")
         self._variables = {"primary": self._place_variables(bundle)}
         if degraded_bundle is not None:
             deg = degraded_bundle.module()
@@ -547,7 +561,7 @@ class ServingEngine:
             # one merge program per (resident width, cohort width, join
             # count): splice k rows from the power-of-two cohort that a
             # k-wide join would prefill (engine._join pads the same way)
-            width = int(resident[0][0].shape[1])
+            width = eng.state_window(resident)
             if not self.cfg.warmup_joins or width in warmed_widths:
                 return
             warmed_widths.add(width)
@@ -557,7 +571,8 @@ class ServingEngine:
                     m *= 2
                 DecodeEngine.merge_cache_rows(
                     resident, cohorts[min(m, cap)],
-                    list(range(k)), list(range(k)), mesh=eng.mesh)
+                    list(range(k)), list(range(k)), mesh=eng.mesh,
+                    kinds=eng.state_kinds)
 
         budget = np.full(cap, self.cfg.max_new_tokens, np.int32)
         t_row = np.zeros(cap, np.int32)
@@ -767,15 +782,22 @@ class ServingEngine:
             for name, n in deltas.items():
                 self._counts[name] = self._counts.get(name, 0) + n
 
-    def _fetch(self, *arrays) -> list:
+    def _fetch(self, eng: DecodeEngine, *arrays) -> list:
         """Bring a program's results to the host: where the scheduler
         thread waits for the device.  The enclosing prefill / segment
         span's self time is then dispatch and argument upload, and this
-        child (`fetch_wait_s`) the wait."""
+        child (`fetch_wait_s`) the wait.  What the program counted on the
+        device (`eng.counts_out`, under `eng.count_names`; nothing for a
+        model that counts nothing) comes in the same fetch and goes to
+        the engine's counters."""
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.fetch", cat="serve"):
             out = [np.asarray(a) for a in arrays]
+            counted = [np.asarray(a) for a in eng.counts_out]
+            eng.counts_out = []          # counted once
         self._count("fetch_wait_s", monotonic() - t0)
+        for values in counted:
+            self._count_all(dict(zip(eng.count_names, values.tolist())))
         return out
 
     def _record_serve(self, event: dict) -> None:
@@ -1064,7 +1086,7 @@ class ServingEngine:
                             joins=len(reqs), lane=lane):
             tok, done, caches = eng.serve_prefill(
                 variables, prompts, true_len, live, self._row_keys(ids))
-            [tok_h] = self._fetch(tok)
+            [tok_h] = self._fetch(eng, tok)
         elapsed = monotonic() - t0
         self._count("prefill_s", elapsed)
         self.estimator.observe_prefill(g.bucket, elapsed)
@@ -1130,7 +1152,7 @@ class ServingEngine:
                     variables, prompts, true_len, matched,
                     _assemble_prefix_row(hit.rows), np.ones(1, bool),
                     self._row_keys(ids))
-                [tok_h] = self._fetch(tok)
+                [tok_h] = self._fetch(eng, tok)
             elapsed = monotonic() - t0
             self._count("prefill_s", elapsed)
             self.estimator.observe_prefill(g.bucket, elapsed)
@@ -1227,7 +1249,7 @@ class ServingEngine:
         t0 = monotonic()
         tok, done, caches = eng.serve_prefill_finish(
             job["state"], job["live"], self._row_keys(job["ids"]))
-        [tok_h] = self._fetch(tok)
+        [tok_h] = self._fetch(eng, tok)
         elapsed = monotonic() - t0
         self._count("prefill_s", elapsed)
         job["elapsed"] += elapsed
@@ -1275,16 +1297,15 @@ class ServingEngine:
                     req.finish(HANDOFF, now)
                 return
             if g.caches is None:
-                g.caches = self._empty_caches(eng.module, g.capacity,
-                                              g.bucket,
-                                              kind=eng.cache_dtype)
+                g.caches = eng.empty_state(g.capacity, g.bucket)
             g.caches = DecodeEngine.merge_cache_rows(
-                g.caches, caches, slots, src, mesh=eng.mesh)
+                g.caches, caches, slots, src, mesh=eng.mesh,
+                kinds=eng.state_kinds)
             if eng.spec_tokens:
                 dc = eng.serve_draft_prefill(self._draft_vars, prompts)
                 if g.draft_caches is None:
-                    g.draft_caches = self._empty_caches(
-                        eng.draft_module, g.capacity, g.bucket)
+                    g.draft_caches = eng.empty_state(g.capacity, g.bucket,
+                                                     draft=True)
                 g.draft_caches = DecodeEngine.merge_cache_rows(
                     g.draft_caches, dc, slots, src, mesh=eng.mesh)
             for j, (req, slot) in zip(src, zip(reqs, slots)):
@@ -1342,25 +1363,6 @@ class ServingEngine:
                 self._record_prefix({"event": "evict_refused",
                                      "request": req.id})
 
-    def _empty_caches(self, module, capacity: int, bucket: int,
-                      kind: str = "model") -> list:
-        import jax.numpy as jnp
-        dh = module.d_model // module.n_heads
-        w0 = _round_up(bucket + 1, self.cfg.cache_chunk)
-        shape = (capacity, w0, module.n_heads, dh)
-        if kind == "int8":
-            # the quantized layout: int8 payloads + f32 per-(row, slot,
-            # head) scales, matching _quantize_cache's 4-tuple
-            sshape = (capacity, w0, module.n_heads)
-            return [(jnp.zeros(shape, jnp.int8),
-                     jnp.zeros(sshape, jnp.float32),
-                     jnp.zeros(shape, jnp.int8),
-                     jnp.zeros(sshape, jnp.float32))
-                    for _ in range(module.n_layers)]
-        return [(jnp.zeros(shape, module.dtype),
-                 jnp.zeros(shape, module.dtype))
-                for _ in range(module.n_layers)]
-
     def splice_remote(self, prompt: np.ndarray, max_new_tokens: int,
                       deadline: float, first_tok: int, src_caches,
                       lane: str = "primary", trace=None) -> Optional[Request]:
@@ -1390,10 +1392,10 @@ class ServingEngine:
                       float(deadline))
         req.trace = trace
         if g.caches is None:
-            g.caches = self._empty_caches(eng.module, g.capacity, bucket,
-                                          kind=eng.cache_dtype)
+            g.caches = eng.empty_state(g.capacity, bucket)
         g.caches = DecodeEngine.merge_cache_rows(
-            g.caches, src_caches, [slot], [0], mesh=eng.mesh)
+            g.caches, src_caches, [slot], [0], mesh=eng.mesh,
+            kinds=eng.state_kinds)
         g.rows[slot] = req
         g.tok[slot] = int(first_tok)
         g.true_len[slot] = req.true_len
@@ -1454,7 +1456,7 @@ class ServingEngine:
         window = eng.serve_window(g.bucket, max_t, seg)
         # the segment reads the whole cache width for every slot, whatever
         # the masks (a resident cache never shrinks: `serve_step`)
-        read = max(window, int(g.caches[0][0].shape[1]))
+        read = max(window, eng.state_window(g.caches))
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.segment", cat="serve",
                             bucket=g.bucket, lane=lane, seg_len=seg,
@@ -1464,7 +1466,7 @@ class ServingEngine:
                 variables, g.caches, np.asarray(g.tok),
                 np.asarray(g.done), g.true_len, g.budget, g.bucket,
                 g.t_row, self._group_keys(g), seg, window)
-            toks_h, tok_h, done_h = self._fetch(toks, tok, done)
+            toks_h, tok_h, done_h = self._fetch(eng, toks, tok, done)
         elapsed = monotonic() - t0
         self.estimator.observe_step(g.bucket, elapsed / seg)
         self._record_serve({"event": "segment", "bucket": g.bucket,
@@ -1528,7 +1530,7 @@ class ServingEngine:
                 g.budget, g.bucket, g.t_row, g.spec_rounds,
                 self._group_keys(g), window)
             toks_h, counts_h, tok_h, done_h, accepted_h = self._fetch(
-                toks, counts, tok, done, accepted)
+                eng, toks, counts, tok, done, accepted)
         elapsed = monotonic() - t0
         g.spec_rounds += 1
         emitted = int(counts_h[live].sum())
@@ -1605,6 +1607,17 @@ class ServingEngine:
         out["breaker_state"] = self.breaker.state
         out["weights_device_bytes"] = _device_bytes(
             (self._variables, self._draft_vars))   # lanes and the draft
+        # gauges: bytes of the resident rows' state, by kind (a window
+        # that grows by cache_chunk; a fixed leaf a row)
+        held = {WINDOW: 0, FIXED: 0}
+        for (_, lane), g in list(self._groups.items()):
+            caches = g.caches
+            if caches is not None:
+                for kind, n in self._engines[lane].state_bytes(
+                        caches).items():
+                    held[kind] += n
+        out["state_bytes_window"] = held[WINDOW]
+        out["state_bytes_fixed"] = held[FIXED]
         if self._prefix is not None:
             out["prefix"] = self._prefix.stats()
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):

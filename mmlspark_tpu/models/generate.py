@@ -169,6 +169,35 @@ def _dense(p: dict, x: jax.Array, dtype) -> jax.Array:
             + p["bias"].astype(dtype))
 
 
+# The dicts of a block that `_dense` reads (the head's, `lm_head`, sits
+# beside the blocks): `_resident_params` names their leaves from this.
+_DENSE_DICTS = ("qkv", "proj", "mlp_up", "mlp_down")
+
+
+def _resident_params(module, params: dict, cast) -> dict:
+    """TransformerLM's `params` with `cast` over every leaf that the
+    decode programs read ONLY through `_dense`'s `.astype(dtype)`: the
+    kernel and bias of the dicts above and of the head.  An int8 dict
+    (`kernel_scale`) stays: its kernel is int8 and its bias is read in
+    float32.  So do the LayerNorms (`_ln` works in float32), the two
+    embeddings (summed in float32 before the cast) and a `moe` subtree
+    (`MoEMLP` applies a float32 router to it).  A kernel that a new code
+    path reads keeps to this rule or tests/test_resident_weights.py's
+    jaxpr guard fails."""
+    def dense(p: dict) -> dict:
+        if "kernel_scale" in p:
+            return p
+        return {**p, "kernel": cast(p["kernel"]), "bias": cast(p["bias"])}
+
+    out = dict(params)
+    out["lm_head"] = dense(params["lm_head"])
+    for i in range(module.n_layers):
+        name = f"block{i}_w"
+        out[name] = {k: dense(v) if k in _DENSE_DICTS else v
+                     for k, v in params[name].items()}
+    return out
+
+
 def _mlp(module, bp: dict, h2: jax.Array, dtype) -> jax.Array:
     """The block's MLP half over normalized activations h2 (B, S, D).
 
@@ -322,6 +351,47 @@ def _decoding_for(module):
     else the model's own statement of its layers (`HybridDecoding`)."""
     _check_generatable(module, _ENGINE_ARCHITECTURES, "DecodeEngine")
     return HybridDecoding(module) if isinstance(module, HybridLM) else None
+
+
+def resident_variables(module, variables, mesh=None):
+    """The weight tree the decode programs of `module` are handed: every
+    leaf that they read only through a cast to `module.dtype` is held in
+    that dtype, so no program call casts it again (with float32
+    parameters and bfloat16 compute XLA hoists those casts out of the
+    step loop and repeats them in every prefill and segment call).  The
+    model says which leaves those are, beside the code that reads them
+    (`_resident_params`, `HybridDecoding.resident_params`); all others
+    keep their dtype.  Rounding once here gives the bits that rounding in
+    every call gave, and `.astype` of a leaf already in `dtype` is the
+    identity, so every logit is unchanged (bit for bit wherever XLA
+    compiles the products alike: always on the CPU; PERF.md section 6
+    has the TPU's one exception).  A float32 module gets its tree back
+    as it is.
+
+    Pass the tree BEFORE `bridge.place_weights` (the cast commutes with
+    replication and sharding).  A leaf is cast where it lives; a host
+    leaf bound for the default device (`mesh` None) is placed there first
+    and cast there, which is far quicker than numpy's cast, and waited
+    for, so that its float32 upload is freed before the next leaf's
+    arrives: the two copies of the tree never stand on the device
+    together."""
+    dtype = jnp.dtype(module.dtype)
+    if dtype == jnp.float32:
+        return variables
+    from mmlspark_tpu.parallel.bridge import place_weights
+
+    def cast(leaf):
+        if leaf.dtype == dtype:
+            return leaf
+        if mesh is None:
+            return jax.block_until_ready(place_weights(leaf).astype(dtype))
+        return leaf.astype(dtype)
+
+    decoding = _decoding_for(module)
+    params = (decoding.resident_params(variables["params"], cast)
+              if decoding is not None
+              else _resident_params(module, variables["params"], cast))
+    return {**variables, "params": params}
 
 
 def _kv_state(module, rows: int, window: int, hint) -> list:
@@ -643,6 +713,31 @@ def _quantize_cache(kc: jax.Array, vc: jax.Array) -> tuple:
     return kq, ks, vq, vs
 
 
+def _fold_heads(caches: list) -> list:
+    """Model-dtype K/V windows (B, W, H, D) as (B, W, H*D), the layout
+    the fused kernel reads (`ops/decode_attention.py`).  On the TPU the
+    two are tiled differently, so the reshape is a copy of the window:
+    left inside the step it is made for every layer's K and V at every
+    decode step (34 ms of a 126 ms segment of Cerebras-GPT-1.3B, PERF.md
+    section 6, PR 29).  A segment folds once, steps on the folded windows
+    (`_decode_block*` write a token's K/V in whichever shape the window
+    has) and unfolds once at its end."""
+    return [tuple(c.reshape(c.shape[:2] + (-1,)) for c in layer)
+            for layer in caches]
+
+
+def _unfold_heads(caches: list, n_heads: int) -> list:
+    """`_fold_heads` undone: the (B, W, H, D) windows a segment returns."""
+    return [tuple(c.reshape(c.shape[:2] + (n_heads, -1)) for c in layer)
+            for layer in caches]
+
+
+def _window_entry(t: jax.Array, cache: jax.Array) -> jax.Array:
+    """A step's K or V, (B, 1, H, D), as one slot of `cache`: in its
+    dtype, and head-folded where the window is (`_fold_heads`)."""
+    return t.astype(cache.dtype).reshape(t.shape[:2] + cache.shape[2:])
+
+
 def _sq_attention(fused: bool):
     """The decode step's cache read.  `fused=True` routes through the
     Pallas single-query kernel (ops/decode_attention.py) — which itself
@@ -697,10 +792,11 @@ def _decode_block(module, bp: dict, x: jax.Array, cache: tuple,
         cache = (kq, ks, vq, vs)
     else:
         k_cache, v_cache = cache
+        at = (0, slot) + (0,) * (k_cache.ndim - 2)
         k_cache = lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
+            k_cache, _window_entry(k, k_cache), at)
         v_cache = lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
+            v_cache, _window_entry(v, v_cache), at)
         o = single_query_attention(q[:, 0], k_cache, v_cache, visible)
         cache = (k_cache, v_cache)
     x = x + _dense(bp["proj"], o.reshape(b, 1, d).astype(dtype), dtype)
@@ -864,8 +960,8 @@ def _decode_block_rows(module, bp: dict, x: jax.Array, cache: tuple,
         cache = (kq, ks, vq, vs)
     else:
         k_cache, v_cache = cache
-        k_cache = _row_write(k_cache, k.astype(k_cache.dtype), slots)
-        v_cache = _row_write(v_cache, v.astype(v_cache.dtype), slots)
+        k_cache = _row_write(k_cache, _window_entry(k, k_cache), slots)
+        v_cache = _row_write(v_cache, _window_entry(v, v_cache), slots)
         o = single_query_attention(q[:, 0], k_cache, v_cache, visible)
         cache = (k_cache, v_cache)
     x = x + _dense(bp["proj"], o.reshape(b, 1, d).astype(dtype), dtype)
@@ -1354,6 +1450,10 @@ class DecodeEngine:
         def add_counts(counts, new):
             return tuple(c + n for c, n in zip(counts, new))
 
+        # a segment steps on head-folded windows where its steps read
+        # them through the fused kernel (`_fold_heads`)
+        fold = decoding is None and fused and cache_dtype == "model"
+
         def grow(caches, window):
             return _grow_state(caches, window, kinds)
 
@@ -1381,6 +1481,8 @@ class DecodeEngine:
                          true_len, bucket, t0, row_keys):
             params = variables["params"]
             caches = grow(caches, window)
+            if fold:
+                caches = _fold_heads(caches)
             slots = jnp.arange(window)
 
             def step(carry, s_off):
@@ -1400,6 +1502,8 @@ class DecodeEngine:
 
             (tok, done, caches, counts), toks = lax.scan(
                 step, (tok, done, caches, no_counts), jnp.arange(seg_len))
+            if fold:
+                caches = _unfold_heads(caches, module.n_heads)
             return (caches, toks.transpose(1, 0), tok, done) + counts
 
         if seq_shards > 1:
@@ -1537,6 +1641,8 @@ class DecodeEngine:
             token (the engine's per-row emit counters ignore them)."""
             params = variables["params"]
             caches = grow(caches, window)
+            if fold:
+                caches = _fold_heads(caches)
             slots_axis = jnp.arange(window)
             max_pos = module.max_len - 1
 
@@ -1557,6 +1663,8 @@ class DecodeEngine:
 
             (tok, done, caches, counts), toks = lax.scan(
                 step, (tok, done, caches, no_counts), jnp.arange(seg_len))
+            if fold:
+                caches = _unfold_heads(caches, module.n_heads)
             return (caches, toks.transpose(1, 0), tok, done) + counts
 
         def prefill_chunk0_impl(w0, variables, tokens, true_len):
@@ -1884,6 +1992,14 @@ class DecodeEngine:
     def bucket_for(self, prompt_len: int) -> int:
         return bucket_length(prompt_len, self.module.max_len,
                              self.max_new_tokens, self.min_bucket)
+
+    def resident_variables(self, variables, draft: bool = False):
+        """`variables` (the draft model's with `draft`) as this engine's
+        programs should be handed them, before their placement on its
+        mesh: the module-level `resident_variables`."""
+        return resident_variables(
+            self.draft_module if draft else self.module, variables,
+            self.mesh)
 
     # -- serving hooks (serve/engine.py) ---------------------------------
     # The continuous-batching scheduler drives the engine's compiled
@@ -2668,8 +2784,9 @@ class TextGenerator(Transformer):
         if self._mesh not in self._device_vars:
             from mmlspark_tpu.parallel.bridge import place_weights
             self._device_vars[self._mesh] = place_weights(
-                self._bundle.variables, self._mesh,
-                self._bundle.partition_rules())
+                resident_variables(self._bundle.module(),
+                                   self._bundle.variables, self._mesh),
+                self._mesh, self._bundle.partition_rules())
         return self._device_vars[self._mesh]
 
     def _draft_device_variables(self):
@@ -2679,8 +2796,9 @@ class TextGenerator(Transformer):
         if self._mesh not in self._draft_device_vars:
             from mmlspark_tpu.parallel.bridge import place_weights
             self._draft_device_vars[self._mesh] = place_weights(
-                self._draft_bundle.variables, self._mesh,
-                replicate_only=True)
+                resident_variables(self._draft_bundle.module(),
+                                   self._draft_bundle.variables, self._mesh),
+                self._mesh, replicate_only=True)
         return self._draft_device_vars[self._mesh]
 
     def _transform_beam(self, rows: list, out: list) -> None:

@@ -392,6 +392,29 @@ class HybridDecoding:
                 if kind == WINDOW else (jnp.zeros(fixed, m.dtype),)
                 for kind in self.state_kinds]
 
+    # a layer's leaves that `_dot` and `routed_experts` read, through a
+    # cast to the compute dtype and in no other way
+    _PRODUCT_LEAVES = frozenset({"conv_in", "conv_out", "wq", "wk", "wv",
+                                 "wo", "w1", "w2", "w3"})
+
+    def resident_params(self, params: dict, cast) -> dict:
+        """`params` with `cast` over every leaf the programs read only
+        through a cast to `module.dtype` (`generate.resident_variables`):
+        the products' kernels above, the expert stacks among them, the
+        embedding (gathered, then cast: the cast commutes with the
+        gather; the tied head reads it through `_dot`) and an untied
+        head.  The norms, `conv_taps`, the router's kernel and the
+        selection bias are read in float32 and stay."""
+        out = dict(params)
+        for name in ("embed", "head"):
+            if name in params:
+                out[name] = cast(params[name])
+        for i in range(self.module.n_layers):
+            name = f"layer{i}"
+            out[name] = {k: cast(v) if k in self._PRODUCT_LEAVES else v
+                         for k, v in params[name].items()}
+        return out
+
     def _prompt_counts(self, loads):
         """A prefill's counts: assignments, and each expert layer's
         fullest expert beside the mean."""

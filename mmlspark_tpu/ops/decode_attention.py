@@ -22,7 +22,11 @@ Layout: the cache stays (B, L, H, D).  Rather than transposing to the
 flash kernel's (B*H, L, D) — a full relayout of the window per decode
 step, the exact traffic the kernel exists to avoid — the head axis is
 folded into the lane dimension: blocks are (block_k, H*D) slices of the
-contiguous (B, L, H*D) view, per-head score rows are produced by one MXU
+(B, L, H*D) view (contiguous in the array's order, but on the TPU tiled
+otherwise than the 4-D window: XLA makes that view by copying the window,
+so a caller that reads a window at every decode step hands it over folded
+and keeps it so: `models/generate._fold_heads`), per-head score rows are
+produced by one MXU
 matmul against a constant head-selector matrix (lane i of the cache
 belongs to head i // D), and the softmax weights are expanded back through
 its transpose.  Scores and statistics live in a 128-lane tile (one lane
@@ -195,7 +199,8 @@ def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
     b, h, d = q.shape
     l = k_cache.shape[1]
     hd = h * d
-    # contiguous head-fold views: no relayout of the cache window
+    # head-fold views: free for a window handed over folded; of a
+    # (B, L, H, D) window the TPU makes a re-tiled copy
     q3 = q.reshape(b, 1, hd)
     k3 = k_cache.reshape(b, l, hd)
     v3 = v_cache.reshape(b, l, hd)
@@ -310,10 +315,15 @@ def _read(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
           block_k: int, interpret: Optional[bool], emit_stats: bool):
     """The dispatch both public wrappers share: the CPU's quiet reference
     path, the fallback ladder, else the kernel."""
-    reference = (single_query_attention_stats if emit_stats
-                 else single_query_attention)
-    b, _, d = q.shape
+    b, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
+
+    def reference(q, k_cache, v_cache, *rest):
+        # the references read (B, L, H, D); a head-folded window unfolds
+        unfold = lambda c: c.reshape(c.shape[:2] + (h, d))
+        return (single_query_attention_stats if emit_stats
+                else single_query_attention)(q, unfold(k_cache),
+                                             unfold(v_cache), *rest)
     if interpret is None:
         if _auto_interpret():
             # the CPU: the reference is the intended path (quiet)
@@ -340,7 +350,10 @@ def fused_single_query_attention(q: jax.Array, k_cache: jax.Array,
                                  ) -> jax.Array:
     """`single_query_attention` with a fused Pallas cache read on TPU.
 
-    Same contract as the reference (q (B, H, D); caches (B, L, H, D); per
+    Same contract as the reference (q (B, H, D); caches (B, L, H, D), or
+    head-folded (B, L, H*D): the kernel's own layout, which on the TPU is
+    another tiling than the 4-D one, so a caller that reads one window
+    many times folds it once; per
     row visibility (B, L); optional per-(row, slot, head) int8 dequant
     scales (B, L, H); returns (B, H, D) float32) and the same float32
     statistics, so the two agree to rounding — tests/test_decode_attention

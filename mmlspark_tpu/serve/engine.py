@@ -359,8 +359,6 @@ class ServingEngine:
         # (the loop thread never sees the caller's contextvars)
         self._run = active_run()
         self._tracer = self._run.tracer if self._run is not None else None
-        self._draft_vars = (self._place_replicated(draft_bundle)
-                            if self.cfg.spec_tokens else None)
         self._engines = {"primary": self._decode_engine(self._module)}
         if FIXED in self._engines["primary"].state_kinds:
             # a fixed per-row state cannot be cut at a prompt prefix or
@@ -376,6 +374,9 @@ class ServingEngine:
                     f"tiered roles and KV handoff are not supported for "
                     f"{name} (role={self.cfg.role!r}): the handoff pages "
                     "window chunks only")
+        self._cast_bytes = 0           # the gauge `weights_cast_bytes`
+        self._draft_vars = (self._place_replicated(draft_bundle)
+                            if self.cfg.spec_tokens else None)
         self._variables = {"primary": self._place_variables(bundle)}
         if degraded_bundle is not None:
             deg = degraded_bundle.module()
@@ -450,15 +451,28 @@ class ServingEngine:
         """A lane's weights are placed ONCE, here (`bridge.place_weights`):
         off-mesh on the default device, under a mesh replicated (dp-only)
         or partition-rule sharded (mp >= 2; the bundle's own rules, else
-        DEFAULT_RULES).  Every jitted call is handed the placed tree, so
-        none uploads it again; it stays resident until the engine stops."""
+        DEFAULT_RULES).  What is placed is the lane's RESIDENT tree
+        (`DecodeEngine.resident_variables`): the leaves its programs read
+        only through a cast to the compute dtype are held in that dtype,
+        so no call casts them again.  Every jitted call is handed the
+        placed tree, so none uploads it again; it stays resident until
+        the engine stops."""
         from mmlspark_tpu.parallel.bridge import place_weights
         t0 = monotonic()
+        eng = self._engines["primary" if lane == "draft" else lane]
         placed = jax.block_until_ready(place_weights(
-            bundle.variables, self._mesh, bundle.partition_rules(),
+            eng.resident_variables(bundle.variables, draft=lane == "draft"),
+            self._mesh, bundle.partition_rules(),
             replicate_only=replicate_only))
+        cast = sum(
+            int(got.nbytes) for got, src in zip(
+                jax.tree_util.tree_leaves(placed),
+                jax.tree_util.tree_leaves(bundle.variables))
+            if got.dtype != src.dtype)
+        self._cast_bytes += cast
         self._record_serve({"event": "weights_placed", "lane": lane,
                             "bytes": _device_bytes(placed),
+                            "cast_bytes": cast,
                             "seconds": round(monotonic() - t0, 3)})
         return placed
 
@@ -643,6 +657,7 @@ class ServingEngine:
         # holds the stopped object (a thread, a closure, the HTTP server)
         self._variables = {}
         self._draft_vars = None
+        self._cast_bytes = 0
         trace_event("serve.drain_end", cat="serve")
         self._record_serve({"event": "drain_end",
                             "counts": dict(self._counts)})
@@ -1607,6 +1622,9 @@ class ServingEngine:
         out["breaker_state"] = self.breaker.state
         out["weights_device_bytes"] = _device_bytes(
             (self._variables, self._draft_vars))   # lanes and the draft
+        # of them, the leaves held in the compute dtype and not the
+        # bundle's (0: a float32 or an int8 model)
+        out["weights_cast_bytes"] = self._cast_bytes
         # gauges: bytes of the resident rows' state, by kind (a window
         # that grows by cache_chunk; a fixed leaf a row)
         held = {WINDOW: 0, FIXED: 0}

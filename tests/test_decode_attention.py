@@ -147,6 +147,35 @@ def test_auto_interpret_on_cpu_is_reference():
                                rtol=0, atol=0)
 
 
+@pytest.mark.parametrize("mode", ["kernel", "int8_kernel", "cpu_reference",
+                                  "non_tiling_fallback"])
+def test_head_folded_window_reads_the_same(mode):
+    """A window handed over head-folded, (B, L, H*D) (the kernel's own
+    layout: a decode segment folds its windows once, where the 4-D one
+    would be re-tiled on the TPU for every read), gives bit for bit the
+    4-D window's result: through the kernel, through the reference the
+    CPU's auto mode takes, and through the fallback for a window the
+    kernel cannot tile."""
+    if mode == "cpu_reference" and ON_TPU:
+        pytest.skip("auto mode compiles the kernel on TPU")
+    kw = dict(interpret=None if mode == "cpu_reference" else INTERPRET)
+    q, k, v, visible = _case(
+        dtype=jnp.bfloat16, seed=9,
+        l=100 if mode == "non_tiling_fallback" else 128)
+    if mode == "non_tiling_fallback":
+        # compiled, 100 has no divisor that is a multiple of bfloat16's
+        # sublane tile: the wrapper hands over to the reference
+        kw.update(interpret=False)
+    if mode == "int8_kernel":
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        kw.update(k_scale=ks, v_scale=vs)
+    fold = lambda c: c.reshape(c.shape[:2] + (-1,))
+    want = fused_single_query_attention(q, k, v, visible, **kw)
+    got = fused_single_query_attention(q, fold(k), fold(v), visible, **kw)
+    assert got.shape == want.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # ---- softmax-stats variants (seq-sharded decode's merge epilogue) -------
 
 def _merge_halves(fn, q, k, v, visible, **kw):

@@ -1,7 +1,11 @@
 """Weights are placed on the device ONCE (parallel/bridge.place_weights):
 no jitted call of `ServingEngine` or `TextGenerator` is handed a host
-weight tree, which `jax.jit` would upload again on every call.  Tiny
-preset on the CPU backend; nothing here is a timing.
+weight tree, which `jax.jit` would upload again on every call.  What they
+place is the RESIDENT tree (`generate.resident_variables`): with bfloat16
+compute the kernels are held in bfloat16, so no call casts them again
+(tests/test_resident_weights.py pins that the results are bit for bit the
+float32 tree's).  Tiny preset on the CPU backend, in both compute dtypes;
+nothing here is a timing.
 """
 
 import jax
@@ -13,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 from mmlspark_tpu import DataTable
 from mmlspark_tpu.models import ModelBundle, TextGenerator
 from mmlspark_tpu.models.definitions import build_model
-from mmlspark_tpu.models.generate import DecodeEngine
+from mmlspark_tpu.models.generate import DecodeEngine, resident_variables
 from mmlspark_tpu.parallel.bridge import place_weights
 from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh
 from mmlspark_tpu.quant import quantize_bundle
@@ -34,6 +38,20 @@ def tree_bytes(tree) -> int:
     return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
 
 
+def resident(bundle):
+    """The tree an engine of `bundle` places, still where the bundle's
+    leaves are."""
+    return resident_variables(bundle.module(), bundle.variables)
+
+
+def cast_bytes(bundle) -> int:
+    """Bytes of the resident leaves that left the bundle's dtype."""
+    return sum(got.nbytes for got, src in zip(
+        jax.tree_util.tree_leaves(resident(bundle)),
+        jax.tree_util.tree_leaves(bundle.variables))
+        if got.dtype != src.dtype)
+
+
 def assert_on_device(placed, source) -> None:
     """Every leaf a `jax.Array` of its source's dtype and shape."""
     leaves, src = (jax.tree_util.tree_leaves(t) for t in (placed, source))
@@ -43,10 +61,11 @@ def assert_on_device(placed, source) -> None:
         assert (got.dtype, got.shape) == (want.dtype, want.shape)
 
 
-@pytest.fixture(scope="module")
-def bundle():
-    """A bundle as `ModelBundle.init` / `load_bundle` give it: numpy."""
-    model = build_model("TransformerLM", CFG)
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def bundle(request):
+    """A bundle as `ModelBundle.init` / `load_bundle` give it: numpy,
+    float32 parameters under either compute dtype."""
+    model = build_model("TransformerLM", {**CFG, "dtype": request.param})
     variables = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))
     return ModelBundle.from_module(model, host_tree(variables))
 
@@ -117,9 +136,11 @@ def test_transfer_guard_refuses_the_host_tree_and_passes_the_placed(bundle):
 
 @pytest.mark.parametrize("lane", ["primary", "degraded", "draft"])
 def test_engine_places_every_lane_once(bundle, lane):
-    """Off-mesh every lane's tree, the int8 tree and its scales and the
-    draft included, lives on the device in the dtype its bundle holds,
-    and `weights_device_bytes` is their size."""
+    """Off-mesh every lane's resident tree, the int8 tree and its scales
+    and the draft included, lives on the device: the bundle's dtypes but
+    for the kernels a bfloat16 model holds in bfloat16.
+    `weights_device_bytes` is their size and `weights_cast_bytes` the
+    part in the compute dtype (none of a float32 or an int8 tree)."""
     sources = {"primary": bundle}
     kw = {}
     if lane == "degraded":
@@ -134,9 +155,16 @@ def test_engine_places_every_lane_once(bundle, lane):
         placed["draft"] = engine._draft_vars
     assert sorted(placed) == sorted(sources)
     for name, src in sources.items():
-        assert_on_device(placed[name], src.variables)
-    assert engine.stats()["weights_device_bytes"] == sum(
-        tree_bytes(b.variables) for b in sources.values())
+        assert_on_device(placed[name], resident(src))
+    stats = engine.stats()
+    assert stats["weights_device_bytes"] == sum(
+        tree_bytes(resident(b)) for b in sources.values())
+    assert stats["weights_cast_bytes"] == sum(
+        cast_bytes(b) for b in sources.values())
+    assert (cast_bytes(bundle) == 0) == (bundle.module().dtype
+                                         == jnp.float32)
+    if lane == "degraded":
+        assert cast_bytes(sources[lane]) == 0
     assert engine._engines["primary"].mesh is None
 
 
@@ -193,6 +221,7 @@ def test_weights_placed_event_beside_warmup_done(bundle, tmp_path):
     assert [e["bytes"] for e in placed] == [
         tree_bytes(engine._variables[lane])
         for lane in ("primary", "degraded")]
+    assert [e["cast_bytes"] for e in placed] == [cast_bytes(bundle), 0]
     assert all(e["seconds"] >= 0 for e in placed)
 
 
@@ -212,6 +241,7 @@ def test_stopped_engine_lets_go_of_the_placed_tree(bundle):
     assert engine.state == "stopped"
     assert engine._variables == {} and engine._draft_vars is None
     assert engine.stats()["weights_device_bytes"] == 0
+    assert engine.stats()["weights_cast_bytes"] == 0
     gc.collect()
     assert [r for r in leaves if r() is not None] == []
 
@@ -223,8 +253,8 @@ def test_textgenerator_places_offmesh_once_and_anew_per_bundle(bundle):
                         maxNewTokens=4, specTokens=2)
     gen.set_draft_bundle(truncated_draft_bundle(bundle))
     first, draft = gen._device_variables(), gen._draft_device_variables()
-    assert_on_device(first, bundle.variables)
-    assert_on_device(draft, gen._draft_bundle.variables)
+    assert_on_device(first, resident(bundle))
+    assert_on_device(draft, resident(gen._draft_bundle))
     assert gen._device_variables() is first
     assert gen._draft_device_variables() is draft
     assert gen._device_vars[None] is first
@@ -253,7 +283,7 @@ def test_textgenerator_transform_feeds_its_engine_the_placed_tree(
            for _ in range(2)]
     assert len(handed) == 2 and handed[0] is handed[1]
     assert handed[0] is gen._device_variables()
-    assert_on_device(handed[0], bundle.variables)
+    assert_on_device(handed[0], resident(bundle))
     # and the tokens are those of the bundle's host tree
     want = [real(DecodeEngine(bundle.module(), 4), bundle.variables,
                  np.pad(r, (0, 8 - len(r)))[None],

@@ -422,9 +422,9 @@ def serve_phase(h: Harness, lm_cfg: dict, prompt_lens: tuple,
                           _signature(text)).group(1))
         prefill[p] = min(prefill.get(p, 1 << 30), _mosaic_calls(text))
     if _on_tpu():
-        from mmlspark_tpu.models.generate import _PREFILL_FLASH_MIN
+        from mmlspark_tpu.models.hybrid_lm import PREFILL_FLASH_MIN
         missing = [p for p, n in prefill.items()
-                   if p >= _PREFILL_FLASH_MIN and n < module.n_layers]
+                   if p >= PREFILL_FLASH_MIN and n < module.n_layers]
         if missing:
             raise AssertionError(
                 f"serve: prefill at {missing} compiled without the flash "

@@ -262,8 +262,9 @@ class TransformerBlock(nn.Module):
             # sparse conditional compute: Switch/GShard experts
             # (ops/moe.py); the expert dimension shards over
             # `expert_axis` via expert_parallel_rules (GSPMD EP).
-            # models/generate.py::_mlp mirrors this construction for
-            # KV-cache decode — keep the two in sync
+            # KV-cache decode builds the same module
+            # (models/transformer_decoding.py::_mlp;
+            # tests/test_decoding_seam.py holds the two together)
             from mmlspark_tpu.ops.moe import MoEMLP
             return x + MoEMLP(self.d_model, n_experts=self.n_experts,
                               mlp_ratio=self.mlp_ratio, dtype=self.dtype,

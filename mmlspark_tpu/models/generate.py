@@ -52,19 +52,19 @@ Sampling keys fold in a stable per-row id — a row's draws depend only on
 search stays on the full-cache per-length path (windowing lands
 sampler-first; see docs/performance.md).
 
-The decoder re-implements the TransformerLM block math as pure functions
-over the SAME flax param tree (models/definitions.py names: qkv / proj /
-mlp_up / mlp_down / LayerNorm_0/1), so any trained TransformerLM bundle —
-including one trained through pipeline parallelism and converted back —
-generates without re-exporting weights.  Parity with recompute-everything
-decoding is pinned exactly at float32 by tests/test_generate.py for
-prompts below _PREFILL_FLASH_MIN (the flash prefill's online softmax can
-reassociate near-tie logits above it).  One
-deliberate dtype difference: decode attention accumulates QK^T / PV in
-float32 (the single-query step is bandwidth-bound, so the extra precision
-is free), while the training forward's einsums run in the model dtype —
-for bfloat16 bundles the logits agree to bf16 rounding (test-pinned), and
-near-tie greedy choices may legitimately resolve differently.
+This file is the engine and knows no model's layers.  It asks the model
+for a DECODING, once, when the engine is built (`_decoding_for`): each
+layer's state kind and empty state, which weights stay resident in the
+compute dtype, and the calls its programs make (a prompt segment, the
+head, a decode step at a shared or at per-row slots, the close of a
+prompt, the entry and exit of a segment).  `TransformerDecoding`
+(models/transformer_decoding.py) answers for TransformerLM, over the SAME
+flax param tree training wrote, so any trained bundle generates without
+re-exporting weights; `HybridDecoding` (models/hybrid_lm.py) for HybridLM.
+A new architecture is a decoding class beside its layers, an entry in
+`_DECODINGS` and a plain reference for the tests; `DecodeEngine` is not
+edited.  The full-cache per-length decoder and beam search call
+TransformerLM's whole-segment forward directly.
 """
 
 from __future__ import annotations
@@ -82,6 +82,8 @@ from mmlspark_tpu.core.pipeline import Transformer
 from mmlspark_tpu.models.bundle import load_bundle, save_bundle
 from mmlspark_tpu.models.definitions import TransformerLM
 from mmlspark_tpu.models.hybrid_lm import FIXED, WINDOW, HybridDecoding, HybridLM
+from mmlspark_tpu.models.transformer_decoding import (TransformerDecoding,
+                                                      forward_with_cache)
 from mmlspark_tpu.observe.costmodel import capture_program_cost
 from mmlspark_tpu.observe.spans import active_timings, span_on
 from mmlspark_tpu.observe.telemetry import active_run
@@ -110,227 +112,39 @@ _SPEC_COIN_STREAM = 2 << 20
 _SPEC_FIX_STREAM = 3 << 20
 
 
-def _hint_kv(c: jax.Array) -> jax.Array:
-    """KV-layout sharding hint: rank-4 (B, W, H, D) payloads carry heads
-    on 'model' (KV_CACHE_SPEC); rank-3 (B, W, H) int8-cache scales follow
-    (KV_SCALE_SPEC).  Off-mesh the hint is identity (shard_constraint
+def _kv_hint(cache_spec, scale_spec):
+    """A K/V state leaf's sharding hint: rank-4 (B, W, H, D) payloads take
+    `cache_spec`, the rank-3 (B, W, H) scales of an int8 cache
+    `scale_spec`.  Off-mesh the hint is identity (shard_constraint
     degrades), so every decode path stays single-device-portable."""
-    if c.ndim == 4:
-        return shard_constraint(c, KV_CACHE_SPEC)
-    if c.ndim == 3:
-        return shard_constraint(c, KV_SCALE_SPEC)
-    return c
+    def hint(c: jax.Array) -> jax.Array:
+        if c.ndim == 4:
+            return shard_constraint(c, cache_spec)
+        if c.ndim == 3:
+            return shard_constraint(c, scale_spec)
+        return c
+    return hint
 
 
-def _hint_draft_kv(c: jax.Array) -> jax.Array:
-    """`_hint_kv` for the DRAFT model's cache: batch on 'data', heads
-    replicated (DRAFT_KV_CACHE_SPEC — a latency-sized draft rarely has a
-    head count the model axis divides, and its forward is a rounding
-    error next to the target's)."""
-    if c.ndim == 4:
-        return shard_constraint(c, DRAFT_KV_CACHE_SPEC)
-    if c.ndim == 3:
-        return shard_constraint(c, DRAFT_KV_SCALE_SPEC)
-    return c
+# heads on 'model'
+_hint_kv = _kv_hint(KV_CACHE_SPEC, KV_SCALE_SPEC)
+# the DRAFT model's cache: batch on 'data', heads replicated (a latency-sized
+# draft rarely has a head count the model axis divides, and its forward is a
+# rounding error next to the target's)
+_hint_draft_kv = _kv_hint(DRAFT_KV_CACHE_SPEC, DRAFT_KV_SCALE_SPEC)
+# a SEQ-SHARDED cache: the WINDOW axis splits over 'seq', so each chip holds
+# a contiguous slab of slots — the long-context layout where one chip's HBM
+# no longer bounds the window.  Heads stay unsharded (the seq engine path
+# refuses model>1 meshes)
+_hint_seq_kv = _kv_hint(SEQ_KV_CACHE_SPEC, SEQ_KV_SCALE_SPEC)
 
 
-def _hint_seq_kv(c: jax.Array) -> jax.Array:
-    """`_hint_kv` for a SEQ-SHARDED cache: the WINDOW axis splits over
-    'seq' (SEQ_KV_CACHE_SPEC / SEQ_KV_SCALE_SPEC) so each chip holds a
-    contiguous slab of cache slots — the long-context layout where one
-    chip's HBM no longer bounds the window.  Heads stay unsharded (the
-    seq engine path refuses model>1 meshes).  Off-mesh the hint is
-    identity, same as every other KV hint."""
-    if c.ndim == 4:
-        return shard_constraint(c, SEQ_KV_CACHE_SPEC)
-    if c.ndim == 3:
-        return shard_constraint(c, SEQ_KV_SCALE_SPEC)
-    return c
-
-
-def _ln(p: dict, x: jax.Array, dtype) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    mu = x32.mean(-1, keepdims=True)
-    var = ((x32 - mu) ** 2).mean(-1, keepdims=True)
-    y = (x32 - mu) * lax.rsqrt(var + 1e-6)
-    return (y * p["scale"] + p["bias"]).astype(dtype)
-
-
-def _dense(p: dict, x: jax.Array, dtype) -> jax.Array:
-    if "kernel_scale" in p:
-        # int8-quantized kernel (quant/quantize.py layout): int8 weights x
-        # low-precision activations with the per-output-channel rescale
-        # applied AFTER the matmul — same fused math as quant/modules.py,
-        # so int8 TransformerLM bundles decode without a re-export
-        y = (x.astype(dtype) @ p["kernel"].astype(dtype)).astype(jnp.float32)
-        y = y * p["kernel_scale"] + p["bias"].astype(jnp.float32)
-        return y.astype(dtype)
-    return (x.astype(dtype) @ p["kernel"].astype(dtype)
-            + p["bias"].astype(dtype))
-
-
-# The dicts of a block that `_dense` reads (the head's, `lm_head`, sits
-# beside the blocks): `_resident_params` names their leaves from this.
-_DENSE_DICTS = ("qkv", "proj", "mlp_up", "mlp_down")
-
-
-def _resident_params(module, params: dict, cast) -> dict:
-    """TransformerLM's `params` with `cast` over every leaf that the
-    decode programs read ONLY through `_dense`'s `.astype(dtype)`: the
-    kernel and bias of the dicts above and of the head.  An int8 dict
-    (`kernel_scale`) stays: its kernel is int8 and its bias is read in
-    float32.  So do the LayerNorms (`_ln` works in float32), the two
-    embeddings (summed in float32 before the cast) and a `moe` subtree
-    (`MoEMLP` applies a float32 router to it).  A kernel that a new code
-    path reads keeps to this rule or tests/test_resident_weights.py's
-    jaxpr guard fails."""
-    def dense(p: dict) -> dict:
-        if "kernel_scale" in p:
-            return p
-        return {**p, "kernel": cast(p["kernel"]), "bias": cast(p["bias"])}
-
-    out = dict(params)
-    out["lm_head"] = dense(params["lm_head"])
-    for i in range(module.n_layers):
-        name = f"block{i}_w"
-        out[name] = {k: dense(v) if k in _DENSE_DICTS else v
-                     for k, v in params[name].items()}
-    return out
-
-
-def _mlp(module, bp: dict, h2: jax.Array, dtype) -> jax.Array:
-    """The block's MLP half over normalized activations h2 (B, S, D).
-
-    MoE blocks re-apply the REAL MoEMLP flax module against the block's
-    own params (same construction as TransformerBlock's, keep in sync —
-    definitions.py), so routing math is never duplicated here.
-    Per-segment routing matches training semantics exactly at prefill
-    (same token group, same capacity arithmetic).  Decode steps route
-    the step's BATCH as one group, so under capacity pressure routing
-    can diverge from the full-sequence recompute in either direction
-    (keep a token it would drop, or drop one it would keep), and a
-    row's generations can depend on its co-batched rows — the capacity
-    drop is a batch-level construct a stepwise decoder cannot reproduce.
-    Tests pin prefill parity exactly and greedy parity in the drop-free
-    regime (moe_group_size=1)."""
-    if module.mlp_impl == "moe":
-        from mmlspark_tpu.ops.moe import MoEMLP
-        return MoEMLP(module.d_model, n_experts=module.n_experts,
-                      mlp_ratio=module.mlp_ratio, dtype=dtype,
-                      expert_axis=module.expert_axis,
-                      router_k=module.moe_router_k,
-                      group_size=module.moe_group_size).apply(
-            {"params": bp["moe"]}, h2)
-    return _dense(bp["mlp_down"], jax.nn.gelu(
-        _dense(bp["mlp_up"], h2, dtype)), dtype)
-
-
-_PREFILL_FLASH_MIN = 512  # prompt length from which prefill attention
-# runs the pallas flash kernel instead of the masked dense matmul: long
-# prompts would otherwise materialize an O(P^2) score tensor — exactly
-# the blow-up the flash path exists to avoid.  Short prompts stay on the
-# dense path, whose f32 softmax is bit-stable for the exact-parity tests.
-
-
-def _block_with_cache(module, bp: dict, x: jax.Array, k_cache: jax.Array,
-                      v_cache: jax.Array, pos, dtype):
-    """One TransformerBlock over a token segment starting at `pos`,
-    reading/writing the (B, max_len, H, Dh) caches.  Works for prefill
-    (S = prompt length, pos = 0) and decode (S = 1, traced pos) alike."""
-    n_heads = module.n_heads
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _ln(bp["LayerNorm_0"], x, dtype)
-    qkv = _dense(bp["qkv"], h, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (b, s, n_heads, dh)
-    q, k, v = (t.reshape(shape) for t in (q, k, v))
-    k_cache = lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
-                                       (0, pos, 0, 0))
-    v_cache = lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
-                                       (0, pos, 0, 0))
-    if s >= _PREFILL_FLASH_MIN and isinstance(pos, int) and pos == 0:
-        # long-prompt PREFILL ONLY (static pos 0: at decode, pos is a
-        # tracer): attention against the cache is then exactly causal
-        # self-attention over the segment, so the flash kernel
-        # (O(block^2) memory, fwd-only) computes it without ever
-        # materializing the (S, S) scores.  A long segment at pos > 0
-        # would need the cached prefix too — it takes the dense
-        # full-cache path below
-        from mmlspark_tpu.ops.flash_attention import flash_attention
-        o = flash_attention(q, k, v, causal=True)
-    else:
-        max_len = k_cache.shape[1]
-        scores = jnp.einsum("bqhd,blhd->bhql", q.astype(jnp.float32),
-                            k_cache.astype(jnp.float32)) * dh ** -0.5
-        # global causal mask: query at pos+i sees cache slots 0..pos+i
-        q_pos = pos + jnp.arange(s)
-        visible = jnp.arange(max_len)[None, :] <= q_pos[:, None]  # (S, L)
-        scores = jnp.where(visible[None, None], scores, NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhql,blhd->bqhd", w, v_cache.astype(jnp.float32))
-    x = x + _dense(bp["proj"], o.reshape(b, s, d).astype(dtype), dtype)
-    h2 = _ln(bp["LayerNorm_1"], x, dtype)
-    return x + _mlp(module, bp, h2, dtype), k_cache, v_cache
-
-
-def _forward_with_cache(params: dict, tokens: jax.Array, caches: list,
-                        pos, module):
-    """Logits (B, S, V) for a token segment at `pos`, updating the caches."""
-    dtype = module.dtype
-    s = tokens.shape[1]
-    positions = pos + jnp.arange(s)
-    emb = (params["tok_embed"]["embedding"][tokens]
-           + params["pos_embed"]["embedding"][positions][None])
-    x = emb.astype(dtype)
-    new_caches = []
-    for i in range(module.n_layers):
-        x, kc, vc = _block_with_cache(
-            module, params[f"block{i}_w"], x, caches[i][0], caches[i][1],
-            pos, dtype)
-        new_caches.append((kc, vc))
-    # same dtype discipline as TransformerLM: final norm + head run in the
-    # model's compute dtype, logits emitted float32
-    x = _ln(params["final_norm_w"], x, dtype)
-    logits = _dense(params["lm_head"], x, dtype).astype(jnp.float32)
-    return logits, new_caches
-
-
-def _seq_prefill_block(module, bp: dict, x: jax.Array, dtype,
-                       seq_axis: str):
-    """One TransformerBlock of the DISTRIBUTED blockwise prefill.  Runs
-    inside the seq shard_map region with `x` the LOCAL token slab
-    (B, P/n, D): attention is `ring_attention` — KV blocks rotate around
-    the `seq` axis by ppermute while each chip keeps only its slab's
-    queries resident — so prefill FLOPs, activation memory, and the
-    O(P^2) score working set all scale ~1/n per chip.  Returns the
-    residual stream plus this slab's K and V: the local shard of the
-    layer's seq-partitioned KV cache, written exactly once with no
-    gather."""
-    from mmlspark_tpu.ops.attention import ring_attention
-    n_heads = module.n_heads
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _ln(bp["LayerNorm_0"], x, dtype)
-    qkv = _dense(bp["qkv"], h, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (b, s, n_heads, dh)
-    q, k, v = (t.reshape(shape) for t in (q, k, v))
-    # ring_attention derives each block's global query positions from
-    # axis_index(seq_axis) internally, so causal masking is globally
-    # correct over the rotating KV blocks; output is f32 (online softmax)
-    o = ring_attention(q, k, v, seq_axis, causal=True)
-    x = x + _dense(bp["proj"], o.reshape(b, s, d).astype(dtype), dtype)
-    h2 = _ln(bp["LayerNorm_1"], x, dtype)
-    return x + _mlp(module, bp, h2, dtype), k, v
-
-
-# The architectures each decode path accepts.  The full-cache per-length
-# programs (`make_generate_fn`, beam search) and the draft of a speculating
-# engine are written against TransformerLM's block; `DecodeEngine` also
-# takes a model that states its own layers' decoding (`_decoding_for`).
+# The full-cache per-length programs (`make_generate_fn`, beam search) and
+# the draft of a speculating engine take TransformerLM only.  `DecodeEngine`
+# takes every architecture that has a decoding: the model's own statement
+# of what the engine's programs ask of it (the module docstring).
 _FULL_CACHE_ARCHITECTURES = (TransformerLM,)
-_ENGINE_ARCHITECTURES = (TransformerLM, HybridLM)
+_DECODINGS = {TransformerLM: TransformerDecoding, HybridLM: HybridDecoding}
 
 
 def _check_generatable(module, accepted=_FULL_CACHE_ARCHITECTURES,
@@ -345,12 +159,27 @@ def _check_generatable(module, accepted=_FULL_CACHE_ARCHITECTURES,
     # MoE blocks decode too: _mlp re-applies the real MoEMLP module.
 
 
-def _decoding_for(module):
+def _decoding_for(module, **how):
     """How `DecodeEngine` decodes `module`, decided once when the engine
-    is built: None for TransformerLM (the block functions of this file),
-    else the model's own statement of its layers (`HybridDecoding`)."""
-    _check_generatable(module, _ENGINE_ARCHITECTURES, "DecodeEngine")
-    return HybridDecoding(module) if isinstance(module, HybridLM) else None
+    is built: the decoding of its architecture (`_DECODINGS`).  `how` is
+    what the engine fixes for every call: `cache_dtype` (the layout
+    segments carry the state in), `fused` (steps may read through the
+    Pallas kernel) and `hint` (a state leaf's sharding hint on the
+    engine's mesh).
+
+    A decoding (`hybrid_lm.Decoding`) has `state_kinds` (WINDOW | FIXED a
+    layer), `count_names` (what its programs count on the device; every
+    `run_*` returns the counts last), `empty_state`, `resident_params`,
+    `run_prompt` + `head` (the head is applied to the gathered row),
+    `run_step` / `run_step_rows`, `close_prompt` / `reopen_prompt` (the layout a
+    finished prompt's state is carried in, and back) and `enter_segment` /
+    `leave_segment` (the layout a segment steps on, and back).  What only
+    a model with nothing but WINDOW state composes with (int8 state, the
+    speculative verify segment, the seq-sharded prompt and step) only its
+    decoding has: `DecodeEngine.__init__` refuses the rest by name."""
+    _check_generatable(module, tuple(_DECODINGS), "DecodeEngine")
+    return next(decoding for arch, decoding in _DECODINGS.items()
+                if isinstance(module, arch))(module, **how)
 
 
 def resident_variables(module, variables, mesh=None):
@@ -360,7 +189,7 @@ def resident_variables(module, variables, mesh=None):
     parameters and bfloat16 compute XLA hoists those casts out of the
     step loop and repeats them in every prefill and segment call).  The
     model says which leaves those are, beside the code that reads them
-    (`_resident_params`, `HybridDecoding.resident_params`); all others
+    (its decoding's `resident_params`); all others
     keep their dtype.  Rounding once here gives the bits that rounding in
     every call gave, and `.astype` of a leaf already in `dtype` is the
     identity, so every logit is unchanged (bit for bit wherever XLA
@@ -387,20 +216,8 @@ def resident_variables(module, variables, mesh=None):
             return jax.block_until_ready(place_weights(leaf).astype(dtype))
         return leaf.astype(dtype)
 
-    decoding = _decoding_for(module)
-    params = (decoding.resident_params(variables["params"], cast)
-              if decoding is not None
-              else _resident_params(module, variables["params"], cast))
+    params = _decoding_for(module).resident_params(variables["params"], cast)
     return {**variables, "params": params}
-
-
-def _kv_state(module, rows: int, window: int, hint) -> list:
-    """Zero K and V windows of a TransformerLM, one pair a layer."""
-    dh = module.d_model // module.n_heads
-    shape = (rows, window, module.n_heads, dh)
-    return [(hint(jnp.zeros(shape, module.dtype)),
-             hint(jnp.zeros(shape, module.dtype)))
-            for _ in range(module.n_layers)]
 
 
 def filter_logits(logits: jax.Array, top_k: Optional[int] = None,
@@ -453,8 +270,9 @@ def _prefill(params, prompts, module, prompt_len: int):
             f"prompts have length {prompts.shape[1]} but this compiled "
             f"decode program was built for prompt_len={prompt_len}")
     b = prompts.shape[0]
-    caches = _kv_state(module, b, module.max_len, _hint_kv)
-    logits, caches = _forward_with_cache(params, prompts, caches, 0, module)
+    caches = TransformerDecoding(module, hint=_hint_kv).empty_state(
+        b, module.max_len)
+    logits, caches = forward_with_cache(params, prompts, caches, 0, module)
     return logits[:, -1], caches
 
 
@@ -496,7 +314,7 @@ def make_generate_fn(module, prompt_len: int, max_new_tokens: int,
 
         def step(carry, step_key):
             tok, pos, caches = carry
-            logits, caches = _forward_with_cache(
+            logits, caches = forward_with_cache(
                 params, tok[:, None], caches, pos, module)
             nxt = sample(logits[:, 0], step_key)
             return (nxt, pos + 1, caches), tok
@@ -570,7 +388,7 @@ def make_beam_search_fn(module, prompt_len: int, max_new_tokens: int,
 
         def step(carry, t):
             tok, scores, history, caches = carry
-            logits, caches = _forward_with_cache(
+            logits, caches = forward_with_cache(
                 params, tok.reshape(b * w, 1), caches,
                 prompt_len + t, module)
             logprobs = jax.nn.log_softmax(
@@ -665,35 +483,21 @@ def _make_sampler(temperature: float, top_k, top_p):
     """A `(logits (B, V), row_keys (B,), step) -> tokens (B,)` sampler with
     per-row keys: each row's stream is `fold_in(row_key, step)`, so a
     row's draws depend only on (its key, the step index) — never on which
-    rows share its batch or how groups were formed."""
+    rows share its batch or how groups were formed.  `step` is one scalar
+    for rows in step, or a (B,) vector: rows at different decode offsets
+    (the serving engine's continuous batch) draw from exactly the stream
+    positions the uniform-step batch would have given them."""
     if temperature <= 0.0:
-        def sample(logits, row_keys, step):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        def sample(logits, row_keys, step):
-            filtered = filter_logits(
-                logits.astype(jnp.float32) / temperature, top_k, top_p)
-            keys = jax.vmap(lambda k: jax.random.fold_in(k, step))(row_keys)
-            return jax.vmap(jax.random.categorical)(
-                keys, filtered).astype(jnp.int32)
-    return sample
+        return lambda logits, row_keys, step: jnp.argmax(
+            logits, axis=-1).astype(jnp.int32)
 
-
-def _make_row_sampler(temperature: float, top_k, top_p):
-    """The per-row-STEP form of `_make_sampler`: `steps` is a (B,) vector,
-    so rows at different decode offsets (the serving engine's continuous
-    batch) draw from exactly the stream positions the uniform-step batch
-    path would have given them — fold_in(row_key, step) per row."""
-    if temperature <= 0.0:
-        def sample(logits, row_keys, steps):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        def sample(logits, row_keys, steps):
-            filtered = filter_logits(
-                logits.astype(jnp.float32) / temperature, top_k, top_p)
-            keys = jax.vmap(jax.random.fold_in)(row_keys, steps)
-            return jax.vmap(jax.random.categorical)(
-                keys, filtered).astype(jnp.int32)
+    def sample(logits, row_keys, step):
+        filtered = filter_logits(
+            logits.astype(jnp.float32) / temperature, top_k, top_p)
+        keys = jax.vmap(jax.random.fold_in, in_axes=(
+            0, 0 if jnp.ndim(step) else None))(row_keys, step)
+        return jax.vmap(jax.random.categorical)(
+            keys, filtered).astype(jnp.int32)
     return sample
 
 
@@ -702,362 +506,6 @@ def _make_stop_check(stop_tokens: tuple):
         return lambda tok: jnp.zeros(tok.shape, bool)
     stops = jnp.asarray(list(stop_tokens), jnp.int32)
     return lambda tok: (tok[:, None] == stops[None, :]).any(axis=-1)
-
-
-def _quantize_cache(kc: jax.Array, vc: jax.Array) -> tuple:
-    """Convert one layer's model-dtype caches to the int8 layout:
-    (k int8, k_scale f32 (B, W, H), v int8, v_scale)."""
-    from mmlspark_tpu.quant.quantize import quantize_kv
-    kq, ks = quantize_kv(kc)
-    vq, vs = quantize_kv(vc)
-    return kq, ks, vq, vs
-
-
-def _fold_heads(caches: list) -> list:
-    """Model-dtype K/V windows (B, W, H, D) as (B, W, H*D), the layout
-    the fused kernel reads (`ops/decode_attention.py`).  On the TPU the
-    two are tiled differently, so the reshape is a copy of the window:
-    left inside the step it is made for every layer's K and V at every
-    decode step (34 ms of a 126 ms segment of Cerebras-GPT-1.3B, PERF.md
-    section 6, PR 29).  A segment folds once, steps on the folded windows
-    (`_decode_block*` write a token's K/V in whichever shape the window
-    has) and unfolds once at its end."""
-    return [tuple(c.reshape(c.shape[:2] + (-1,)) for c in layer)
-            for layer in caches]
-
-
-def _unfold_heads(caches: list, n_heads: int) -> list:
-    """`_fold_heads` undone: the (B, W, H, D) windows a segment returns."""
-    return [tuple(c.reshape(c.shape[:2] + (n_heads, -1)) for c in layer)
-            for layer in caches]
-
-
-def _window_entry(t: jax.Array, cache: jax.Array) -> jax.Array:
-    """A step's K or V, (B, 1, H, D), as one slot of `cache`: in its
-    dtype, and head-folded where the window is (`_fold_heads`)."""
-    return t.astype(cache.dtype).reshape(t.shape[:2] + cache.shape[2:])
-
-
-def _sq_attention(fused: bool):
-    """The decode step's cache read.  `fused=True` routes through the
-    Pallas single-query kernel (ops/decode_attention.py) — which itself
-    degrades to the XLA reference off-TPU or on shapes it can't tile, so
-    tier-1 CPU runs exercise the fallback on the product path.  The
-    engine only requests it single-device: `pallas_call` carries no SPMD
-    partitioning rule, so under a mesh the decode step keeps the einsum
-    composition GSPMD can shard."""
-    if fused:
-        from mmlspark_tpu.ops.decode_attention import (
-            fused_single_query_attention)
-        return fused_single_query_attention
-    from mmlspark_tpu.ops.attention import single_query_attention
-    return single_query_attention
-
-
-def _decode_block(module, bp: dict, x: jax.Array, cache: tuple,
-                  slot, visible, dtype, cache_kind: str,
-                  fused: bool = False):
-    """One TransformerBlock for a single decode token: write K/V at cache
-    `slot` (shared across rows — decode slots sit after the bucket's pad
-    tail), attend under the per-row `visible` mask (true-prompt slots plus
-    decode slots written so far), MLP as in `_block_with_cache`.
-
-    `cache` is (k, v) for a model-dtype cache or (k_q, k_scale, v_q,
-    v_scale) for an int8 one (cache_kind 'int8'): the new token's K/V are
-    quantized per-head ON WRITE and the attention read dequantizes inside
-    the cache attention (`_sq_attention`: the fused Pallas kernel on a
-    single TPU device, `single_query_attention` otherwise) — the steady
-    step streams 1 byte per cached element instead of the model dtype's
-    2-4."""
-    single_query_attention = _sq_attention(fused)
-    n_heads = module.n_heads
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _ln(bp["LayerNorm_0"], x, dtype)
-    qkv = _dense(bp["qkv"], h, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (b, 1, n_heads, dh)
-    q, k, v = (t.reshape(shape) for t in (q, k, v))
-    if cache_kind == "int8":
-        from mmlspark_tpu.quant.quantize import quantize_kv
-        kq, ks, vq, vs = cache
-        k8, k8s = quantize_kv(k)
-        v8, v8s = quantize_kv(v)
-        kq = lax.dynamic_update_slice(kq, k8, (0, slot, 0, 0))
-        ks = lax.dynamic_update_slice(ks, k8s, (0, slot, 0))
-        vq = lax.dynamic_update_slice(vq, v8, (0, slot, 0, 0))
-        vs = lax.dynamic_update_slice(vs, v8s, (0, slot, 0))
-        o = single_query_attention(q[:, 0], kq, vq, visible,
-                                   k_scale=ks, v_scale=vs)
-        cache = (kq, ks, vq, vs)
-    else:
-        k_cache, v_cache = cache
-        at = (0, slot) + (0,) * (k_cache.ndim - 2)
-        k_cache = lax.dynamic_update_slice(
-            k_cache, _window_entry(k, k_cache), at)
-        v_cache = lax.dynamic_update_slice(
-            v_cache, _window_entry(v, v_cache), at)
-        o = single_query_attention(q[:, 0], k_cache, v_cache, visible)
-        cache = (k_cache, v_cache)
-    x = x + _dense(bp["proj"], o.reshape(b, 1, d).astype(dtype), dtype)
-    h2 = _ln(bp["LayerNorm_1"], x, dtype)
-    return x + _mlp(module, bp, h2, dtype), cache
-
-
-def _decode_step(params: dict, tok: jax.Array, pos: jax.Array, slot,
-                 caches: list, visible, module, cache_kind: str = "model",
-                 fused: bool = False):
-    """Logits (B, V) for one decode token per row: per-row positions `pos`
-    (true prompt length + step — NOT the shared cache slot), shared write
-    `slot`, per-row attention visibility."""
-    dtype = module.dtype
-    emb = (params["tok_embed"]["embedding"][tok]
-           + params["pos_embed"]["embedding"][pos])
-    x = emb[:, None].astype(dtype)
-    new_caches = []
-    for i in range(module.n_layers):
-        x, cache = _decode_block(module, params[f"block{i}_w"], x,
-                                 caches[i], slot, visible, dtype,
-                                 cache_kind, fused)
-        new_caches.append(cache)
-    x = _ln(params["final_norm_w"], x, dtype)
-    logits = _dense(params["lm_head"], x, dtype).astype(jnp.float32)
-    return logits[:, 0], new_caches
-
-
-def _seq_decode_block(module, bp: dict, x: jax.Array, cache: tuple,
-                      slot, lo, visible, dtype, cache_kind: str,
-                      seq_axis: str):
-    """`_decode_block` for a SEQ-SHARDED cache, running inside the seq
-    shard_map region.  Each chip holds a contiguous window slab of `w_l`
-    slots starting at its `lo = axis_index(seq) * w_l`; the new token's
-    K/V land on exactly the one chip that owns global `slot` (`owns` is
-    a traced scalar — every chip computes the candidate write, the
-    non-owners discard it via `jnp.where`, so no cross-chip writes ever
-    happen).  Attention reads become per-chip softmax STATS
-    (`single_query_attention_stats`: f32 running (acc, m, l) against the
-    local slab under the local slice of `visible`) merged across `seq`
-    by `merge_attention_stats` — one pmax + two psums per layer instead
-    of gathering the window.  int8 dequant scales compose unchanged:
-    dequantization happens inside the local stats pass, before the
-    merge."""
-    from mmlspark_tpu.ops.attention import (merge_attention_stats,
-                                            single_query_attention_stats)
-    n_heads = module.n_heads
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _ln(bp["LayerNorm_0"], x, dtype)
-    qkv = _dense(bp["qkv"], h, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (b, 1, n_heads, dh)
-    q, k, v = (t.reshape(shape) for t in (q, k, v))
-    w_l = cache[0].shape[1]
-    owns = (slot >= lo) & (slot < lo + w_l)
-    local_slot = jnp.clip(slot - lo, 0, w_l - 1)
-    if cache_kind == "int8":
-        from mmlspark_tpu.quant.quantize import quantize_kv
-        kq, ks, vq, vs = cache
-        k8, k8s = quantize_kv(k)
-        v8, v8s = quantize_kv(v)
-        kq = jnp.where(owns, lax.dynamic_update_slice(
-            kq, k8, (0, local_slot, 0, 0)), kq)
-        ks = jnp.where(owns, lax.dynamic_update_slice(
-            ks, k8s, (0, local_slot, 0)), ks)
-        vq = jnp.where(owns, lax.dynamic_update_slice(
-            vq, v8, (0, local_slot, 0, 0)), vq)
-        vs = jnp.where(owns, lax.dynamic_update_slice(
-            vs, v8s, (0, local_slot, 0)), vs)
-        acc, m, l = single_query_attention_stats(q[:, 0], kq, vq, visible,
-                                                 k_scale=ks, v_scale=vs)
-        cache = (kq, ks, vq, vs)
-    else:
-        k_cache, v_cache = cache
-        k_cache = jnp.where(owns, lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, local_slot, 0, 0)),
-            k_cache)
-        v_cache = jnp.where(owns, lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, local_slot, 0, 0)),
-            v_cache)
-        acc, m, l = single_query_attention_stats(q[:, 0], k_cache, v_cache,
-                                                 visible)
-        cache = (k_cache, v_cache)
-    o = merge_attention_stats(acc, m, l, axis_name=seq_axis)
-    x = x + _dense(bp["proj"], o.reshape(b, 1, d).astype(dtype), dtype)
-    h2 = _ln(bp["LayerNorm_1"], x, dtype)
-    return x + _mlp(module, bp, h2, dtype), cache
-
-
-def _seq_decode_step(params: dict, tok: jax.Array, pos: jax.Array, slot,
-                     lo, caches: list, visible, module,
-                     cache_kind: str, seq_axis: str):
-    """`_decode_step` inside the seq shard_map region: same per-row
-    positions / shared global write `slot`, but `visible` covers only
-    the local window slab and each block merges softmax stats across
-    `seq`.  The non-attention compute (embeddings, MLPs, head) is
-    replicated per seq shard — deterministic-identical on every chip, so
-    the logits really are replicated over `seq` as the out_specs
-    claim."""
-    dtype = module.dtype
-    emb = (params["tok_embed"]["embedding"][tok]
-           + params["pos_embed"]["embedding"][pos])
-    x = emb[:, None].astype(dtype)
-    new_caches = []
-    for i in range(module.n_layers):
-        x, cache = _seq_decode_block(module, params[f"block{i}_w"], x,
-                                     caches[i], slot, lo, visible, dtype,
-                                     cache_kind, seq_axis)
-        new_caches.append(cache)
-    x = _ln(params["final_norm_w"], x, dtype)
-    logits = _dense(params["lm_head"], x, dtype).astype(jnp.float32)
-    return logits[:, 0], new_caches
-
-
-def _row_write(cache: jax.Array, update: jax.Array,
-               slots: jax.Array) -> jax.Array:
-    """Write a contiguous block of new entries per row at a PER-ROW
-    start slot: vmap of the single-row dynamic_update_slice over the
-    batch axis.  `cache` (B, W, ...), `update` (B, S, ...) — S is 1 for
-    decode steps, the verify segment length for speculative decoding —
-    `slots` (B,) int32 start positions.  The serving
-    engine's continuous batch needs this — joined rows sit at different
-    decode offsets, so the uniform shared-slot write of `_decode_block`
-    no longer applies.  dynamic_update_slice clamps starts, so a frozen
-    row whose slot has run past the window writes harmlessly into its own
-    last slot (its `done` mask keeps the output frozen regardless)."""
-    zeros = (0,) * (cache.ndim - 2)
-    return jax.vmap(
-        lambda c, u, s: lax.dynamic_update_slice(c, u, (s,) + zeros)
-    )(cache, update, slots)
-
-
-def _decode_block_rows(module, bp: dict, x: jax.Array, cache: tuple,
-                       slots, visible, dtype, cache_kind: str,
-                       fused: bool = False):
-    """`_decode_block` with PER-ROW write slots (serving engine): row r
-    writes its K/V at `slots[r]` instead of one shared slot.  Math and
-    cache layouts are identical otherwise — same quantize-on-write int8
-    discipline, same cache-attention read (`_sq_attention`)."""
-    single_query_attention = _sq_attention(fused)
-    n_heads = module.n_heads
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _ln(bp["LayerNorm_0"], x, dtype)
-    qkv = _dense(bp["qkv"], h, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (b, 1, n_heads, dh)
-    q, k, v = (t.reshape(shape) for t in (q, k, v))
-    if cache_kind == "int8":
-        from mmlspark_tpu.quant.quantize import quantize_kv
-        kq, ks, vq, vs = cache
-        k8, k8s = quantize_kv(k)
-        v8, v8s = quantize_kv(v)
-        kq = _row_write(kq, k8, slots)
-        ks = _row_write(ks, k8s, slots)
-        vq = _row_write(vq, v8, slots)
-        vs = _row_write(vs, v8s, slots)
-        o = single_query_attention(q[:, 0], kq, vq, visible,
-                                   k_scale=ks, v_scale=vs)
-        cache = (kq, ks, vq, vs)
-    else:
-        k_cache, v_cache = cache
-        k_cache = _row_write(k_cache, _window_entry(k, k_cache), slots)
-        v_cache = _row_write(v_cache, _window_entry(v, v_cache), slots)
-        o = single_query_attention(q[:, 0], k_cache, v_cache, visible)
-        cache = (k_cache, v_cache)
-    x = x + _dense(bp["proj"], o.reshape(b, 1, d).astype(dtype), dtype)
-    h2 = _ln(bp["LayerNorm_1"], x, dtype)
-    return x + _mlp(module, bp, h2, dtype), cache
-
-
-def _decode_step_rows(params: dict, tok: jax.Array, pos: jax.Array, slots,
-                      caches: list, visible, module,
-                      cache_kind: str = "model", fused: bool = False):
-    """`_decode_step` with per-row write `slots` (B,) — the continuous-
-    batching decode step.  `pos` stays per-row true positions; callers
-    clamp it below max_len for frozen rows (their output is masked by
-    `done` anyway, but the position gather must stay in range)."""
-    dtype = module.dtype
-    emb = (params["tok_embed"]["embedding"][tok]
-           + params["pos_embed"]["embedding"][pos])
-    x = emb[:, None].astype(dtype)
-    new_caches = []
-    for i in range(module.n_layers):
-        x, cache = _decode_block_rows(module, params[f"block{i}_w"], x,
-                                      caches[i], slots, visible, dtype,
-                                      cache_kind, fused)
-        new_caches.append(cache)
-    x = _ln(params["final_norm_w"], x, dtype)
-    logits = _dense(params["lm_head"], x, dtype).astype(jnp.float32)
-    return logits[:, 0], new_caches
-
-
-def _verify_block_rows(module, bp: dict, x: jax.Array, cache: tuple,
-                       slots0, visible, dtype, cache_kind: str):
-    """One TransformerBlock over a row's CONTIGUOUS S-token verify
-    segment (speculative decoding): row r writes S new K/V entries at
-    slots0[r]..slots0[r]+S-1 in one per-row block write (`_row_write`
-    takes any update length), then attends all S queries against the
-    cache window under per-QUERY visibility
-    (ops/attention.segment_cache_attention).  Same quantize-on-write
-    int8 discipline as `_decode_block_rows`; at S = 1 the attention math
-    is elementwise-identical to the single-query step — the property the
-    speculative path's greedy byte-exactness rests on."""
-    from mmlspark_tpu.ops.attention import segment_cache_attention
-    n_heads = module.n_heads
-    b, s, d = x.shape
-    dh = d // n_heads
-    h = _ln(bp["LayerNorm_0"], x, dtype)
-    qkv = _dense(bp["qkv"], h, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    shape = (b, s, n_heads, dh)
-    q, k, v = (t.reshape(shape) for t in (q, k, v))
-    if cache_kind == "int8":
-        from mmlspark_tpu.quant.quantize import quantize_kv
-        kq, ks, vq, vs = cache
-        k8, k8s = quantize_kv(k)
-        v8, v8s = quantize_kv(v)
-        kq = _row_write(kq, k8, slots0)
-        ks = _row_write(ks, k8s, slots0)
-        vq = _row_write(vq, v8, slots0)
-        vs = _row_write(vs, v8s, slots0)
-        o = segment_cache_attention(q, kq, vq, visible,
-                                    k_scale=ks, v_scale=vs)
-        cache = (kq, ks, vq, vs)
-    else:
-        k_cache, v_cache = cache
-        k_cache = _row_write(k_cache, k.astype(k_cache.dtype), slots0)
-        v_cache = _row_write(v_cache, v.astype(v_cache.dtype), slots0)
-        o = segment_cache_attention(q, k_cache, v_cache, visible)
-        cache = (k_cache, v_cache)
-    x = x + _dense(bp["proj"], o.reshape(b, s, d).astype(dtype), dtype)
-    h2 = _ln(bp["LayerNorm_1"], x, dtype)
-    return x + _mlp(module, bp, h2, dtype), cache
-
-
-def _verify_step_rows(params: dict, toks: jax.Array, pos0: jax.Array,
-                      slots0, caches: list, visible, module,
-                      cache_kind: str = "model"):
-    """Logits (B, S, V) for per-row contiguous verify segments — the
-    speculative-decoding target forward: ONE program scores every drafted
-    position.  Row r's S tokens sit at positions pos0[r]..pos0[r]+S-1
-    (clamped to the position table) and write cache slots
-    slots0[r]..slots0[r]+S-1; `visible` is per-query (B, S, W)."""
-    dtype = module.dtype
-    s = toks.shape[1]
-    positions = pos0[:, None] + jnp.arange(s)[None, :]
-    positions = jnp.minimum(positions, module.max_len - 1)
-    emb = (params["tok_embed"]["embedding"][toks]
-           + params["pos_embed"]["embedding"][positions])
-    x = emb.astype(dtype)
-    new_caches = []
-    for i in range(module.n_layers):
-        x, cache = _verify_block_rows(module, params[f"block{i}_w"], x,
-                                      caches[i], slots0, visible, dtype,
-                                      cache_kind)
-        new_caches.append(cache)
-    x = _ln(params["final_norm_w"], x, dtype)
-    logits = _dense(params["lm_head"], x, dtype).astype(jnp.float32)
-    return logits, new_caches
 
 
 def _grow_cache(cache: jax.Array, window: int) -> jax.Array:
@@ -1248,10 +696,23 @@ class DecodeEngine:
                  min_new_tokens: int = 1,
                  prefill_chunk: Optional[int] = None,
                  draft_module=None, spec_tokens: int = 0):
-        decoding = _decoding_for(module)
-        if decoding is not None:
-            # what a model that states its own decoding does not carry
-            # over yet refuses here, by name; nothing falls back
+        seq_shards = (int(mesh.shape.get(SEQ_AXIS, 1))
+                      if mesh is not None else 1)
+        # the fused Pallas single-query kernel only runs single-device:
+        # pallas_call has no SPMD partitioning rule, so under a mesh the
+        # decode step keeps the einsum composition GSPMD can shard.  (The
+        # kernel itself degrades to the same reference off-TPU — tier-1
+        # CPU runs exercise that fallback on this very path.)
+        fused = mesh is None
+        # a window leaf's hint on this mesh: slots over 'seq' where the
+        # mesh has it (1 = the classic whole-window engine), else heads
+        # on 'model'
+        hint = _hint_seq_kv if seq_shards > 1 else _hint_kv
+        decoding = _decoding_for(module, cache_dtype=cache_dtype,
+                                 fused=fused, hint=hint)
+        if FIXED in decoding.state_kinds:
+            # what a model with fixed per-row state does not carry over
+            # yet refuses here, by name; nothing falls back
             name = type(module).__name__
             if cache_dtype == "int8":
                 raise ValueError(
@@ -1323,8 +784,6 @@ class DecodeEngine:
                 raise ValueError(
                     f"stop token {t} outside the vocabulary "
                     f"(0..{module.vocab_size - 1})")
-        seq_shards = (int(mesh.shape.get(SEQ_AXIS, 1))
-                      if mesh is not None else 1)
         if seq_shards > 1:
             # the seq-sharded engine path: long-context decode with the
             # KV window partitioned over 'seq'.  Its refusals bound the
@@ -1366,10 +825,11 @@ class DecodeEngine:
         # a fixed per-row leaf) and the names of what the programs count
         # on the device: asked of the model once, here
         self._decoding = decoding
-        self.state_kinds = (decoding.state_kinds if decoding is not None
-                            else (WINDOW,) * module.n_layers)
-        self.count_names = (decoding.count_names if decoding is not None
-                            else ())
+        self._draft_decoding = draft = (
+            _decoding_for(draft_module, hint=_hint_draft_kv)
+            if draft_module is not None else None)
+        self.state_kinds = kinds = decoding.state_kinds
+        self.count_names = decoding.count_names
         self.counts_out: list = []   # the last program's device counts
         self._chunk_counts: list = []
         self.max_new_tokens = max_new_tokens
@@ -1385,15 +845,7 @@ class DecodeEngine:
         # segments, merge) traces under use_mesh(mesh), so at mp >= 2 the
         # cache keeps heads on 'model' end to end; None = single-device
         self.mesh = mesh
-        # window shards over 'seq' (1 = the classic whole-window engine)
         self.seq_shards = seq_shards
-        # the fused Pallas single-query kernel only runs single-device:
-        # pallas_call has no SPMD partitioning rule, so under a mesh the
-        # decode step keeps the einsum composition GSPMD can shard.  (The
-        # kernel itself degrades to the same reference off-TPU — tier-1
-        # CPU runs exercise that fallback on this very path.)
-        fused = mesh is None
-        self.uses_fused_attention = fused
         greedy = temperature <= 0.0
         sample = _make_sampler(temperature,
                                None if greedy else top_k,
@@ -1409,82 +861,45 @@ class DecodeEngine:
                 return is_stop(tok)
             return is_stop(tok) & (new_count >= min_new)
 
-        # The model behind the programs.  TransformerLM runs this file's
-        # block functions; another model its own (`_decoding_for`).  Each
-        # call returns `counts`, a tuple of device counters that rides
-        # with the tokens: () for TransformerLM, which counts nothing.
-        kinds = self.state_kinds
-        if decoding is None:
-            def new_state(b, w):
-                return _kv_state(module, b, w, _hint_kv)
-
-            def run_prompt(params, tokens, caches, start, true_len, live):
-                logits, caches = _forward_with_cache(params, tokens, caches,
-                                                     start, module)
-                return logits, caches, ()
-
-            def to_logits(params, last):
-                return last
-
-            def run_step(params, tok, pos, slot, caches, visible, live):
-                logits, caches = _decode_step(params, tok, pos, slot,
-                                              caches, visible, module,
-                                              cache_dtype, fused)
-                return logits, caches, ()
-
-            def run_step_rows(params, tok, pos, slots, caches, visible,
-                              live):
-                logits, caches = _decode_step_rows(
-                    params, tok, pos, slots, caches, visible, module,
-                    cache_dtype, fused)
-                return logits, caches, ()
-            no_counts = ()
-        else:
-            new_state = decoding.empty_state
-            run_prompt = decoding.run_prompt
-            to_logits = decoding.head
-            # the uniform-slot step is the per-row step with equal slots
-            run_step = run_step_rows = decoding.run_step_rows
-            no_counts = (jnp.zeros(len(self.count_names), jnp.float32),)
+        # Every `run_*` of the decoding returns `counts` last, a tuple of
+        # device counters that rides with the tokens: () where the model
+        # counts nothing.
+        no_counts = ((jnp.zeros(len(self.count_names), jnp.float32),)
+                     if self.count_names else ())
 
         def add_counts(counts, new):
             return tuple(c + n for c, n in zip(counts, new))
 
-        # a segment steps on head-folded windows where its steps read
-        # them through the fused kernel (`_fold_heads`)
-        fold = decoding is None and fused and cache_dtype == "model"
-
         def grow(caches, window):
-            return _grow_state(caches, window, kinds)
+            return _grow_state(caches, window, kinds, hint)
+
+        def row_logits(params, feats, idx):
+            """Each row's logits at position `idx` (B,) of what
+            `run_prompt` returned for a segment: the head sees the
+            gathered row only."""
+            return decoding.head(params, jnp.take_along_axis(
+                feats, idx[:, None, None], axis=1)[:, 0])
 
         def prefill_impl(variables, prompts, true_len, live, row_keys):
             params = variables["params"]
-            b, p = prompts.shape
-            w0 = _round_up(p + 1, chunk)
-            caches = new_state(b, w0)
-            feats, caches, counts = run_prompt(params, prompts, caches, 0,
-                                               true_len, live)
-            last = to_logits(params, jnp.take_along_axis(
-                feats, (true_len - 1)[:, None, None], axis=1)[:, 0])
-            tok = sample(last, row_keys, 0)
-            done = ~live | stop_gate(tok, 1)
-            if cache_dtype == "int8":
-                # quantize-on-write at prefill granularity: the prompt's
-                # whole cache quantizes once here, decode steps quantize
-                # each new token inside _decode_block
-                caches = [tuple(_hint_kv(c)
-                                for c in _quantize_cache(kc, vc))
-                          for kc, vc in caches]
-            return (tok, done, caches) + counts
+            w0 = _round_up(prompts.shape[1] + 1, chunk)
+            # called here, not through a helper: the flash kernels trace
+            # a frame nearer the stack's base so (PERF.md section 7)
+            if seq_shards > 1:
+                feats, caches, counts = seq_prompt(params, prompts, w0)
+            else:
+                feats, caches, counts = decoding.run_prompt(
+                    params, prompts,
+                    decoding.empty_state(prompts.shape[0], w0), 0, true_len,
+                    live)
+            return prefill_finish_impl(
+                caches, row_logits(params, feats, true_len - 1), live,
+                row_keys) + counts
 
-        def segment_impl(seg_len, window, variables, caches, tok, done,
-                         true_len, bucket, t0, row_keys):
-            params = variables["params"]
-            caches = grow(caches, window)
-            if fold:
-                caches = _fold_heads(caches)
-            slots = jnp.arange(window)
-
+        def uniform_steps(run_step, seg_len, slots, params, caches, tok,
+                          done, true_len, bucket, t0, row_keys):
+            """`seg_len` decode steps of rows that share their step offset
+            (and so their write slot) over the window `slots` names."""
             def step(carry, s_off):
                 tok, done, caches, counts = carry
                 t = t0 + s_off
@@ -1502,131 +917,67 @@ class DecodeEngine:
 
             (tok, done, caches, counts), toks = lax.scan(
                 step, (tok, done, caches, no_counts), jnp.arange(seg_len))
-            if fold:
-                caches = _unfold_heads(caches, module.n_heads)
             return (caches, toks.transpose(1, 0), tok, done) + counts
 
+        def segment_impl(seg_len, window, variables, caches, tok, done,
+                         true_len, bucket, t0, row_keys):
+            caches = decoding.enter_segment(grow(caches, window))
+            caches, *out = uniform_steps(
+                decoding.run_step, seg_len, jnp.arange(window),
+                variables["params"], caches, tok, done, true_len, bucket,
+                t0, row_keys)
+            return (decoding.leave_segment(caches), *out)
+
         if seq_shards > 1:
-            # SEQ-SHARDED engine: replace the prefill/segment impls with
-            # shard_map'd equivalents before the meshed wrappers below
-            # close over the names.  Prefill runs DISTRIBUTED BLOCKWISE
-            # (ring attention over the prompt slabs — wall clock ~1/n);
-            # decode keeps the host segment loop identical but merges
-            # per-chip softmax stats across 'seq' each step.
+            # SEQ-SHARDED engine: the prompt forward and the segment run
+            # in shard_map regions over 'seq'.  Prefill runs DISTRIBUTED
+            # BLOCKWISE (ring attention over the prompt slabs — wall clock
+            # ~1/n); decode keeps the host segment loop identical but
+            # merges per-chip softmax stats across 'seq' each step.
             from jax.sharding import PartitionSpec as P
             from mmlspark_tpu.parallel.ring import _shard_map
-            tok_spec = P(DATA_AXIS, SEQ_AXIS)
             row_spec = P(DATA_AXIS)
 
-            def _seq_cache_specs(caches):
-                return [tuple(SEQ_KV_CACHE_SPEC if c.ndim == 4
-                              else SEQ_KV_SCALE_SPEC for c in layer)
-                        for layer in caches]
-
-            def seq_prefill_impl(variables, prompts, true_len, live,
-                                 row_keys):
-                params = variables["params"]
-                p = prompts.shape[1]
-                w0 = _round_up(p + 1, chunk)
-                dtype = module.dtype
-
-                def local_fwd(params, tokens):
-                    s_l = tokens.shape[1]
-                    lo = lax.axis_index(SEQ_AXIS) * s_l
-                    # SHARED positions 0..p-1 (the global slab offset),
-                    # exactly _forward_with_cache's position stream —
-                    # causal masking alone makes the per-row true_len-1
-                    # logit gather correct
-                    positions = lo + jnp.arange(s_l)
-                    emb = (params["tok_embed"]["embedding"][tokens]
-                           + params["pos_embed"]["embedding"][positions][
-                               None])
-                    x = emb.astype(dtype)
-                    kvs = []
-                    for i in range(module.n_layers):
-                        x, k_l, v_l = _seq_prefill_block(
-                            module, params[f"block{i}_w"], x, dtype,
-                            SEQ_AXIS)
-                        kvs.append((k_l.astype(dtype), v_l.astype(dtype)))
-                    x = _ln(params["final_norm_w"], x, dtype)
-                    logits = _dense(params["lm_head"], x,
-                                    dtype).astype(jnp.float32)
-                    return logits, kvs
-
+            def seq_prompt(params, prompts, w0):
                 logits, kvs = _shard_map(
-                    local_fwd, mesh=mesh,
-                    in_specs=(P(), tok_spec),
+                    decoding.run_prompt_seq, mesh=mesh,
+                    in_specs=(P(), P(DATA_AXIS, SEQ_AXIS)),
                     out_specs=(P(DATA_AXIS, SEQ_AXIS, None),
                                SEQ_KV_CACHE_SPEC))(params, prompts)
-                last = jnp.take_along_axis(
-                    logits, (true_len - 1)[:, None, None], axis=1)[:, 0]
-                tok = sample(last, row_keys, 0)
-                done = ~live | stop_gate(tok, 1)
                 # the cache window (w0, chunk-aligned) has DIFFERENT seq
                 # partition boundaries than the prompt (p): pad outside
                 # the shard_map and let GSPMD reshard once against the
                 # hint — not inside, where slab widths would disagree
-                caches = [(_hint_seq_kv(_grow_cache(k_l, w0)),
-                           _hint_seq_kv(_grow_cache(v_l, w0)))
-                          for k_l, v_l in kvs]
-                if cache_dtype == "int8":
-                    caches = [tuple(_hint_seq_kv(c)
-                                    for c in _quantize_cache(kc, vc))
-                              for kc, vc in caches]
-                return tok, done, caches
+                return logits, grow(kvs, w0), ()
 
-            def seq_segment_impl(seg_len, window, variables, caches, tok,
-                                 done, true_len, bucket, t0, row_keys):
-                params = variables["params"]
-                caches = [tuple(_hint_seq_kv(_grow_cache(c, window))
-                                for c in layer) for layer in caches]
-                cache_specs = _seq_cache_specs(caches)
-                # typed PRNG keys are an extended dtype shard_map can't
-                # always carry (jax 0.4.x): thread the raw uint32 key
-                # data through and rebuild inside
-                rk = jax.random.key_data(row_keys)
+            def segment_impl(seg_len, window, variables, caches, tok,
+                             done, true_len, bucket, t0, row_keys):
+                caches = grow(caches, window)
+                cache_specs = [tuple(SEQ_KV_CACHE_SPEC if c.ndim == 4
+                                     else SEQ_KV_SCALE_SPEC for c in layer)
+                               for layer in caches]
 
                 def local_seg(params, caches, tok, done, true_len, bucket,
                               t0, rk):
-                    row_keys = jax.random.wrap_key_data(rk)
                     w_l = caches[0][0].shape[1]
                     lo = lax.axis_index(SEQ_AXIS) * w_l
-                    slots = lo + jnp.arange(w_l)
+                    return uniform_steps(
+                        functools.partial(decoding.run_step_seq, lo=lo),
+                        seg_len, lo + jnp.arange(w_l), params, caches, tok,
+                        done, true_len, bucket, t0,
+                        jax.random.wrap_key_data(rk))
 
-                    def step(carry, s_off):
-                        tok, done, caches = carry
-                        t = t0 + s_off
-                        slot = bucket + t
-                        pos = true_len + t
-                        visible = ((slots[None, :] < true_len[:, None])
-                                   | ((slots[None, :] >= bucket)
-                                      & (slots[None, :] <= slot)))
-                        logits, caches = _seq_decode_step(
-                            params, tok, pos, slot, lo, caches, visible,
-                            module, cache_dtype, SEQ_AXIS)
-                        nxt = sample(logits, row_keys, t + 1)
-                        nxt = jnp.where(done, tok, nxt)
-                        return (nxt, done | stop_gate(nxt, t + 2),
-                                caches), tok
-
-                    (tok, done, caches), toks = lax.scan(
-                        step, (tok, done, caches), jnp.arange(seg_len))
-                    return caches, toks.transpose(1, 0), tok, done
-
+                # typed PRNG keys are an extended dtype shard_map can't
+                # always carry (jax 0.4.x): thread the raw uint32 key
+                # data through and rebuild inside
                 return _shard_map(
                     local_seg, mesh=mesh,
                     in_specs=(P(), cache_specs, row_spec, row_spec,
                               row_spec, P(), P(), P(DATA_AXIS, None)),
                     out_specs=(cache_specs, P(DATA_AXIS, None), row_spec,
                                row_spec))(
-                    params, caches, tok, done, true_len, bucket, t0, rk)
-
-            prefill_impl = seq_prefill_impl
-            segment_impl = seq_segment_impl
-
-        row_sample = _make_row_sampler(temperature,
-                                       None if greedy else top_k,
-                                       None if greedy else top_p)
+                    variables["params"], caches, tok, done, true_len,
+                    bucket, t0, jax.random.key_data(row_keys))
 
         def serve_segment_impl(seg_len, window, variables, caches, tok,
                                done, true_len, budget, bucket, t_row,
@@ -1640,9 +991,7 @@ class DecodeEngine:
             own cache row only and their emissions repeat the frozen
             token (the engine's per-row emit counters ignore them)."""
             params = variables["params"]
-            caches = grow(caches, window)
-            if fold:
-                caches = _fold_heads(caches)
+            caches = decoding.enter_segment(grow(caches, window))
             slots_axis = jnp.arange(window)
             max_pos = module.max_len - 1
 
@@ -1654,18 +1003,17 @@ class DecodeEngine:
                 visible = ((slots_axis[None, :] < true_len[:, None])
                            | ((slots_axis[None, :] >= bucket)
                               & (slots_axis[None, :] <= slot[:, None])))
-                logits, caches, new = run_step_rows(
+                logits, caches, new = decoding.run_step_rows(
                     params, tok, pos, slot, caches, visible, ~done)
-                nxt = row_sample(logits, row_keys, t + 1)
+                nxt = sample(logits, row_keys, t + 1)
                 nxt = jnp.where(done, tok, nxt)
                 done = done | stop_gate(nxt, t + 2) | (t + 1 >= budget)
                 return (nxt, done, caches, add_counts(counts, new)), nxt
 
             (tok, done, caches, counts), toks = lax.scan(
                 step, (tok, done, caches, no_counts), jnp.arange(seg_len))
-            if fold:
-                caches = _unfold_heads(caches, module.n_heads)
-            return (caches, toks.transpose(1, 0), tok, done) + counts
+            return (decoding.leave_segment(caches), toks.transpose(1, 0),
+                    tok, done) + counts
 
         def prefill_chunk0_impl(w0, variables, tokens, true_len):
             """First chunk of a CHUNKED prefill (offset 0): allocates the
@@ -1675,66 +1023,47 @@ class DecodeEngine:
             long prompt stops stalling running requests."""
             params = variables["params"]
             b, cl = tokens.shape
-            caches = new_state(b, w0)
-            feats, caches, counts = run_prompt(
+            caches = decoding.empty_state(b, w0)
+            feats, caches, counts = decoding.run_prompt(
                 params, tokens, caches, 0, true_len, jnp.ones(b, bool))
-            idx = jnp.clip(true_len - 1, 0, cl - 1)
-            last = to_logits(params, jnp.take_along_axis(
-                feats, idx[:, None, None], axis=1)[:, 0])
+            last = row_logits(params, feats,
+                              jnp.clip(true_len - 1, 0, cl - 1))
             return (caches, last) + counts
 
         def prefill_chunk_impl(variables, tokens, caches, last, true_len,
                                c0):
-            """One later prompt chunk at TRACED offset `c0`: the dense
-            `_block_with_cache` path works at any position, so every
-            chunk index shares ONE compiled program per shape class.
+            """One later prompt chunk at TRACED offset `c0`: a prompt
+            segment's read of the whole window works at any position, so
+            every chunk index shares ONE compiled program per shape class.
             Rows whose last prompt token falls inside this chunk update
             the running last-position logits."""
             params = variables["params"]
             b, cl = tokens.shape
-            feats, caches, counts = run_prompt(
+            feats, caches, counts = decoding.run_prompt(
                 params, tokens, caches, c0, true_len, jnp.ones(b, bool))
-            idx = jnp.clip(true_len - 1 - c0, 0, cl - 1)
-            cand = to_logits(params, jnp.take_along_axis(
-                feats, idx[:, None, None], axis=1)[:, 0])
+            cand = row_logits(params, feats,
+                              jnp.clip(true_len - 1 - c0, 0, cl - 1))
             here = (true_len - 1 >= c0) & (true_len - 1 < c0 + cl)
             last = jnp.where(here[:, None], cand, last)
             return (caches, last) + counts
 
         def prefill_finish_impl(caches, last, live, row_keys):
-            """Close a chunked prefill: sample the first token and (int8
-            mode) quantize the whole prompt cache — the same
-            (tok, done, caches) contract as `prefill_impl`."""
+            """Close a prefill, whole or chunked: sample the first token
+            and hand the prompt's state over as segments carry it (an
+            int8 cache quantizes here), as (tok, done, caches)."""
             tok = sample(last, row_keys, 0)
             done = ~live | stop_gate(tok, 1)
-            if cache_dtype == "int8":
-                caches = [tuple(_hint_kv(c)
-                                for c in _quantize_cache(kc, vc))
-                          for kc, vc in caches]
-            return tok, done, caches
+            return tok, done, decoding.close_prompt(caches)
 
         def resume_init_impl(w0, row_caches):
             """Open a RESUMED chunked prefill from a donor prefix row
-            (serve/prefix_cache.py): dequantize int8 donor slots back to
-            model dtype (suffix chunks keep writing through the same
-            model-dtype cache the fresh path uses; `prefill_finish_impl`
-            re-quantizes the whole window, and quantize_kv's round-trip
-            idempotency keeps the stored prefix bytes identical), grow
+            (serve/prefix_cache.py): reopen the donor slots (suffix chunks
+            keep writing through the same state the fresh path uses;
+            `prefill_finish_impl` closes the whole window again), grow
             to the bucket window, and zero the running last-position
             logits — the matched prefix is always strictly inside the
             prompt, so a later chunk's `here` mask recomputes them."""
-            caches = []
-            for layer in row_caches:
-                if len(layer) == 4:
-                    kq, ks, vq, vs = layer
-                    k = (kq.astype(jnp.float32)
-                         * ks[..., None]).astype(module.dtype)
-                    v = (vq.astype(jnp.float32)
-                         * vs[..., None]).astype(module.dtype)
-                else:
-                    k, v = layer
-                caches.append((_hint_kv(_grow_cache(k, w0)),
-                               _hint_kv(_grow_cache(v, w0))))
+            caches = grow(decoding.reopen_prompt(row_caches), w0)
             b = row_caches[0][0].shape[0]
             last = jnp.zeros((b, module.vocab_size), jnp.float32)
             return caches, last
@@ -1747,14 +1076,11 @@ class DecodeEngine:
             model-dtype (the draft is latency-sized; int8's bandwidth
             win is a target-cache story) and replicate their heads under
             a mesh (DRAFT_KV_CACHE_SPEC)."""
-            dm = draft_module
-            params = draft_variables["params"]
             b, p = prompts.shape
-            w0 = _round_up(p + 1, chunk)
-            caches = _kv_state(dm, b, w0, _hint_draft_kv)
-            _, caches = _forward_with_cache(params, prompts, caches, 0,
-                                            dm)
-            return caches
+            caches = draft.empty_state(b, _round_up(p + 1, chunk))
+            # the draft is a TransformerLM: its prompt needs no lengths
+            return draft.run_prompt(draft_variables["params"], prompts,
+                                    caches, 0, None, None)[1]
 
         k_spec = spec_tokens
 
@@ -1767,7 +1093,7 @@ class DecodeEngine:
             steps (the extra step back-fills the last proposal's
             draft-cache slot, so the draft never attends a zero slot),
             ONE target forward scores every proposal
-            (`_verify_step_rows`), and the agreeing prefix commits.
+            (the decoding's `run_verify`), and the agreeing prefix commits.
 
             Greedy mode accepts while the proposal equals the target
             argmax and appends the target's own next token — the
@@ -1789,11 +1115,9 @@ class DecodeEngine:
             acceptance-rate telemetry."""
             params = variables["params"]
             dparams = draft_variables["params"]
-            caches = [tuple(_hint_kv(_grow_cache(c, window))
-                            for c in layer) for layer in caches]
-            draft_caches = [tuple(_hint_draft_kv(_grow_cache(c, window))
-                                  for c in layer)
-                            for layer in draft_caches]
+            caches = grow(caches, window)
+            draft_caches = _grow_state(draft_caches, window,
+                                       hint=_hint_draft_kv)
             b = tok.shape[0]
             s = k_spec + 1
             slots_axis = jnp.arange(window)
@@ -1813,9 +1137,8 @@ class DecodeEngine:
                 visible = ((slots_axis[None, :] < true_len[:, None])
                            | ((slots_axis[None, :] >= bucket)
                               & (slots_axis[None, :] <= slot[:, None])))
-                dlogits, draft_caches = _decode_step_rows(
-                    dparams, cur, pos, slot, draft_caches, visible,
-                    draft_module, "model")
+                dlogits, draft_caches, _ = draft.run_step_rows(
+                    dparams, cur, pos, slot, draft_caches, visible, ~done)
                 if j == k_spec:
                     break          # K/V back-fill only; proposal unused
                 if sampling:
@@ -1846,9 +1169,8 @@ class DecodeEngine:
                       & (slots_axis[None, None, :]
                          <= (slots0[:, None]
                              + q_idx[None, :])[:, :, None])))
-            logits, caches = _verify_step_rows(
-                params, xs, true_len + t_row, slots0, caches, vis,
-                module, cache_dtype)
+            logits, caches = decoding.run_verify(
+                params, xs, true_len + t_row, slots0, caches, vis)
 
             # -- accept --
             if sampling:
@@ -1921,62 +1243,40 @@ class DecodeEngine:
             return (caches, draft_caches, toks_out, count, cur, done,
                     accepted)
 
-        # jit the meshed wrappers, not the impls: tracing runs the body,
-        # so use_mesh(mesh) bakes the KV hints into every compiled
-        # program (and the attributes stay jit objects —
-        # capture_program_cost .lower()s them)
-        def prefill_meshed(variables, prompts, true_len, live, row_keys):
-            with use_mesh(mesh):
-                return prefill_impl(variables, prompts, true_len, live,
-                                    row_keys)
+        def meshed(impl, name: str):
+            """`impl` under use_mesh(mesh), to be jitted: tracing runs the
+            body, so the KV hints of this mesh are baked into every
+            compiled program (and the attributes stay jit objects —
+            capture_program_cost .lower()s them).  Profiles and
+            chip_smoke.py find a program by `name`."""
+            def call(*args):
+                with use_mesh(mesh):
+                    return impl(*args)
+            call.__name__ = name
+            return call
 
-        def segment_meshed(seg_len, window, *args):
-            with use_mesh(mesh):
-                return segment_impl(seg_len, window, *args)
-
-        def serve_segment_meshed(seg_len, window, *args):
-            with use_mesh(mesh):
-                return serve_segment_impl(seg_len, window, *args)
-
-        def prefill_chunk0_meshed(w0, variables, tokens, true_len):
-            with use_mesh(mesh):
-                return prefill_chunk0_impl(w0, variables, tokens,
-                                           true_len)
-
-        def prefill_chunk_meshed(*args):
-            with use_mesh(mesh):
-                return prefill_chunk_impl(*args)
-
-        def prefill_finish_meshed(*args):
-            with use_mesh(mesh):
-                return prefill_finish_impl(*args)
-
-        def resume_init_meshed(w0, row_caches):
-            with use_mesh(mesh):
-                return resume_init_impl(w0, row_caches)
-
-        self._prefill = jax.jit(prefill_meshed)
-        self._segment = jax.jit(segment_meshed, static_argnums=(0, 1))
-        self._serve_segment = jax.jit(serve_segment_meshed,
-                                      static_argnums=(0, 1))
-        self._prefill_chunk0 = jax.jit(prefill_chunk0_meshed,
-                                       static_argnums=(0,))
-        self._prefill_chunk = jax.jit(prefill_chunk_meshed)
-        self._prefill_finish = jax.jit(prefill_finish_meshed)
-        self._resume_init = jax.jit(resume_init_meshed,
-                                    static_argnums=(0,))
+        self._prefill = jax.jit(meshed(prefill_impl, "prefill_meshed"))
+        self._segment = jax.jit(meshed(segment_impl, "segment_meshed"),
+                                static_argnums=(0, 1))
+        self._serve_segment = jax.jit(
+            meshed(serve_segment_impl, "serve_segment_meshed"),
+            static_argnums=(0, 1))
+        self._prefill_chunk0 = jax.jit(
+            meshed(prefill_chunk0_impl, "prefill_chunk0_meshed"),
+            static_argnums=(0,))
+        self._prefill_chunk = jax.jit(
+            meshed(prefill_chunk_impl, "prefill_chunk_meshed"))
+        self._prefill_finish = jax.jit(
+            meshed(prefill_finish_impl, "prefill_finish_meshed"))
+        self._resume_init = jax.jit(
+            meshed(resume_init_impl, "resume_init_meshed"),
+            static_argnums=(0,))
         if spec_tokens:
-            def draft_prefill_meshed(draft_variables, prompts):
-                with use_mesh(mesh):
-                    return draft_prefill_impl(draft_variables, prompts)
-
-            def spec_round_meshed(window, *args):
-                with use_mesh(mesh):
-                    return spec_round_impl(window, *args)
-
-            self._draft_prefill = jax.jit(draft_prefill_meshed)
-            self._spec_round = jax.jit(spec_round_meshed,
-                                       static_argnums=(0,))
+            self._draft_prefill = jax.jit(
+                meshed(draft_prefill_impl, "draft_prefill_meshed"))
+            self._spec_round = jax.jit(
+                meshed(spec_round_impl, "spec_round_meshed"),
+                static_argnums=(0,))
         self._programs: set = set()
         self._program_costs: dict = {}  # program key -> captured cost row
         # (captured once at the recompile; replayed into every later
@@ -2071,23 +1371,9 @@ class DecodeEngine:
         the bucket's first window (`draft`: the draft model's, always
         model-dtype K/V): the allocation the programs make, made for the
         serving engine's resident batch."""
-        window = _round_up(bucket + 1, self.chunk)
-        unhinted = lambda c: c
-        if draft:
-            return _kv_state(self.draft_module, rows, window, unhinted)
-        if self._decoding is not None:
-            return self._decoding.empty_state(rows, window)
-        if self.cache_dtype == "int8":
-            # int8 payloads + f32 per-(row, slot, head) scales, matching
-            # _quantize_cache's 4-tuple
-            m = self.module
-            shape = (rows, window, m.n_heads, m.d_model // m.n_heads)
-            return [(jnp.zeros(shape, jnp.int8),
-                     jnp.zeros(shape[:3], jnp.float32),
-                     jnp.zeros(shape, jnp.int8),
-                     jnp.zeros(shape[:3], jnp.float32))
-                    for _ in range(m.n_layers)]
-        return _kv_state(self.module, rows, window, unhinted)
+        decoding = self._draft_decoding if draft else self._decoding
+        return decoding.empty_state(rows, _round_up(bucket + 1, self.chunk),
+                                    resident=True)
 
     def state_bytes(self, caches) -> dict:
         """Bytes a state holds in its window layers and in its fixed
@@ -2918,7 +2204,7 @@ def naive_generate(module, variables, prompts, max_new_tokens: int) -> np.ndarra
     """Recompute-everything greedy decoding through the ordinary module
     forward — O(N * S^2) work, no cache.  The parity oracle for
     `generate`; never the product path."""
-    _check_generatable(module, _ENGINE_ARCHITECTURES, "naive_generate")
+    _check_generatable(module, tuple(_DECODINGS), "naive_generate")
     toks = jnp.asarray(prompts, jnp.int32)
     for _ in range(max_new_tokens):
         logits = module.apply(variables, toks)

@@ -42,8 +42,11 @@ from mmlspark_tpu.ops.moe import routed_experts
 NEG_INF = -1e30
 CONV, ATTENTION = "conv", "full_attention"
 WINDOW, FIXED = "window", "fixed"      # the two kinds of per-row state
-# prompt length from which a whole-prompt prefill runs the flash kernel
-# (models/generate.py keeps the same threshold for TransformerLM)
+# prompt length from which a whole-prompt prefill runs the pallas flash
+# kernel instead of the masked dense matmul, for every architecture: a long
+# prompt must not materialize the O(P^2) score tensor the flash path exists
+# to avoid; short ones stay on the dense path, whose f32 softmax is
+# bit-stable for the exact-parity tests
 PREFILL_FLASH_MIN = 512
 
 # what the decode programs count on the device, in this order (the keys
@@ -121,6 +124,10 @@ def short_conv(p: dict, h: jax.Array, state, view: Optional[StateView],
 
 
 def _row_write(cache: jax.Array, update: jax.Array, slots: jax.Array):
+    """Write `update` (B, S, ...) into `cache` (B, W, ...) from a PER-ROW
+    start slot `slots` (B,) on: vmap of the single-row
+    dynamic_update_slice over the batch axis (S is 1 for a decode step,
+    the verify segment's length under speculation)."""
     zeros = (0,) * (cache.ndim - 2)
     return jax.vmap(lambda c, u, s: lax.dynamic_update_slice(
         c, u, (s,) + zeros))(cache, update, slots)
@@ -368,22 +375,49 @@ class HybridLM(nn.Module):
         return head(self, params, x)
 
 
-class HybridDecoding:
-    """What `DecodeEngine` asks of a `HybridLM`, once, when it is built:
-    its layers' state kinds and shapes, and the three calls its programs
-    make.  Every call runs `hidden_states` above."""
+class Decoding:
+    """What `DecodeEngine` asks of a model, once, when it is built
+    (`generate._decoding_for`), with the answers of a model that counts
+    nothing on the device and whose state has one layout.  `cache_dtype`
+    is the layout segments carry the state in ('model' | 'int8'), `fused`
+    whether steps may read through the Pallas kernel, `hint` the sharding
+    hint of a state leaf on the engine's mesh (None: no mesh)."""
+
+    count_names = ()
+
+    def __init__(self, module, *, cache_dtype: str = "model",
+                 fused: bool = False, hint=None):
+        self.module = module
+        self.cache_dtype, self.fused = cache_dtype, fused
+        self.hint = hint or (lambda c: c)
+
+    def _same(self, state: list) -> list:
+        return state
+
+    # a prompt's state and a segment's are the state as it is
+    close_prompt = reopen_prompt = enter_segment = leave_segment = _same
+
+
+class HybridDecoding(Decoding):
+    """`Decoding` for a `HybridLM`: its layers' state kinds and shapes,
+    and the calls its programs make.  Every call runs `hidden_states`
+    above.  The state has one layout, the model dtype's, unhinted (the
+    engine refuses int8 state and a sharded window for a model with FIXED
+    layers)."""
 
     count_names = COUNT_NAMES
 
-    def __init__(self, module: HybridLM):
-        self.module = module
+    def __init__(self, module: HybridLM, **how):
+        super().__init__(module, **how)
         self.state_kinds = tuple(
             FIXED if kind == CONV else WINDOW for kind in module.layer_types)
 
-    def empty_state(self, rows: int, window: int) -> list:
+    def empty_state(self, rows: int, window: int,
+                    resident: bool = False) -> list:
         """Zero state for `rows` rows: a window layer's K and V, (rows,
         window, n_kv_heads, D) each, or a fixed layer's last K-1
-        convolution columns, (rows, K-1, d)."""
+        convolution columns, (rows, K-1, d).  The same for a prompt and
+        for a `resident` batch."""
         m = self.module
         dh = m.d_model // m.n_heads
         kv = (rows, window, m.n_kv_heads, dh)
@@ -464,6 +498,9 @@ class HybridDecoding:
             self.module, params, tok[:, None], pos[:, None], state, view)
         return (head(self.module, params, x[:, 0]), state,
                 (self._step_counts(loads),))
+
+    # the uniform-slot step is the per-row step with equal slots
+    run_step = run_step_rows
 
     def head(self, params, x):
         return head(self.module, params, x)

@@ -38,8 +38,8 @@ A leaf is quantized iff it is named ``kernel``, is floating, and has rank
 2 (Dense) or 4 (2-D Conv); everything else floating becomes bfloat16.
 The whole ``moe`` subtree (expert stacks AND router, ops/moe.py)
 deliberately does NOT int8-quantize — decode re-applies the real MoEMLP
-module against the raw tree (models/generate.py::_mlp) and must keep
-seeing plain float kernels.
+module against the raw tree (models/transformer_decoding.py::_mlp) and
+must keep seeing plain float kernels.
 
 KV-cache quantization (`quantize_kv`) is the activation-side counterpart:
 per-head symmetric int8, quantize-on-write inside the decode step, dequant
@@ -121,8 +121,9 @@ def _quantize_tree(tree: dict, mode: str, stats: dict,
     for k, v in tree.items():
         if isinstance(v, dict):
             # the whole `moe` subtree stays float: decode re-applies the
-            # real MoEMLP module against these params (generate.py::_mlp),
-            # which must keep seeing plain kernels (router included)
+            # real MoEMLP module against these params
+            # (transformer_decoding.py::_mlp), which must keep seeing
+            # plain kernels (router included)
             out[k] = _quantize_tree(v, mode, stats,
                                     int8_ok and k != "moe")
             continue

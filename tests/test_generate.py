@@ -77,7 +77,7 @@ def test_bf16_decode_logits_match_module_forward():
     agree with module.apply to bfloat16 rounding (decode accumulates
     attention in f32 — see module docstring — so exact bit parity is not
     the contract; closeness at bf16 resolution is)."""
-    from mmlspark_tpu.models.generate import _forward_with_cache
+    from mmlspark_tpu.models.transformer_decoding import forward_with_cache
 
     lm = build_model("TransformerLM", dict(CFG, dtype="bfloat16"))
     toks = jnp.asarray(np.random.default_rng(1).integers(0, 32, (2, 8)),
@@ -87,7 +87,7 @@ def test_bf16_decode_logits_match_module_forward():
     caches = [(jnp.zeros((2, CFG["max_len"], 4, 8), jnp.bfloat16),
                jnp.zeros((2, CFG["max_len"], 4, 8), jnp.bfloat16))
               for _ in range(CFG["n_layers"])]
-    got, _ = _forward_with_cache(variables["params"], toks, caches, 0, lm)
+    got, _ = forward_with_cache(variables["params"], toks, caches, 0, lm)
     np.testing.assert_allclose(np.asarray(got), ref, rtol=0.05, atol=0.05)
 
 
@@ -121,7 +121,7 @@ def test_moe_decode_prefill_matches_module_forward():
     """MoE blocks decode: the prefill forward re-applies the REAL MoEMLP
     per layer, so its logits equal module.apply exactly (same token group,
     same capacity arithmetic)."""
-    from mmlspark_tpu.models.generate import _forward_with_cache
+    from mmlspark_tpu.models.transformer_decoding import forward_with_cache
 
     moe = build_model("TransformerLM", dict(
         CFG, mlp_impl="moe", n_experts=4, moe_router_k=2))
@@ -132,7 +132,7 @@ def test_moe_decode_prefill_matches_module_forward():
     caches = [(jnp.zeros((3, CFG["max_len"], 4, 8), jnp.float32),
                jnp.zeros((3, CFG["max_len"], 4, 8), jnp.float32))
               for _ in range(CFG["n_layers"])]
-    got, _ = _forward_with_cache(variables["params"], toks, caches, 0, moe)
+    got, _ = forward_with_cache(variables["params"], toks, caches, 0, moe)
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-5, atol=2e-5)
 
 
@@ -143,7 +143,7 @@ def test_moe_greedy_decode_matches_naive():
     full-sequence routing exactly.  With larger groups the two can
     legitimately diverge under capacity pressure — the capacity drop is a
     BATCH-level training construct a stepwise decoder cannot reproduce
-    (documented in models/generate.py::_mlp)."""
+    (documented in models/transformer_decoding.py::_mlp)."""
     moe = build_model("TransformerLM", dict(CFG, mlp_impl="moe",
                                             n_experts=2, moe_group_size=1))
     toks = np.asarray([[3, 1, 4, 1]], np.int32)
@@ -182,16 +182,16 @@ def test_generates_from_pipeline_trained_bundle():
 
 @pytest.mark.slow
 def test_long_prompt_prefill_uses_flash_and_matches_dense():
-    """Prefill at >= _PREFILL_FLASH_MIN tokens routes through the flash
+    """Prefill at >= PREFILL_FLASH_MIN tokens routes through the flash
     kernel (no O(P^2) score tensor); its logits match the module's dense
     forward to online-softmax rounding, the public jit-once generation
     program runs end to end at that prompt length, and no dense fallback
     fires (which would silently re-materialize the scores)."""
     import mmlspark_tpu.ops.flash_attention as fa
-    from mmlspark_tpu.models.generate import (_PREFILL_FLASH_MIN,
-                                              _forward_with_cache)
+    from mmlspark_tpu.models.hybrid_lm import PREFILL_FLASH_MIN
+    from mmlspark_tpu.models.transformer_decoding import forward_with_cache
 
-    P = _PREFILL_FLASH_MIN
+    P = PREFILL_FLASH_MIN
     cfg = {"vocab_size": 32, "d_model": 16, "n_heads": 2, "n_layers": 1,
            "max_len": P + 8, "dtype": "float32"}
     lm = build_model("TransformerLM", cfg)
@@ -201,8 +201,8 @@ def test_long_prompt_prefill_uses_flash_and_matches_dense():
     ref = np.asarray(lm.apply(variables, toks))
     caches = [(jnp.zeros((1, P + 8, 2, 8), jnp.float32),
                jnp.zeros((1, P + 8, 2, 8), jnp.float32))]
-    got, new_caches = _forward_with_cache(variables["params"], toks,
-                                          caches, 0, lm)
+    got, new_caches = forward_with_cache(variables["params"], toks,
+                                         caches, 0, lm)
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-4, atol=2e-4)
     # the cache was still written for the decode steps that follow
     assert float(jnp.abs(new_caches[0][0][0, :P]).sum()) > 0

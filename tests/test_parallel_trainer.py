@@ -6,8 +6,6 @@ Round-trip contract on the CPU mesh, for both families:
 fit -> checkpoint -> restore -> bundle -> TPUModel scoring.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,8 +79,10 @@ def test_pp_stage_weights_sharded_in_state(pp_trainer_run):
 
 
 def test_pp_checkpoint_restore_roundtrip(pp_trainer_run):
+    from mmlspark_tpu.resilience.checkpoints import latest_valid_checkpoint
+
     trainer, _, ckpt, _ = pp_trainer_run
-    assert os.path.exists(os.path.join(ckpt, "checkpoint.msgpack"))
+    assert latest_valid_checkpoint(ckpt) is not None
     state = trainer._last_state
     restored = trainer.restore_checkpoint(state, ckpt)
     assert int(restored.step) == int(state.step)

@@ -34,6 +34,14 @@ Deadline math runs on the injectable resilience clock — the whole
 scheduler is testable with a `VirtualClock` and zero sleeps by calling
 `_tick()` directly (the loop thread, spawned by serve/lifecycle.py, is
 just `_tick` + a condition wait).
+
+Groups take TURNS inside a pass: harvest the group's segment in flight,
+cancel, join, dispatch its next segment — and the fetch of that segment
+waits for the group's next turn, so with two live groups the device
+always has the other group's program queued or running while the host
+works on this one (`_Flight`).  `_tick()` still ends with every segment
+it dispatched harvested; only the loop thread carries them from one pass
+into the next.
 """
 
 from __future__ import annotations
@@ -290,6 +298,25 @@ class _Group:
         self.true_len[slot] = 1
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A dispatched segment whose results are still on the device: what
+    its harvest needs.  The group's host row state (`tok`, `done`,
+    `t_row`, ...) is the dispatch's own until then — nothing seats,
+    releases or cancels a row of a group in flight."""
+
+    group: _Group
+    lane: str
+    toks: object                   # device handles of the program
+    tok: object
+    done: object
+    counts: list                   # its device counts (`_take_counts`)
+    live: list                     # slots resident at the dispatch
+    read: int                      # cache width the segment reads
+    dispatched: float              # `monotonic()` before the call
+    own_s: Optional[float] = None  # `_ended`, once its end was seen
+
+
 # engine lifecycle states
 CREATED, READY, DRAINING, STOPPED = "created", "ready", "draining", "stopped"
 
@@ -405,8 +432,13 @@ class ServingEngine:
             if self.cfg.prefix_cache else None)
         self._groups: dict[tuple, _Group] = {}
         # in-flight chunked prefills: one advances a single chunk per
-        # tick, between phase 4 (joins) and phase 5 (segments)
+        # tick, in its group's turn, between the joins and the segment
         self._pending: list[dict] = []
+        # segments dispatched and not yet harvested, in dispatch order
+        # (the device's own order), and when the last program seen to
+        # end did: a program's own time starts there (`_ended`)
+        self._flights: list[_Flight] = []
+        self._device_free = 0.0
         self.role = self.cfg.role
         # prefill tier: the handoff bus (serve/handoff.py) wires this to
         # receive each finished cohort's (reqs, first tokens, caches)
@@ -797,23 +829,57 @@ class ServingEngine:
             for name, n in deltas.items():
                 self._counts[name] = self._counts.get(name, 0) + n
 
-    def _fetch(self, eng: DecodeEngine, *arrays) -> list:
+    @staticmethod
+    def _take_counts(eng: DecodeEngine) -> list:
+        """The device counts of the program `eng` dispatched last, taken
+        off its side channel: the next program of that engine overwrites
+        `counts_out`, so whoever will fetch a program owns its counts
+        from the dispatch on."""
+        counts, eng.counts_out = eng.counts_out, []
+        return counts
+
+    def _ended(self, dispatched: float) -> float:
+        """A program's results are ready: its own seconds, from the later
+        of its dispatch and the end of the program before it (the device
+        runs them in dispatch order, and so must the calls here)."""
+        now = monotonic()
+        own = now - max(dispatched, self._device_free)
+        self._device_free = now
+        return own
+
+    def _fetch(self, eng: DecodeEngine, *arrays, counts: list,
+               dispatched: float, flight: Optional[_Flight] = None) -> tuple:
         """Bring a program's results to the host: where the scheduler
-        thread waits for the device.  The enclosing prefill / segment
-        span's self time is then dispatch and argument upload, and this
-        child (`fetch_wait_s`) the wait.  What the program counted on the
-        device (`eng.counts_out`, under `eng.count_names`; nothing for a
-        model that counts nothing) comes in the same fetch and goes to
-        the engine's counters."""
+        thread waits for the device.  The enclosing prefill span's self
+        time is then dispatch and argument upload, and this span
+        (`fetch_wait_s`) the wait.  What the program counted on the
+        device (`counts`, its own from `_take_counts`, under
+        `eng.count_names`; nothing for a model that counts nothing) comes
+        in the same fetch and goes to the engine's counters.
+
+        Returns (host arrays, the program's own seconds).  A segment
+        still in flight that was dispatched before this program
+        (`flight`: the segment being harvested; none: a program just
+        dispatched) ends before it: its end is stamped first, so each
+        program's time is its own and not the queue's before it."""
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.fetch", cat="serve"):
+            for f in self._flights:
+                if f is flight:
+                    break
+                if f.own_s is None:
+                    jax.block_until_ready(f.done)
+                    f.own_s = self._ended(f.dispatched)
             out = [np.asarray(a) for a in arrays]
-            counted = [np.asarray(a) for a in eng.counts_out]
-            eng.counts_out = []          # counted once
+            counted = [np.asarray(a) for a in counts]
+        if flight is None or flight.own_s is None:
+            own = self._ended(dispatched)
+        else:
+            own = flight.own_s
         self._count("fetch_wait_s", monotonic() - t0)
         for values in counted:
             self._count_all(dict(zip(eng.count_names, values.tolist())))
-        return out
+        return out, own
 
     def _record_serve(self, event: dict) -> None:
         if self._run is not None:
@@ -865,7 +931,10 @@ class ServingEngine:
             for i in g.live_slots():
                 if g.rows[i] is req:
                     req.finish(CANCELLED, self.now(), detail)
-                    g.release(i)
+                    if not any(f.group is g for f in list(self._flights)):
+                        g.release(i)
+                    # else the row state is the segment's in flight: its
+                    # harvest frees the slot of a finished request
                     self._count("cancelled_external")
                     return True
         for job in list(self._pending):
@@ -954,17 +1023,23 @@ class ServingEngine:
         inc_counter(f"serve.{status}")
 
     # -- the scheduler pass ------------------------------------------------
-    def _tick(self) -> bool:
-        """One scheduler pass: expire, join, advance every group one
-        segment, harvest.  Returns True when any work was done (the loop
-        idles on False).  Synchronous and sleep-free: tests drive it
-        directly under a VirtualClock."""
+    def _tick(self, carry: bool = False) -> bool:
+        """One scheduler pass: expire, then each group's turn (harvest,
+        cancel, join, dispatch its next segment).  Returns True when any
+        work was done (the loop idles on False).  When it returns, every
+        segment it dispatched is harvested — synchronous and sleep-free:
+        tests drive it directly under a VirtualClock — unless `carry`
+        (the loop thread's own): the segments then stay in flight into
+        the next pass, each group's until its next turn, so the device
+        works on one group while this thread works on the other."""
         t0 = monotonic()
         # profiler-only (no tracer handle), like `serve.idle_wait`: an
         # idle engine passes here a hundred times a second, which would
         # scroll the run's ring of records
         with span_on_tracer(None, "serve.tick"):
             worked = self._pass()
+            if not carry:
+                self._harvest_all()
         self._count("tick_s", monotonic() - t0)
         return worked
 
@@ -983,7 +1058,9 @@ class ServingEngine:
             self._complete(req, TIMEOUT, "expired in queue")
             worked = True
         # 2. drain-deadline enforcement: past it, cancel everything left
+        # (what the segments in flight produced still reaches its rows)
         if self._state == DRAINING and now >= (self._drain_deadline or 0):
+            worked = self._harvest_all() or worked
             for g in self._groups.values():
                 for i in g.live_slots():
                     self._complete(g.rows[i], CANCELLED,
@@ -1001,58 +1078,68 @@ class ServingEngine:
                 worked = True
             self._groups.clear()
             return worked
-        # 3. cancel expired resident rows at the boundary
-        for g in self._groups.values():
-            for i in g.live_slots():
-                req = g.rows[i]
-                if req.deadline <= now:
-                    self._complete(req, TIMEOUT, "cancelled at boundary")
-                    trace_event("serve.cancel", cat="serve",
-                                request=req.id, at_step=int(g.t_row[i]))
-                    g.release(i)
-                    worked = True
-        # 4. joins: pull queued work into free slots, bucket by bucket
-        for bucket, lane in self.admission.queued_buckets():
-            g = self._groups.get((bucket, lane))
-            if g is None:
-                g = self._groups[(bucket, lane)] = _Group(
+        # 3. a group for every (bucket, lane) with queued work
+        queued = self.admission.queued_buckets()
+        for bucket, lane in queued:
+            if (bucket, lane) not in self._groups:
+                self._groups[(bucket, lane)] = _Group(
                     bucket, self.cfg.max_batch)
-            free = g.free_slots()
-            if not free:
-                continue
-            reqs = self._take(bucket, len(free), lane)
-            if reqs:
-                slots = free[:len(reqs)]
-                if self._prefix is not None and lane == "primary":
-                    # peel prefix-pool hits off the cohort: each resumes
-                    # from its donor rows (only the novel suffix
-                    # prefills); misses keep the normal cohort path
-                    reqs, slots = self._join_prefix_hits(g, lane, reqs,
-                                                         slots)
-                if reqs:
-                    if self._engines[lane].serve_prefill_chunks(bucket):
-                        self._start_chunked_join(g, lane, reqs, slots)
-                    else:
-                        self._join(g, lane, reqs, slots)
-                worked = True
-        # 4b. advance every in-flight chunked prefill by ONE chunk — the
-        # point of chunking: the long forward yields to phase 5 between
-        # chunks instead of holding the tick for the whole prompt
-        for job in list(self._pending):
-            self._advance_prefill(job)
-            worked = True
-        # 5. advance each group one segment
+        # 4. the groups take turns
         for (bucket, lane), g in list(self._groups.items()):
-            if g.live_slots():
-                self._advance(g, lane)
+            if self._turn(g, lane, now, (bucket, lane) in queued):
                 worked = True
-            elif (not g.reserved and not self.admission.pending()):
+            if not (g.live_slots() or g.reserved
+                    or self.admission.pending()):
                 # empty group with no queued work: drop the cache memory
                 del self._groups[(bucket, lane)]
         return worked
 
+    def _turn(self, g: _Group, lane: str, now: float, queued: bool) -> bool:
+        """One group's turn: harvest its segment in flight, cancel its
+        expired rows, join queued work into its free slots, run one chunk
+        of each of its chunked prefills, dispatch its next segment (a
+        speculative lane: one whole round).  The harvest comes first:
+        every later step writes the row state that it overwrites.  True
+        when anything was done."""
+        worked = self._harvest(g)
+        # cancel expired resident rows at the boundary
+        for i in g.live_slots():
+            req = g.rows[i]
+            if req.deadline <= now:
+                self._complete(req, TIMEOUT, "cancelled at boundary")
+                trace_event("serve.cancel", cat="serve",
+                            request=req.id, at_step=int(g.t_row[i]))
+                g.release(i)
+                worked = True
+        # joins: pull queued work into free slots
+        free = g.free_slots() if queued else []
+        reqs = self._take(g.bucket, len(free), lane) if free else []
+        if reqs:
+            slots = free[:len(reqs)]
+            if self._prefix is not None and lane == "primary":
+                # peel prefix-pool hits off the cohort: each resumes
+                # from its donor rows (only the novel suffix
+                # prefills); misses keep the normal cohort path
+                reqs, slots = self._join_prefix_hits(g, lane, reqs, slots)
+            if reqs:
+                if self._engines[lane].serve_prefill_chunks(g.bucket):
+                    self._start_chunked_join(g, lane, reqs, slots)
+                else:
+                    self._join(g, lane, reqs, slots)
+            worked = True
+        # advance each of its in-flight chunked prefills by ONE chunk —
+        # the point of chunking: the long forward yields to the segment
+        # between chunks instead of holding the tick for the whole prompt
+        for job in [j for j in self._pending if j["group"] is g]:
+            self._advance_prefill(job)
+            worked = True
+        if g.live_slots():
+            self._advance(g, lane)
+            worked = True
+        return worked
+
     def _take(self, bucket: int, n: int, lane: str) -> list:
-        """Step 4's pull from the admission queue; the wait of each
+        """A turn's pull from the admission queue; the wait of each
         request taken ends here (`joined`, `queue_wait_s`)."""
         with span_on_tracer(self._tracer, "serve.admit", cat="serve",
                             bucket=bucket, lane=lane):
@@ -1101,10 +1188,10 @@ class ServingEngine:
                             joins=len(reqs), lane=lane):
             tok, done, caches = eng.serve_prefill(
                 variables, prompts, true_len, live, self._row_keys(ids))
-            [tok_h] = self._fetch(eng, tok)
-        elapsed = monotonic() - t0
-        self._count("prefill_s", elapsed)
-        self.estimator.observe_prefill(g.bucket, elapsed)
+            [tok_h], own = self._fetch(
+                eng, tok, counts=self._take_counts(eng), dispatched=t0)
+        self._count("prefill_s", monotonic() - t0)
+        self.estimator.observe_prefill(g.bucket, own)
         self._splice(g, lane, reqs, slots, list(range(len(reqs))),
                      tok_h, caches, prompts)
 
@@ -1167,10 +1254,10 @@ class ServingEngine:
                     variables, prompts, true_len, matched,
                     _assemble_prefix_row(hit.rows), np.ones(1, bool),
                     self._row_keys(ids))
-                [tok_h] = self._fetch(eng, tok)
-            elapsed = monotonic() - t0
-            self._count("prefill_s", elapsed)
-            self.estimator.observe_prefill(g.bucket, elapsed)
+                [tok_h], own = self._fetch(
+                    eng, tok, counts=self._take_counts(eng), dispatched=t0)
+            self._count("prefill_s", monotonic() - t0)
+            self.estimator.observe_prefill(g.bucket, own)
             self._splice(g, lane, [req], [slot], [0], tok_h, caches,
                          prompts)
         finally:
@@ -1264,10 +1351,10 @@ class ServingEngine:
         t0 = monotonic()
         tok, done, caches = eng.serve_prefill_finish(
             job["state"], job["live"], self._row_keys(job["ids"]))
-        [tok_h] = self._fetch(eng, tok)
-        elapsed = monotonic() - t0
-        self._count("prefill_s", elapsed)
-        job["elapsed"] += elapsed
+        [tok_h], own = self._fetch(
+            eng, tok, counts=self._take_counts(eng), dispatched=t0)
+        self._count("prefill_s", monotonic() - t0)
+        job["elapsed"] += own
         self.estimator.observe_prefill(g.bucket, job["elapsed"])
         # requests whose deadline passed while their prompt was still
         # chunking: finish as timeouts, splice only the survivors
@@ -1398,6 +1485,7 @@ class ServingEngine:
         if g is None:
             g = self._groups[(bucket, lane)] = _Group(
                 bucket, self.cfg.max_batch)
+        self._harvest(g)       # before a row of the group is written
         free = g.free_slots()
         if not free:
             return None
@@ -1458,8 +1546,9 @@ class ServingEngine:
             req.note_tokens()
 
     def _advance(self, g: _Group, lane: str) -> None:
-        """Run one mixed-age segment (or, on speculative lanes, one
-        draft-verify round) for a group and harvest the results."""
+        """Dispatch one mixed-age segment for a group, its harvest left
+        to the group's next turn (`_harvest`) — or, on speculative lanes,
+        run one whole draft-verify round."""
         if self._engines[lane].spec_tokens:
             self._advance_spec(g, lane)
             return
@@ -1472,25 +1561,50 @@ class ServingEngine:
         # the segment reads the whole cache width for every slot, whatever
         # the masks (a resident cache never shrinks: `serve_step`)
         read = max(window, eng.state_window(g.caches))
+        # overlapped: behind another program of this engine that nobody
+        # has fetched yet (a prefill is fetched where it is dispatched, so
+        # that is another group's segment)
+        overlapped = any(f.lane == lane for f in self._flights)
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.segment", cat="serve",
                             bucket=g.bucket, lane=lane, seg_len=seg,
                             window=window, occupancy=round(
                                 len(live) / g.capacity, 3)):
-            caches, toks, tok, done = eng.serve_step(
+            g.caches, toks, tok, done = eng.serve_step(
                 variables, g.caches, np.asarray(g.tok),
                 np.asarray(g.done), g.true_len, g.budget, g.bucket,
                 g.t_row, self._group_keys(g), seg, window)
-            toks_h, tok_h, done_h = self._fetch(eng, toks, tok, done)
-        elapsed = monotonic() - t0
-        self.estimator.observe_step(g.bucket, elapsed / seg)
+        self._flights.append(_Flight(
+            g, lane, toks, tok, done, self._take_counts(eng), live, read,
+            t0))
+        self._count_all({"segments_dispatched": 1,
+                         "segments_overlapped": int(overlapped)})
+
+    def _harvest_all(self) -> bool:
+        """Harvest every segment in flight, oldest first."""
+        return any([self._harvest(f.group) for f in list(self._flights)])
+
+    def _harvest(self, g: _Group) -> bool:
+        """Fetch the segment `g` has in flight, if any, and hand its
+        tokens to the rows: emit, complete, free.  True when it had
+        one."""
+        f = next((f for f in self._flights if f.group is g), None)
+        if f is None:
+            return False
+        eng = self._engines[f.lane]
+        seg, live = self.cfg.segment_steps, f.live
+        (toks_h, tok_h, done_h), own = self._fetch(
+            eng, f.toks, f.tok, f.done, counts=f.counts,
+            dispatched=f.dispatched, flight=f)
+        self._flights.remove(f)
+        self.estimator.observe_step(g.bucket, own / seg)
         self._record_serve({"event": "segment", "bucket": g.bucket,
-                            "lane": lane, "rows": len(live)})
+                            "lane": f.lane, "rows": len(live)})
         if self._run is not None:
-            # per-token pacing: one sample per segment (segment wall over
-            # its decode steps), not per token — bounded-cost by design
-            self._run.observe_hist("serve.inter_token_s", elapsed / seg)
-        g.caches = caches
+            # per-token pacing: one sample per segment (the segment's own
+            # time over its decode steps), not per token — bounded-cost by
+            # design
+            self._run.observe_hist("serve.inter_token_s", own / seg)
         g.tok = tok_h.astype(np.int32)
         g.done = done_h.astype(bool)
         # a row is live for the steps whose tokens it keeps: one that
@@ -1502,7 +1616,10 @@ class ServingEngine:
                             bucket=g.bucket, rows=len(live)):
             for i in live:
                 req = g.rows[i]
-                if req is None:
+                if req is None or req.finished:
+                    # withdrawn while the segment ran (`cancel_request`,
+                    # a replica's `fail_inflight`): the slot is free
+                    g.release(i)
                     continue
                 had = len(req.tokens)
                 seen = int(g.true_len[i] + g.t_row[i])
@@ -1516,11 +1633,12 @@ class ServingEngine:
             "slot_steps_live": steps_live,
             "slot_steps_capacity": g.capacity * seg,
             "decode_keys_live": keys_live,
-            "decode_keys_read": g.capacity * seg * read})
+            "decode_keys_read": g.capacity * seg * f.read})
         if self._run is not None:
             self._run.gauge("serve.queue_depth", self.admission.pending())
             self._run.gauge("serve.in_flight", self.in_flight())
             self._gauge_prefix()
+        return True
 
     def _advance_spec(self, g: _Group, lane: str) -> None:
         """One speculative round: the draft proposes, one target forward
@@ -1544,9 +1662,9 @@ class ServingEngine:
                 np.asarray(g.tok), np.asarray(g.done), g.true_len,
                 g.budget, g.bucket, g.t_row, g.spec_rounds,
                 self._group_keys(g), window)
-            toks_h, counts_h, tok_h, done_h, accepted_h = self._fetch(
-                eng, toks, counts, tok, done, accepted)
-        elapsed = monotonic() - t0
+            (toks_h, counts_h, tok_h, done_h, accepted_h), elapsed = (
+                self._fetch(eng, toks, counts, tok, done, accepted,
+                            counts=self._take_counts(eng), dispatched=t0))
         g.spec_rounds += 1
         emitted = int(counts_h[live].sum())
         per_row = emitted / max(1, len(live))
@@ -1587,15 +1705,16 @@ class ServingEngine:
                 and self.admission.pending() == 0)
 
     def _loop(self) -> None:
-        """The scheduler thread body: tick, check the SIGTERM guard,
-        idle on the condition when there is no work."""
+        """The scheduler thread body: tick (the segments it dispatches
+        carried into the next pass), check the SIGTERM guard, idle on
+        the condition when there is no work."""
         while True:
             if (self._guard is not None and self._guard.triggered
                     and self._state == READY):
                 self.begin_drain("sigterm")
             if self._state == STOPPED:
                 return
-            worked = self._tick()
+            worked = self._tick(carry=True)
             if self._drained():
                 self._finish_drain()
                 return

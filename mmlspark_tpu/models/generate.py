@@ -523,9 +523,12 @@ def _grow_state(caches: list, window: int, kinds=None,
                 hint=_hint_kv) -> list:
     """`_grow_cache` over every layer's leaves.  `kinds` names each
     layer's state kind (None: every layer a window, TransformerLM's): a
-    FIXED layer's leaves are row-indexed only and pass through."""
+    FIXED layer's leaves are row-indexed only and pass through.  A window
+    leaf narrower than its layer's first (an indexer's compressed keys, one
+    a stride of slots) keeps its share of the window."""
     return [layer if kinds is not None and kinds[i] == FIXED
-            else tuple(hint(_grow_cache(c, window)) for c in layer)
+            else tuple(hint(_grow_cache(
+                c, window * c.shape[1] // layer[0].shape[1])) for c in layer)
             for i, layer in enumerate(caches)]
 
 

@@ -1,5 +1,6 @@
-"""A decoder whose layers are a per-layer list: short-convolution and
-grouped-attention mixers over dense or routed-expert gated MLPs.
+"""A decoder whose layers are a per-layer list: short-convolution,
+grouped-attention, block-sparse-attention and linear-attention mixers over
+dense or routed-expert gated MLPs.
 
 `HybridLM` is the registered architecture (`build_model("HybridLM", ...)`).
 Its layers are the plain functions below, each `(params, x, ..., state
@@ -7,9 +8,12 @@ view) -> (y, new state)`; the flax module (for `init`, `Trainer`,
 `TPUModel`) and the decode programs of `models/generate.py` (through
 `HybridDecoding`) call THESE functions, so a layer is stated once.
 
-    x0 = E[tokens]                                (no position table)
-    x  = x + mixer_i(norm(x));  x = x + ffn_i(norm(x))     per layer
-    logits = norm(x_L) @ E^T                      (head tied to E, or its own)
+    x0 = e * E[tokens]                            (no position table)
+    x  = x + a * mixer_i(norm(x));  x = x + a * ffn_i(norm(x))   per layer
+    logits = (l * norm(x_L)) @ E^T                (head tied to E, or its own)
+
+with the multipliers e, a, l (`embed_scale`, `residual_scale`,
+`logit_scale`) 1 unless the constructor says otherwise.
 
   * `norm` is RMSNorm: x * rsqrt(mean(x^2) + eps) * g, in float32.
   * a `conv` mixer: [B, C, z] = W_in h; u = B * z; c_t = sum_j k_j
@@ -20,6 +24,18 @@ view) -> (y, new state)`; the flax module (for `init`, `Trainer`,
     (half-split pairing) over the whole head; each KV head serves
     n_heads / n_kv_heads query heads; causal softmax.  A row carries a
     window of K and V.
+  * a `minicpm4` mixer: q, k, v as above without rotary; the row keeps a
+    window of K and V and, beside it, the indexer's cache of compressed
+    keys, a sixteenth as wide; each query reads the blocks
+    `ops/sparse_attention.read_blocks` selects; y = W_o (sigmoid(W_g h) *
+    o).  K and V are written at the token's POSITION, not at the slot the
+    engine names (the two differ once a right-padded prompt is decoded
+    from), so that a block holds the same tokens wherever it is read.
+  * a `lightning-attn` mixer: q, k, v of `n_heads` heads each; RMSNorm
+    over each head of q and k, rotary, q / sqrt(D); the decayed linear
+    recurrence of `ops/linear_attention`; RMSNorm over the concatenated
+    heads; y = W_o (sigmoid(W_g h) * o).  A row carries S (H, D, D) in
+    float32, taken at its true length.
   * the first `n_dense_layers` layers have a gated MLP W2 (silu(W1 h) *
     W3 h); every other layer has routed experts (`ops/moe.routed_experts`:
     sigmoid scores, a selection bias, top-k, renormalised, dropless).
@@ -37,10 +53,14 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
+from mmlspark_tpu.ops import sparse_attention as sparse
+from mmlspark_tpu.ops.linear_attention import decay_slopes, linear_attention
 from mmlspark_tpu.ops.moe import routed_experts
 
 NEG_INF = -1e30
 CONV, ATTENTION = "conv", "full_attention"
+SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
+MIXERS = (CONV, ATTENTION, SPARSE, LIGHTNING)
 WINDOW, FIXED = "window", "fixed"      # the two kinds of per-row state
 # prompt length from which a whole-prompt prefill runs the pallas flash
 # kernel instead of the masked dense matmul, for every architecture: a long
@@ -53,6 +73,14 @@ PREFILL_FLASH_MIN = 512
 # `ServingEngine.stats()` gains)
 COUNT_NAMES = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
                "moe_load_max", "moe_load_mean")
+# and of a model with `minicpm4` or `lightning-attn` layers: keys a sparse
+# layer's queries could read and did read (decode steps of live rows, one KV
+# head's, per layer; `_prompt_`: the same of a prefill's true tokens), the
+# steps answered on the `dense_len` path, and the tokens a linear state
+# took in (a prompt's and a decoded one, per layer)
+SPARSE_COUNT_NAMES = ("sparse_keys_visible", "sparse_keys_read",
+                      "sparse_prompt_keys_visible", "sparse_prompt_keys_read",
+                      "sparse_dense_steps", "linear_state_steps")
 
 
 @dataclasses.dataclass
@@ -65,13 +93,23 @@ class StateView:
     the slots each query may read, or None for "causal over the segment
     itself" (a whole prompt from slot 0).  `n_valid`: `(B,)`, how many of
     the segment's tokens are the row's own (the rest is bucket padding):
-    the convolution state is taken there, at the row's true length.
-    `valid`: `(B, S)` bool, the tokens the counters count."""
+    the convolution state and the linear-attention state are taken
+    there, at the row's true length.  `valid`: `(B, S)` bool, the tokens
+    the counters count.  `counts`: what the mixers of this call counted
+    ({name of SPARSE_COUNT_NAMES: sum so far}), read once the layers
+    ran."""
 
     write_at: Any
     visible: Optional[jax.Array]
     n_valid: jax.Array
     valid: Optional[jax.Array] = None
+    counts: dict = dataclasses.field(default_factory=dict)
+
+    def count(self, name: str, values: jax.Array) -> None:
+        """Add `values` (B, S) at the counted tokens to `name`."""
+        if self.valid is not None:
+            self.counts[name] = self.counts.get(name, 0.0) + jnp.sum(
+                jnp.where(self.valid, values.astype(jnp.float32), 0.0))
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float, dtype) -> jax.Array:
@@ -195,6 +233,93 @@ def grouped_attention(p: dict, h: jax.Array, positions: jax.Array, state,
                 (k_cache, v_cache))
 
 
+def _gated_out(p: dict, h: jax.Array, o: jax.Array, dtype) -> jax.Array:
+    """y = W_o (sigmoid(W_g h) * o), o (B, S, d) float32: the output gate
+    of the `minicpm4` and `lightning-attn` mixers."""
+    with jax.named_scope("attn.gate"):
+        gate = jax.nn.sigmoid(_dot(h, p["wg"], dtype).astype(jnp.float32))
+        return _dot(gate * o, p["wo"], dtype)
+
+
+def block_sparse_attention(p: dict, h: jax.Array, positions: jax.Array,
+                           state, view: Optional[StateView], *, n_heads: int,
+                           n_kv_heads: int, cfg: sparse.Sparse, eps: float,
+                           dtype):
+    """Block-sparse attention over normalized `h` (B, S, d); `state` is the
+    row's K and V windows, (B, W, n_kv_heads, D) each, and its compressed
+    keys, (B, W / stride, n_kv_heads, D) float32; or None."""
+    b, s, d = h.shape
+    dh = d // n_heads
+    q = rms_norm(_dot(h, p["wq"], dtype).reshape(b, s, n_heads, dh),
+                 p["q_norm"], eps, dtype)
+    k = rms_norm(_dot(h, p["wk"], dtype).reshape(b, s, n_kv_heads, dh),
+                 p["k_norm"], eps, dtype)
+    v = _dot(h, p["wv"], dtype).reshape(b, s, n_kv_heads, dh)
+    scale = dh ** -0.5
+    if state is None:
+        # a plain forward: a window of its own, in whole blocks
+        w = -(-s // cfg.block) * cfg.block
+        zeros = lambda width, of: jnp.zeros((b, width, n_kv_heads, dh), of)
+        k_cache, v_cache = zeros(w, dtype), zeros(w, dtype)
+        kc, at = zeros(w // cfg.stride, jnp.float32), 0
+    else:
+        (k_cache, v_cache, kc), at = state, view.write_at
+    if jnp.ndim(at) == 0:
+        # a prompt segment: every row from slot `at` on, its position
+        k_cache = lax.dynamic_update_slice(
+            k_cache, k.astype(k_cache.dtype), (0, at, 0, 0))
+        v_cache = lax.dynamic_update_slice(
+            v_cache, v.astype(v_cache.dtype), (0, at, 0, 0))
+        kc = jax.vmap(lambda c, keys: sparse.compress_row(
+            c, keys, at, s, cfg))(kc, k_cache)
+        o, n_read = sparse.attend_masked(q, k_cache, v_cache, kc,
+                                         positions[0], cfg, scale)
+        names = ("sparse_prompt_keys_visible", "sparse_prompt_keys_read")
+    else:
+        # a decode step: row r writes at ITS position (the slot the engine
+        # names lies past the bucket's padding)
+        if s != 1:
+            raise ValueError("a minicpm4 layer steps one token a row")
+        at = positions[:, 0]
+        k_cache = _row_write(k_cache, k.astype(k_cache.dtype), at)
+        v_cache = _row_write(v_cache, v.astype(v_cache.dtype), at)
+        kc = jax.vmap(lambda c, keys, a: sparse.compress_row(
+            c, keys, a, 1, cfg))(kc, k_cache, at)
+        o, n_read = sparse.attend_step(q, k_cache, v_cache, kc, positions,
+                                       cfg, scale)
+        names = ("sparse_keys_visible", "sparse_keys_read")
+        view.count("sparse_dense_steps", positions + 1 <= cfg.dense_len)
+    if view is not None:
+        view.count(names[0], positions + 1)
+        view.count(names[1], n_read)
+    y = _gated_out(p, h, o.reshape(b, s, d), dtype)
+    return y, None if state is None else (k_cache, v_cache, kc)
+
+
+def lightning_attention(p: dict, h: jax.Array, positions: jax.Array, state,
+                        view: Optional[StateView], *, n_heads: int,
+                        rope_theta: float, eps: float, dtype):
+    """Decayed linear attention over normalized `h` (B, S, d); `state` is
+    the row's S (B, n_heads, D, D) float32, or None."""
+    b, s, d = h.shape
+    dh = d // n_heads
+    heads = lambda name: _dot(h, p[name], dtype).reshape(b, s, n_heads, dh)
+    q = rotary(rms_norm(heads("wq"), p["q_norm"], eps, dtype), positions,
+               rope_theta)
+    k = rotary(rms_norm(heads("wk"), p["k_norm"], eps, dtype), positions,
+               rope_theta)
+    q = (q.astype(jnp.float32) * dh ** -0.5).astype(dtype)
+    o, carried = linear_attention(
+        q, k, heads("wv"), decay_slopes(n_heads),
+        jnp.zeros((b, n_heads, dh, dh), jnp.float32) if state is None
+        else state,
+        jnp.full((b,), s) if view is None else view.n_valid)
+    if view is not None:
+        view.count("linear_state_steps", jnp.ones((b, s)))
+    o = rms_norm(o.reshape(b, s, d), p["o_norm"], eps, jnp.float32)
+    return _gated_out(p, h, o, dtype), None if state is None else carried
+
+
 def gated_mlp(p: dict, h: jax.Array, dtype) -> jax.Array:
     return _dot(jax.nn.silu(_dot(h, p["w1"], dtype))
                 * _dot(h, p["w3"], dtype), p["w2"], dtype)
@@ -215,13 +340,18 @@ def layer_params_shapes(module, i: int) -> dict:
     d = module.d_model
     dh = d // module.n_heads
     shapes = {"op_norm": (d,), "ffn_norm": (d,)}
-    if module.layer_types[i] == CONV:
+    kind = module.layer_types[i]
+    if kind == CONV:
         shapes.update(conv_in=(d, 3 * d), conv_taps=(module.conv_kernel, d),
                       conv_out=(d, d))
     else:
-        kv = module.n_kv_heads * dh
+        kv = d if kind == LIGHTNING else module.n_kv_heads * dh
         shapes.update(wq=(d, d), wk=(d, kv), wv=(d, kv), wo=(d, d),
                       q_norm=(dh,), k_norm=(dh,))
+        if kind != ATTENTION:
+            shapes.update(wg=(d, d))
+        if kind == LIGHTNING:
+            shapes.update(o_norm=(d,))
     if i < module.n_dense_layers:
         w = module.mlp_width
         shapes.update(w1=(d, w), w3=(d, w), w2=(w, d))
@@ -230,6 +360,21 @@ def layer_params_shapes(module, i: int) -> dict:
         shapes.update(router=(d, e), expert_bias=(e,), w1=(e, d, w),
                       w3=(e, d, w), w2=(e, w, d))
     return shapes
+
+
+def _gated_mixer(module, i: int, p: dict, h: jax.Array, positions, state,
+                 view: Optional[StateView]):
+    """Layer i's `minicpm4` or `lightning-attn` mixer."""
+    if module.layer_types[i] == SPARSE:
+        return block_sparse_attention(
+            p, h, positions, state, view, n_heads=module.n_heads,
+            n_kv_heads=module.n_kv_heads, cfg=module.sparse_cfg,
+            eps=module.norm_eps, dtype=module.dtype)
+    y, state = lightning_attention(
+        p, h, positions, None if state is None else state[0], view,
+        n_heads=module.n_heads, rope_theta=module.rope_theta,
+        eps=module.norm_eps, dtype=module.dtype)
+    return y, None if state is None else (state,)
 
 
 def apply_layer(module, i: int, p: dict, x: jax.Array, positions, state,
@@ -243,18 +388,21 @@ def apply_layer(module, i: int, p: dict, x: jax.Array, positions, state,
         y, state = short_conv(p, h, None if state is None else state[0],
                               view, dtype)
         state = None if state is None else (state,)
-    else:
+    elif module.layer_types[i] == ATTENTION:
         y, state = grouped_attention(
             p, h, positions, state, view, n_heads=module.n_heads,
             n_kv_heads=module.n_kv_heads, rope_theta=module.rope_theta,
             eps=eps, dtype=dtype)
-    x = x + y
+    else:
+        y, state = _gated_mixer(module, i, p, h, positions, state, view)
+    x = x + module.residual_scale * y
     h = rms_norm(x, p["ffn_norm"], eps, dtype)
     if i < module.n_dense_layers:
-        return x + gated_mlp(p, h, dtype), state, None
+        return (x + module.residual_scale * gated_mlp(p, h, dtype), state,
+                None)
     y, load = expert_mlp(p, h, None if view is None else view.valid,
                          top_k=module.experts_per_token, dtype=dtype)
-    return x + y, state, load
+    return x + module.residual_scale * y, state, load
 
 
 def hidden_states(module, params: dict, tokens: jax.Array, positions,
@@ -262,7 +410,7 @@ def hidden_states(module, params: dict, tokens: jax.Array, positions,
     """The model up to its final norm: `(x (B, S, d), new state, loads)`;
     `state` is a list of per-layer tuples (or None: a plain forward) and
     `loads` the expert layers' assignment counts, stacked (n, E)."""
-    x = params["embed"][tokens].astype(module.dtype)
+    x = module.embed_scale * params["embed"][tokens].astype(module.dtype)
     new_state, loads = [], []
     for i in range(module.n_layers):
         x, layer_state, load = apply_layer(
@@ -281,7 +429,8 @@ def head(module, params: dict, x: jax.Array) -> jax.Array:
     """Logits, float32, of normalized hidden states (..., d)."""
     kernel = (params["embed"].T if module.tie_embeddings
               else params["head"])
-    return _dot(x, kernel, module.dtype).astype(jnp.float32)
+    return _dot(module.logit_scale * x, kernel,
+                module.dtype).astype(jnp.float32)
 
 
 def _fan_in(name: str, shape: tuple) -> int:
@@ -315,8 +464,10 @@ class _Leaves(nn.Module):
 
 class HybridLM(nn.Module):
     """The decoder of the module docstring.  `layer_types` lists each
-    layer's mixer (`conv` | `full_attention`); `max_len` caps the
-    positions a decode may reach (rotary positions need no table)."""
+    layer's mixer (`conv` | `full_attention` | `minicpm4` |
+    `lightning-attn`); `max_len` caps the positions a decode may reach
+    (rotary positions need no table).  The `sparse_*` numbers are those of
+    `ops/sparse_attention.py`, in tokens."""
 
     vocab_size: int = 256
     d_model: int = 128
@@ -332,15 +483,27 @@ class HybridLM(nn.Module):
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
     tie_embeddings: bool = True
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    sparse_block: int = 64
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_window: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_topk: int = 64
+    sparse_dense_len: int = 8192
     max_len: int = 2048
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        unknown = set(self.layer_types) - {CONV, ATTENTION}
+        unknown = set(self.layer_types) - set(MIXERS)
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)} "
-                             f"({CONV} | {ATTENTION})")
+                             f"({' | '.join(MIXERS)})")
+        if SPARSE in self.layer_types:
+            sparse.check(self.sparse_cfg)
         if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"d_model {self.d_model} / n_heads {self.n_heads} / "
@@ -350,6 +513,13 @@ class HybridLM(nn.Module):
     @property
     def n_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def sparse_cfg(self) -> sparse.Sparse:
+        return sparse.Sparse(self.sparse_block, self.sparse_kernel,
+                             self.sparse_stride, self.sparse_window,
+                             self.sparse_init_blocks, self.sparse_topk,
+                             self.sparse_dense_len)
 
     @nn.compact
     def __call__(self, tokens):
@@ -405,31 +575,48 @@ class HybridDecoding(Decoding):
     engine refuses int8 state and a sharded window for a model with FIXED
     layers)."""
 
-    count_names = COUNT_NAMES
-
     def __init__(self, module: HybridLM, **how):
         super().__init__(module, **how)
+        kinds = module.layer_types
         self.state_kinds = tuple(
-            FIXED if kind == CONV else WINDOW for kind in module.layer_types)
+            FIXED if kind in (CONV, LIGHTNING) else WINDOW for kind in kinds)
+        # the expert counts, and the sparse and linear layers' where the
+        # model has such layers: a program counts what it can read
+        self.count_names = COUNT_NAMES + (
+            SPARSE_COUNT_NAMES if {SPARSE, LIGHTNING} & set(kinds) else ())
 
     def empty_state(self, rows: int, window: int,
                     resident: bool = False) -> list:
-        """Zero state for `rows` rows: a window layer's K and V, (rows,
-        window, n_kv_heads, D) each, or a fixed layer's last K-1
-        convolution columns, (rows, K-1, d).  The same for a prompt and
-        for a `resident` batch."""
+        """Zero state for `rows` rows, a layer at a time: K and V, (rows,
+        window, n_kv_heads, D) each, and under a `minicpm4` layer the
+        compressed keys beside them, (rows, window / stride, n_kv_heads,
+        D) float32; a convolution's last K-1 columns, (rows, K-1, d); a
+        linear-attention layer's S, (rows, n_heads, D, D) float32 whatever
+        the model's dtype is.  The same for a prompt and for a `resident`
+        batch."""
         m = self.module
         dh = m.d_model // m.n_heads
         kv = (rows, window, m.n_kv_heads, dh)
-        fixed = (rows, m.conv_kernel - 1, m.d_model)
-        return [(jnp.zeros(kv, m.dtype), jnp.zeros(kv, m.dtype))
-                if kind == WINDOW else (jnp.zeros(fixed, m.dtype),)
-                for kind in self.state_kinds]
+        if SPARSE in m.layer_types and window % m.sparse_block:
+            raise ValueError(
+                f"a window of {window} slots is not whole blocks of "
+                f"{m.sparse_block}: the cache chunk must be a multiple")
+        empty = {
+            CONV: lambda: (jnp.zeros((rows, m.conv_kernel - 1, m.d_model),
+                                     m.dtype),),
+            ATTENTION: lambda: (jnp.zeros(kv, m.dtype),
+                                jnp.zeros(kv, m.dtype)),
+            SPARSE: lambda: (jnp.zeros(kv, m.dtype), jnp.zeros(kv, m.dtype),
+                             jnp.zeros((rows, window // m.sparse_stride)
+                                       + kv[2:], jnp.float32)),
+            LIGHTNING: lambda: (jnp.zeros((rows, m.n_heads, dh, dh),
+                                          jnp.float32),)}
+        return [empty[kind]() for kind in m.layer_types]
 
     # a layer's leaves that `_dot` and `routed_experts` read, through a
     # cast to the compute dtype and in no other way
     _PRODUCT_LEAVES = frozenset({"conv_in", "conv_out", "wq", "wk", "wv",
-                                 "wo", "w1", "w2", "w3"})
+                                 "wo", "wg", "w1", "w2", "w3"})
 
     def resident_params(self, params: dict, cast) -> dict:
         """`params` with `cast` over every leaf the programs read only
@@ -437,8 +624,9 @@ class HybridDecoding(Decoding):
         the products' kernels above, the expert stacks among them, the
         embedding (gathered, then cast: the cast commutes with the
         gather; the tied head reads it through `_dot`) and an untied
-        head.  The norms, `conv_taps`, the router's kernel and the
-        selection bias are read in float32 and stay."""
+        head.  The norms and gains, `conv_taps`, the router's kernel and
+        the selection bias are read in float32 and stay (the decay slopes
+        are no parameters)."""
         out = dict(params)
         for name in ("embed", "head"):
             if name in params:
@@ -449,19 +637,26 @@ class HybridDecoding(Decoding):
                          for k, v in params[name].items()}
         return out
 
-    def _prompt_counts(self, loads):
+    def _counts(self, experts: list, view: StateView):
+        """The count vector, `count_names` long: the experts' five, then
+        what the sparse and linear layers added to `view.counts`."""
+        return jnp.stack([jnp.asarray(c, jnp.float32) for c in experts] + [
+            jnp.asarray(view.counts.get(name, 0.0), jnp.float32)
+            for name in self.count_names[len(COUNT_NAMES):]])
+
+    def _prompt_counts(self, loads, view: StateView):
         """A prefill's counts: assignments, and each expert layer's
         fullest expert beside the mean."""
-        return jnp.stack([loads.sum(), 0.0, 0.0, loads.max(-1).sum(),
-                          loads.mean(-1).sum()])
+        return self._counts([loads.sum(), 0.0, 0.0, loads.max(-1).sum(),
+                             loads.mean(-1).sum()], view)
 
-    def _step_counts(self, loads):
+    def _step_counts(self, loads, view: StateView):
         """A decode step's counts: assignments, and the experts that got
         one beside all that a step could touch (none where no row is
         live)."""
         slots = loads.size * (loads.sum() > 0)
-        return jnp.stack([loads.sum(), (loads > 0).sum().astype(jnp.float32),
-                          slots.astype(jnp.float32), 0.0, 0.0])
+        return self._counts([loads.sum(), (loads > 0).sum(), slots, 0.0,
+                             0.0], view)
 
     def run_prompt(self, params, tokens, state, start, true_len, live):
         """A prompt segment of right-padded rows from slot `start` on
@@ -484,7 +679,7 @@ class HybridDecoding(Decoding):
         x, state, loads = hidden_states(
             self.module, params, tokens, jnp.broadcast_to(at, (b, s)),
             state, view)
-        return x, state, (self._prompt_counts(loads),)
+        return x, state, (self._prompt_counts(loads, view),)
 
     def run_step_rows(self, params, tok, pos, slots, state, visible, live):
         """One decode token a row: per-row positions `pos`, write `slots`
@@ -497,7 +692,7 @@ class HybridDecoding(Decoding):
         x, state, loads = hidden_states(
             self.module, params, tok[:, None], pos[:, None], state, view)
         return (head(self.module, params, x[:, 0]), state,
-                (self._step_counts(loads),))
+                (self._step_counts(loads, view),))
 
     # the uniform-slot step is the per-row step with equal slots
     run_step = run_step_rows

@@ -274,10 +274,13 @@ def _signature(text: str) -> str:
 
 
 def _cache_window(text: str, module) -> int:
-    """The widest KV window in a lowered decode program's signature."""
+    """The widest KV window in a lowered decode program's signature: a
+    leaf (B, W, H, D), or head-folded (B, W, H*D) where the engine keeps
+    its windows so (`TransformerDecoding.folds`)."""
     dh = module.d_model // module.n_heads
     return max(int(w) for w in re.findall(
-        rf"tensor<\d+x(\d+)x{module.n_heads}x{dh}x", _signature(text)))
+        rf"tensor<\d+x(\d+)x(?:{module.n_heads}x{dh}|{module.d_model})x",
+        _signature(text)))
 
 
 def _segment_windows(h: Harness, since: int, module) -> dict:

@@ -116,7 +116,10 @@ def _kv_hint(cache_spec, scale_spec):
     """A K/V state leaf's sharding hint: rank-4 (B, W, H, D) payloads take
     `cache_spec`, the rank-3 (B, W, H) scales of an int8 cache
     `scale_spec`.  Off-mesh the hint is identity (shard_constraint
-    degrades), so every decode path stays single-device-portable."""
+    degrades), so every decode path stays single-device-portable.  A
+    head-folded payload (B, W, H*D) is rank 3 too and would take the
+    scales' spec: harmless, because windows are head-folded off-mesh
+    only (`TransformerDecoding.folds`), where no spec applies."""
     def hint(c: jax.Array) -> jax.Array:
         if c.ndim == 4:
             return shard_constraint(c, cache_spec)
@@ -164,16 +167,18 @@ def _decoding_for(module, **how):
     is built: the decoding of its architecture (`_DECODINGS`).  `how` is
     what the engine fixes for every call: `cache_dtype` (the layout
     segments carry the state in), `fused` (steps may read through the
-    Pallas kernel) and `hint` (a state leaf's sharding hint on the
-    engine's mesh).
+    Pallas kernel), `verifies` (the engine speculates) and `hint` (a
+    state leaf's sharding hint on the engine's mesh).
 
     A decoding (`hybrid_lm.Decoding`) has `state_kinds` (WINDOW | FIXED a
     layer), `count_names` (what its programs count on the device; every
     `run_*` returns the counts last), `empty_state`, `resident_params`,
     `run_prompt` + `head` (the head is applied to the gathered row),
-    `run_step` / `run_step_rows`, `close_prompt` / `reopen_prompt` (the layout a
-    finished prompt's state is carried in, and back) and `enter_segment` /
-    `leave_segment` (the layout a segment steps on, and back).  What only
+    `run_step` / `run_step_rows`, `close_prompt` / `reopen_prompt` (the
+    RESIDENT layout: the one a finished prompt's state is carried in
+    between calls and a segment steps on as it is; and back, for a prompt
+    resumed from donor rows) and `relayout_bytes` (what a call of one of
+    its programs re-tiles of the state).  What only
     a model with nothing but WINDOW state composes with (int8 state, the
     speculative verify segment, the seq-sharded prompt and step) only its
     decoding has: `DecodeEngine.__init__` refuses the rest by name."""
@@ -711,8 +716,9 @@ class DecodeEngine:
         # mesh has it (1 = the classic whole-window engine), else heads
         # on 'model'
         hint = _hint_seq_kv if seq_shards > 1 else _hint_kv
-        decoding = _decoding_for(module, cache_dtype=cache_dtype,
-                                 fused=fused, hint=hint)
+        decoding = _decoding_for(
+            module, cache_dtype=cache_dtype, fused=fused, hint=hint,
+            verifies=bool(spec_tokens or draft_module is not None))
         if FIXED in decoding.state_kinds:
             # what a model with fixed per-row state does not carry over
             # yet refuses here, by name; nothing falls back
@@ -834,6 +840,10 @@ class DecodeEngine:
         self.state_kinds = kinds = decoding.state_kinds
         self.count_names = decoding.count_names
         self.counts_out: list = []   # the last program's device counts
+        # bytes of state that the serving hooks' programs re-tiled, summed
+        # at each dispatch from what the decoding says of the program
+        # (`Decoding.relayout_bytes`: shapes alone, nothing is fetched)
+        self.relayout_bytes = 0
         self._chunk_counts: list = []
         self.max_new_tokens = max_new_tokens
         self.stop_tokens = stop_tokens
@@ -924,12 +934,10 @@ class DecodeEngine:
 
         def segment_impl(seg_len, window, variables, caches, tok, done,
                          true_len, bucket, t0, row_keys):
-            caches = decoding.enter_segment(grow(caches, window))
-            caches, *out = uniform_steps(
+            return uniform_steps(
                 decoding.run_step, seg_len, jnp.arange(window),
-                variables["params"], caches, tok, done, true_len, bucket,
-                t0, row_keys)
-            return (decoding.leave_segment(caches), *out)
+                variables["params"], grow(caches, window), tok, done,
+                true_len, bucket, t0, row_keys)
 
         if seq_shards > 1:
             # SEQ-SHARDED engine: the prompt forward and the segment run
@@ -994,7 +1002,7 @@ class DecodeEngine:
             own cache row only and their emissions repeat the frozen
             token (the engine's per-row emit counters ignore them)."""
             params = variables["params"]
-            caches = decoding.enter_segment(grow(caches, window))
+            caches = grow(caches, window)
             slots_axis = jnp.arange(window)
             max_pos = module.max_len - 1
 
@@ -1015,8 +1023,7 @@ class DecodeEngine:
 
             (tok, done, caches, counts), toks = lax.scan(
                 step, (tok, done, caches, no_counts), jnp.arange(seg_len))
-            return (decoding.leave_segment(caches), toks.transpose(1, 0),
-                    tok, done) + counts
+            return (caches, toks.transpose(1, 0), tok, done) + counts
 
         def prefill_chunk0_impl(w0, variables, tokens, true_len):
             """First chunk of a CHUNKED prefill (offset 0): allocates the
@@ -1261,9 +1268,22 @@ class DecodeEngine:
         self._prefill = jax.jit(meshed(prefill_impl, "prefill_meshed"))
         self._segment = jax.jit(meshed(segment_impl, "segment_meshed"),
                                 static_argnums=(0, 1))
-        self._serve_segment = jax.jit(
-            meshed(serve_segment_impl, "serve_segment_meshed"),
-            static_argnums=(0, 1))
+        # The serving segment steps on the buffers it is handed: the
+        # resident state is DONATED, so the step loop's carry is the
+        # caller's own windows and no call copies them in (a parameter
+        # that is not donated is read-only: XLA copies every window into
+        # the carry first, 5.5 ms of a 1.3 B model's 8-row call).  The
+        # caller's arrays are consumed: `serve_step` returns the state to
+        # go on with.  A call that also GROWS the windows has no buffer
+        # of the new width to take over, so it runs the same program
+        # without donation (`serve_step` picks).  The offline `_segment`
+        # is probed by `capture_program_cost` on the arguments it is then
+        # called with, and keeps its arguments.
+        serve_segment = meshed(serve_segment_impl, "serve_segment_meshed")
+        self._serve_segment = jax.jit(serve_segment, static_argnums=(0, 1),
+                                      donate_argnums=(3,))
+        self._serve_segment_grows = jax.jit(serve_segment,
+                                            static_argnums=(0, 1))
         self._prefill_chunk0 = jax.jit(
             meshed(prefill_chunk0_impl, "prefill_chunk0_meshed"),
             static_argnums=(0,))
@@ -1338,6 +1358,7 @@ class DecodeEngine:
             variables, jnp.asarray(prompts), jnp.asarray(true_len),
             jnp.asarray(live), row_keys)
         self._program(*key)
+        self._count_relayout("prompt", caches, p)
         return tok, done, caches
 
     def serve_step(self, variables, caches, tok, done, true_len, budget,
@@ -1348,7 +1369,9 @@ class DecodeEngine:
         `t_row` and per-row token budgets; returns (caches, toks
         (B, seg_len), tok, done).  `window` must cover the highest slot
         any live row writes: bucket + max(t_row) + seg_len, chunk-rounded
-        (`serve_window`)."""
+        (`serve_window`).  `caches` is CONSUMED where the window does not
+        grow (the segment steps on those buffers in place): go on with
+        the state returned."""
         self._refuse_seq("serve_step")
         b = int(tok.shape[0])
         w_in = self.state_window(caches)
@@ -1357,13 +1380,22 @@ class DecodeEngine:
         # holds — the segment then just attends the existing width
         window = max(int(window), w_in)
         key = ("serve_segment", b, w_in, window, seg_len)
-        caches, toks, tok, done, *self.counts_out = self._serve_segment(
+        segment = (self._serve_segment if w_in == window
+                   else self._serve_segment_grows)
+        caches, toks, tok, done, *self.counts_out = segment(
             seg_len, window, variables, caches, tok, done,
             jnp.asarray(true_len), jnp.asarray(budget, jnp.int32),
             jnp.asarray(bucket, jnp.int32),
             jnp.asarray(t_row, jnp.int32), row_keys)
         self._program(*key)
+        self._count_relayout("step", caches)
         return caches, toks, tok, done
+
+    def _count_relayout(self, program: str, state, tokens: int = 0) -> None:
+        """Add what the program just dispatched re-tiles of `state`, as
+        the decoding says of it, to `relayout_bytes`."""
+        self.relayout_bytes += self._decoding.relayout_bytes(
+            program, state, tokens)
 
     def state_window(self, caches) -> int:
         """Slots a state's window layers hold now."""
@@ -1426,6 +1458,7 @@ class DecodeEngine:
             out = self._prefill_chunk(variables, tokens, caches, last, tl,
                                       jnp.asarray(index * cl, jnp.int32))
             self._program("prefill_chunk", b, cl, w0)
+        self._count_relayout("chunk" if index else "prompt", out[0], cl)
         return self._keep_chunk_counts(out)
 
     def _keep_chunk_counts(self, out) -> tuple:
@@ -1475,7 +1508,9 @@ class DecodeEngine:
         b = int(row_caches[0][0].shape[0])
         n = int(row_caches[0][0].shape[1])
         state = self._resume_init(w0, row_caches)
-        self._program("resume_init", b, n, w0, len(row_caches[0]))
+        self._program("resume_init", b, n, w0, len(row_caches[0]),
+                      row_caches[0][0].ndim)
+        self._count_relayout("reopen", row_caches)
         return state
 
     def serve_prefill_resume(self, variables, prompts, true_len,
@@ -1503,6 +1538,7 @@ class DecodeEngine:
             variables, tokens, caches, last, jnp.asarray(true_len),
             jnp.asarray(prefix_len, jnp.int32))
         self._program("prefill_chunk", b, p - prefix_len, w0)
+        self._count_relayout("chunk", state[0])
         return self.serve_prefill_finish(state, live, row_keys)
 
     def serve_draft_prefill(self, draft_variables, prompts):
@@ -1565,6 +1601,20 @@ class DecodeEngine:
                 "a row explicitly (serialize_cache_row np.asarray-"
                 "gathers the window) or decode outside the serving join "
                 "path")
+        layout = lambda caches: [[(c.ndim, str(c.dtype)) for c in layer]
+                                 for layer in caches]
+        if layout(dst_caches) != layout(src_caches):
+            # e.g. a handoff page of a folding engine (payloads (B, W,
+            # H*D)) given to one that keeps (B, W, H, D), or model-dtype
+            # rows to an int8 batch: never scattered across layouts
+            raise ValueError(
+                "merge_cache_rows: the source rows' state layout "
+                f"(rank, dtype a leaf: {layout(src_caches)[0]}) is not "
+                f"the resident batch's ({layout(dst_caches)[0]}): they "
+                "come from an engine built otherwise (cache_dtype, mesh "
+                "or speculation decide the resident layout); build both "
+                "alike, or resume the rows through serve_prefill_resume, "
+                "whose reopen_prompt converts them")
         di = jnp.asarray(dst_rows, jnp.int32)
         si = jnp.asarray(src_rows, jnp.int32)
         with use_mesh(mesh):

@@ -550,22 +550,40 @@ class Decoding:
     (`generate._decoding_for`), with the answers of a model that counts
     nothing on the device and whose state has one layout.  `cache_dtype`
     is the layout segments carry the state in ('model' | 'int8'), `fused`
-    whether steps may read through the Pallas kernel, `hint` the sharding
-    hint of a state leaf on the engine's mesh (None: no mesh)."""
+    whether steps may read through the Pallas kernel, `verifies` whether
+    the engine speculates (its rounds read the state through
+    `run_verify`), `hint` the sharding hint of a state leaf on the
+    engine's mesh (None: no mesh).
+
+    The state a finished prompt hands on (`close_prompt`) is RESIDENT: the
+    one layout that `merge_cache_rows`, window growth, every segment,
+    the prefix pool's slices and handoff pages carry, and that a segment
+    steps on as it is."""
 
     count_names = ()
 
     def __init__(self, module, *, cache_dtype: str = "model",
-                 fused: bool = False, hint=None):
+                 fused: bool = False, verifies: bool = False, hint=None):
         self.module = module
         self.cache_dtype, self.fused = cache_dtype, fused
+        self.verifies = verifies
         self.hint = hint or (lambda c: c)
 
     def _same(self, state: list) -> list:
         return state
 
-    # a prompt's state and a segment's are the state as it is
-    close_prompt = reopen_prompt = enter_segment = leave_segment = _same
+    # a prompt's state is resident as it is
+    close_prompt = reopen_prompt = _same
+
+    def relayout_bytes(self, program: str, state: list,
+                       tokens: int = 0) -> int:
+        """Bytes of `state` that ONE call of `program` re-tiles on the
+        device, known from shapes alone: "prompt" (`run_prompt` over
+        `tokens` a row from slot 0), "chunk" (a later prompt segment),
+        "reopen" (`reopen_prompt` of donor rows), "step" (a segment).
+        The serving engine sums them at each dispatch
+        (`state_relayout_bytes`).  One layout: nothing, ever."""
+        return 0
 
 
 class HybridDecoding(Decoding):

@@ -129,7 +129,9 @@ def _stack(module, params: dict, tokens: jax.Array, positions: jax.Array,
 
 def _window_entry(t: jax.Array, cache: jax.Array) -> jax.Array:
     """New K or V, (B, S, H, D), as slots of `cache`: in its dtype, and
-    head-folded where the window is (`enter_segment`)."""
+    head-folded where the window is (`TransformerDecoding.folds`).  The
+    fold of a token's own K/V undoes `_block`'s split into heads: it is
+    the qkv product's column slice, so nothing is re-tiled for it."""
     return t.astype(cache.dtype).reshape(t.shape[:2] + cache.shape[2:])
 
 
@@ -168,8 +170,12 @@ def _slot_write(slot):
 def _masked_dense_read(q, k_cache, v_cache, pos):
     """softmax(q k^T) v in float32 against the whole (B, L, H, D) cache,
     under the global causal mask: the query at pos+i sees cache slots
-    0..pos+i.  Its f32 softmax is bit-stable for the exact-parity tests."""
+    0..pos+i.  Its f32 softmax is bit-stable for the exact-parity tests.
+    A head-folded cache (B, L, H*D) is unfolded for the read: the one
+    place a prompt re-tiles a window (`relayout_bytes`)."""
     s, dh = q.shape[1], q.shape[3]
+    k_cache, v_cache = (c.reshape(c.shape[:2] + q.shape[2:])
+                        for c in (k_cache, v_cache))
     scores = jnp.einsum("bqhd,blhd->bhql", q.astype(jnp.float32),
                         k_cache.astype(jnp.float32)) * dh ** -0.5
     visible = (jnp.arange(k_cache.shape[1])[None, :]
@@ -180,13 +186,13 @@ def _masked_dense_read(q, k_cache, v_cache, pos):
 
 
 def _segment_view(pos):
-    """A token segment from slot `pos` on, against the whole (B, L, H,
-    D) cache: a prefill (`pos` the static 0) or a prompt chunk or
-    full-cache decode token (traced `pos`)."""
+    """A token segment from slot `pos` on, against the whole cache, (B,
+    L, H, D) or head-folded: a prefill (`pos` the static 0) or a prompt
+    chunk or full-cache decode token (traced `pos`)."""
     write = _slot_write(pos)
 
     def attend(q, k, v, layer):
-        state = tuple(write(c, t.astype(c.dtype))
+        state = tuple(write(c, _window_entry(t, c))
                       for c, t in zip(layer, (k, v)))
         if (q.shape[1] >= PREFILL_FLASH_MIN and isinstance(pos, int)
                 and pos == 0):
@@ -195,7 +201,9 @@ def _segment_view(pos):
             # self-attention over the segment, so the flash kernel
             # (O(block^2) memory, fwd-only) computes it without ever
             # materializing the (S, S) scores.  A long segment at pos > 0
-            # would need the cached prefix too: it takes the dense read
+            # would need the cached prefix too: it takes the dense read.
+            # The kernel reads the segment's own K and V, never the
+            # cache: a whole prompt re-tiles no window
             from mmlspark_tpu.ops.flash_attention import flash_attention
             return flash_attention(q, k, v, causal=True), state
         return _masked_dense_read(q, *state, pos), state
@@ -281,22 +289,36 @@ class TransformerDecoding(Decoding):
     def __init__(self, module, **how):
         super().__init__(module, **how)
         self.state_kinds = (WINDOW,) * module.n_layers
-        # a segment steps on head-folded windows where its steps read
-        # them through the fused kernel (`enter_segment`)
-        self.folds = self.fused and self.cache_dtype == "model"
+        # The RESIDENT LAYOUT.  The fused kernel reads a model-dtype
+        # window (B, W, H, D) head-folded, as (B, W, H*D)
+        # (`ops/decode_attention.py`).  On the TPU the two are tiled
+        # differently, so the reshape is a copy of the window: inside the
+        # step it was made for every layer's K and V at every decode step
+        # (34 ms of a 126 ms segment of Cerebras-GPT-1.3B, PERF.md section
+        # 6, PR 29), round the step loop at every segment call (11 ms of
+        # 63, PR 33).  So where segments step through the fused kernel
+        # the windows ARE head-folded, from `empty_state` on: a prompt
+        # writes them so, `merge_cache_rows`, window growth, the prefix
+        # pool and handoff pages carry them so (all rank-agnostic), and a
+        # segment steps on them as they are.  What the engine knows when
+        # it is built decides it: no mesh (`fused`), a model-dtype cache,
+        # no speculation (`run_verify` reads (B, W, H, D)).
+        self.folds = (self.fused and self.cache_dtype == "model"
+                      and not self.verifies)
 
     def empty_state(self, rows: int, window: int,
                     resident: bool = False) -> list:
         """Zero K and V windows, one pair a layer, as a prompt is run
-        into them: in the model dtype, hinted.  `resident`: as segments
-        carry them (`close_prompt`'s layout) and with no hint, for a
-        batch allocated outside any program."""
+        into them: in the model dtype, hinted, head-folded where
+        `folds`.  `resident`: as segments carry them (`close_prompt`'s
+        layout) and with no hint, for a batch allocated outside any
+        program."""
         m = self.module
         shape = (rows, window, m.n_heads, m.d_model // m.n_heads)
         if resident and self.cache_dtype == "int8":
             leaves = ((shape, jnp.int8), (shape[:3], jnp.float32)) * 2
         else:
-            leaves = ((shape, m.dtype),) * 2
+            leaves = ((self._payload(shape), m.dtype),) * 2
         hint = (lambda c: c) if resident else self.hint
         return [tuple(hint(jnp.zeros(*leaf)) for leaf in leaves)
                 for _ in range(m.n_layers)]
@@ -345,11 +367,24 @@ class TransformerDecoding(Decoding):
         # functions.
         return last
 
+    def _payload(self, shape: tuple) -> tuple:
+        """The shape of a model-dtype K or V leaf whose slots hold (H,
+        D): as it is, or head-folded where `folds`."""
+        return shape[:2] + (shape[2] * shape[3],) if self.folds else shape
+
+    def _foreign(self, state: list) -> bool:
+        """Model-dtype donor rows that an engine of the other layout
+        made (a folding engine's rank 3 given to one that keeps (B, W,
+        H, D), or the reverse)."""
+        return any(len(layer) == 2 and (layer[0].ndim == 3) != self.folds
+                   for layer in state)
+
     def close_prompt(self, state: list) -> list:
-        """The state a finished prompt hands to segments: an int8 cache
-        quantizes the whole prompt's K/V once here (decode steps
-        quantize each new token on write), to (k int8, k_scale f32 (B,
-        W, H), v int8, v_scale)."""
+        """The state a finished prompt hands to segments.  A model-dtype
+        prompt's state is resident as it was written (head-folded where
+        `folds`); an int8 cache quantizes the whole prompt's K/V once
+        here (decode steps quantize each new token on write), to (k
+        int8, k_scale f32 (B, W, H), v int8, v_scale)."""
         if self.cache_dtype != "int8":
             return state
         from mmlspark_tpu.quant.quantize import quantize_kv
@@ -358,35 +393,41 @@ class TransformerDecoding(Decoding):
                 for kc, vc in state]
 
     def reopen_prompt(self, state: list) -> list:
-        """`close_prompt` undone, for a prompt resumed from donor rows:
-        int8 slots back in the model dtype (quantize_kv's round trip is
-        idempotent, so closing again stores the same bytes)."""
-        dtype = self.module.dtype
-        return [layer if len(layer) == 2 else tuple(
-            (q.astype(jnp.float32) * s[..., None]).astype(dtype)
-            for q, s in (layer[:2], layer[2:])) for layer in state]
+        """`close_prompt` undone, for a prompt resumed from donor rows,
+        whichever engine closed them: int8 slots back in the model dtype
+        (quantize_kv's round trip is idempotent, so closing again stores
+        the same bytes), and every payload in THIS decoding's prompt
+        layout, so donor rows of the other layout (`_foreign`) are
+        re-tiled here once and never read wrong."""
+        m = self.module
+        dtype, heads = m.dtype, (m.n_heads, m.d_model // m.n_heads)
+        reopened = []
+        for layer in state:
+            if len(layer) == 4:
+                layer = tuple((q.astype(jnp.float32) * s[..., None])
+                              .astype(dtype)
+                              for q, s in (layer[:2], layer[2:]))
+            reopened.append(tuple(
+                c.reshape(self._payload(c.shape[:2] + heads))
+                for c in layer))
+        return reopened
 
-    def enter_segment(self, state: list) -> list:
-        """The state as a segment steps on it.  The fused kernel reads a
-        model-dtype window (B, W, H, D) head-folded, as (B, W, H*D)
-        (`ops/decode_attention.py`).  On the TPU the two are tiled
-        differently, so the reshape is a copy of the window: left inside
-        the step it is made for every layer's K and V at every decode
-        step (34 ms of a 126 ms segment of Cerebras-GPT-1.3B, PERF.md
-        section 6, PR 29).  So a segment folds once, steps on the folded
-        windows (`_window_entry` gives a token's K/V whichever shape the
-        window has) and unfolds once at its end (`leave_segment`)."""
-        if not self.folds:
-            return state
-        return [tuple(c.reshape(c.shape[:2] + (-1,)) for c in layer)
-                for layer in state]
-
-    def leave_segment(self, state: list) -> list:
-        if not self.folds:
-            return state
-        heads = (self.module.n_heads, -1)
-        return [tuple(c.reshape(c.shape[:2] + heads) for c in layer)
-                for layer in state]
+    def relayout_bytes(self, program: str, state: list,
+                       tokens: int = 0) -> int:
+        """`Decoding.relayout_bytes`: a segment re-tiles nothing; a
+        prompt segment on head-folded windows unfolds the rows' K and V
+        where it takes the dense read (`_segment_view`: short of
+        PREFILL_FLASH_MIN tokens, or a later chunk) and nothing where it
+        takes the flash kernel; reopening re-tiles donor rows of the
+        other layout."""
+        if program == "reopen":
+            retiles = self._foreign(state)
+        else:
+            retiles = self.folds and (program == "chunk" or (
+                program == "prompt" and tokens < PREFILL_FLASH_MIN))
+        if not retiles:
+            return 0
+        return sum(int(c.nbytes) for layer in state for c in layer)
 
     def _run_step(self, params, tok, pos, state, attend):
         logits, state = _stack(self.module, params, tok, pos, state, attend)

@@ -25,7 +25,7 @@ folded into the lane dimension: blocks are (block_k, H*D) slices of the
 (B, L, H*D) view (contiguous in the array's order, but on the TPU tiled
 otherwise than the 4-D window: XLA makes that view by copying the window,
 so a caller that reads a window at every decode step hands it over folded
-and keeps it so: `TransformerDecoding.enter_segment`), per-head score
+and keeps it so: `TransformerDecoding.folds`, the resident layout), per-head score
 rows are produced by one MXU matmul against a constant head-selector
 matrix (lane i of the cache belongs to head i // D), and the softmax
 weights are expanded back through its transpose.  Scores and statistics

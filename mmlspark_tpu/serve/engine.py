@@ -641,6 +641,11 @@ class ServingEngine:
                 t += k1
                 rounds += 1
             return
+        # as a live group's state comes to be: a segment consumes the
+        # state it steps on, and the cohorts are spliced again below
+        caches = DecodeEngine.merge_cache_rows(
+            eng.empty_state(cap, bucket), caches, list(range(cap)),
+            list(range(cap)), mesh=eng.mesh, kinds=eng.state_kinds)
         while t < self.cfg.max_new_tokens:
             window = eng.serve_window(bucket, t, seg)
             caches, _, tok, done = eng.serve_step(
@@ -1755,6 +1760,11 @@ class ServingEngine:
                     held[kind] += n
         out["state_bytes_window"] = held[WINDOW]
         out["state_bytes_fixed"] = held[FIXED]
+        # a count: bytes of state that the lanes' dispatched programs
+        # re-tiled, as each decoding says of its own programs (0 for a
+        # segment: it steps on the resident layout as it is)
+        out["state_relayout_bytes"] = sum(
+            eng.relayout_bytes for eng in self._engines.values())
         if self._prefix is not None:
             out["prefix"] = self._prefix.stats()
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):

@@ -100,7 +100,13 @@ def test_decoding_state_and_uniform_step(case):
     closed = _prompt_state(decoding, params, prompts)
     assert _layout(closed) == _layout(
         decoding.empty_state(ROWS, WINDOW_SLOTS, resident=True))
-    state = decoding.enter_segment(closed)
+    # a segment steps on the state `close_prompt` returned, as it is:
+    # head-folded where the decoding folds, (B, W, H*D) a leaf
+    state = closed
+    if case == "transformer_lm_folded":
+        assert decoding.folds and {
+            leaf.shape for layer in state for leaf in layer} == {
+                (ROWS, WINDOW_SLOTS, module.d_model)}
     slots = jnp.arange(WINDOW_SLOTS)
     visible = ((slots[None, :] < TRUE_LEN[:, None])
                | (slots[None, :] == BUCKET))
@@ -113,7 +119,49 @@ def test_decoding_state_and_uniform_step(case):
     assert one[0].shape == (ROWS, module.vocab_size)
     assert len(one[2]) == len(rows[2]) == (1 if decoding.count_names else 0)
     assert_bitwise(rows, one)
-    assert _layout(decoding.leave_segment(one[1])) == _layout(closed)
+    assert _layout(one[1]) == _layout(rows[1]) == _layout(closed)
+
+
+FOREIGN = {"transformer_lm": "transformer_lm_folded",
+           "transformer_lm_folded": "transformer_lm",
+           "transformer_lm_int8_state": "transformer_lm_folded"}
+
+
+@pytest.mark.parametrize("case", list(SEAM_CASES))
+def test_reopen_prompt_undoes_close_prompt(case):
+    """`reopen_prompt(close_prompt(s))` is `s` bit for bit (an int8
+    cache rounds once: closing what it reopened stores the same bytes),
+    and rows that a decoding of ANOTHER layout closed reopen as this
+    decoding's own prompt state."""
+    arch, cfg, how = SEAM_CASES[case]
+    module, params = _model(arch, cfg)
+    decoding = _decoding_for(module, **how)
+    prompts, _ = _prompts(module.vocab_size)
+
+    @jax.jit
+    def opened(params, prompts):
+        state = decoding.empty_state(ROWS, WINDOW_SLOTS)
+        return decoding.run_prompt(params, prompts, state, 0,
+                                   jnp.asarray(TRUE_LEN),
+                                   jnp.ones(ROWS, bool))[1]
+    state = opened(params, prompts)
+    closed = jax.jit(decoding.close_prompt)(state)
+    again = jax.jit(decoding.reopen_prompt)(closed)
+    assert _layout(again) == _layout(state)
+    if how.get("cache_dtype") == "int8":
+        assert_bitwise(jax.jit(decoding.close_prompt)(again), closed)
+    else:
+        assert_bitwise(again, state)
+    assert decoding.relayout_bytes("reopen", closed) == 0
+    if case in FOREIGN:
+        other = _decoding_for(module, **SEAM_CASES[FOREIGN[case]][2])
+        theirs = _prompt_state(other, params, prompts)
+        mine = jax.jit(decoding.reopen_prompt)(theirs)
+        assert _layout(mine) == _layout(state)
+        if "int8" not in case:
+            assert_bitwise(mine, state)
+            assert decoding.relayout_bytes("reopen", theirs) == sum(
+                leaf.nbytes for layer in theirs for leaf in layer)
 
 
 # TransformerLM's views at float32: each continues a prompt to the logits

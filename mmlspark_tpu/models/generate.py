@@ -178,10 +178,11 @@ def _decoding_for(module, **how):
     RESIDENT layout: the one a finished prompt's state is carried in
     between calls and a segment steps on as it is; and back, for a prompt
     resumed from donor rows) and `relayout_bytes` (what a call of one of
-    its programs re-tiles of the state).  What only
-    a model with nothing but WINDOW state composes with (int8 state, the
-    speculative verify segment, the seq-sharded prompt and step) only its
-    decoding has: `DecodeEngine.__init__` refuses the rest by name."""
+    its programs re-tiles of the state).  What only some decodings
+    carry (int8 state, the speculative verify segment, the seq-sharded
+    prompt and step, partition rules) each says of itself (`cache_dtypes`,
+    `speculates`, `shards`): `DecodeEngine.__init__` refuses the rest by
+    name."""
     _check_generatable(module, tuple(_DECODINGS), "DecodeEngine")
     return next(decoding for arch, decoding in _DECODINGS.items()
                 if isinstance(module, arch))(module, **how)
@@ -719,26 +720,25 @@ class DecodeEngine:
         decoding = _decoding_for(
             module, cache_dtype=cache_dtype, fused=fused, hint=hint,
             verifies=bool(spec_tokens or draft_module is not None))
-        if FIXED in decoding.state_kinds:
-            # what a model with fixed per-row state does not carry over
-            # yet refuses here, by name; nothing falls back
-            name = type(module).__name__
-            if cache_dtype == "int8":
-                raise ValueError(
-                    f"cache_dtype='int8' is not supported for {name}: "
-                    "its fixed per-row state has no quantized layout")
-            if draft_module is not None or spec_tokens:
-                raise ValueError(
-                    f"speculative decoding is not supported for {name}: "
-                    "the multi-token verify forward has no per-row "
-                    "fixed-state path")
-            if mesh is not None and (
-                    int(mesh.shape.get(SEQ_AXIS, 1)) > 1
-                    or int(mesh.shape.get(MODEL_AXIS, 1)) > 1):
-                raise ValueError(
-                    f"a mesh with model>1 or seq>1 is not supported for "
-                    f"{name}: its expert stacks and state have no "
-                    "partition rules (a data-only mesh works)")
+        # what the model's decoding does not carry refuses here, by name;
+        # nothing falls back
+        name = type(module).__name__
+        if cache_dtype == "int8" and "int8" not in decoding.cache_dtypes:
+            raise ValueError(
+                f"cache_dtype='int8' is not supported for {name}: its "
+                "layers' state has no quantized layout")
+        if ((draft_module is not None or spec_tokens)
+                and not decoding.speculates):
+            raise ValueError(
+                f"speculative decoding is not supported for {name}: the "
+                "multi-token verify forward has no path through its "
+                "layers' state")
+        if mesh is not None and not decoding.shards and (
+                seq_shards > 1 or int(mesh.shape.get(MODEL_AXIS, 1)) > 1):
+            raise ValueError(
+                f"a mesh with model>1 or seq>1 is not supported for "
+                f"{name}: its weights and state have no partition rules "
+                "(a data-only mesh works)")
         if cache_dtype not in ("model", "int8"):
             raise ValueError(
                 f"unknown cache_dtype '{cache_dtype}' (model | int8)")
@@ -1534,9 +1534,9 @@ class DecodeEngine:
         caches, last = self.serve_resume_init(row_caches, p)
         w0 = self.state_window(caches)
         tokens = jnp.asarray(prompts[:, prefix_len:])
-        state = self._prefill_chunk(
+        state = self._keep_chunk_counts(self._prefill_chunk(
             variables, tokens, caches, last, jnp.asarray(true_len),
-            jnp.asarray(prefix_len, jnp.int32))
+            jnp.asarray(prefix_len, jnp.int32)))
         self._program("prefill_chunk", b, p - prefix_len, w0)
         self._count_relayout("chunk", state[0])
         return self.serve_prefill_finish(state, live, row_keys)

@@ -13,15 +13,30 @@ view) -> (y, new state)`; the flax module (for `init`, `Trainer`,
     logits = (l * norm(x_L)) @ E^T                (head tied to E, or its own)
 
 with the multipliers e, a, l (`embed_scale`, `residual_scale`,
-`logit_scale`) 1 unless the constructor says otherwise.
+`logit_scale`) 1 unless the constructor says otherwise.  A LOOPED model
+(`n_passes` R > 1) runs the same layers R times over the same weights:
+
+    for t in 1..R:  x = stack(x; windows of pass t);  x = norm(x);
+                    g_t = sigmoid(w_g . x + b_g)            (`exit_gate`)
+    logits = x_R @ head
+
+so the final norm closes EVERY pass and its output enters the next one, and
+pass t of a layer attends to the keys and values that pass t of that layer
+wrote: a row keeps R windows a layer, side by side on the head axis of the
+layer's K and V leaves (pass t's heads at [t * n_kv_heads, (t + 1) *
+n_kv_heads)), so rows stay on axis 0 and slots on axis 1.  The gates give
+the exit distribution p_t = g_t prod_{j<t} (1 - g_j), p_R the rest; the
+published `exit_threshold` of 1 leaves at the last pass, and nothing here
+leaves earlier: the gates are counted (`loop_exit_expected`), not acted on.
 
   * `norm` is RMSNorm: x * rsqrt(mean(x^2) + eps) * g, in float32.
   * a `conv` mixer: [B, C, z] = W_in h; u = B * z; c_t = sum_j k_j
     u_{t-K+1+j} per channel (depthwise, causal, no activation);
     y = W_out (C * c).  A row carries u at its last K-1 positions.
   * a `full_attention` mixer: q, k, v projections with `n_kv_heads` <=
-    `n_heads`; RMSNorm over each head of q and k, then rotary positions
-    (half-split pairing) over the whole head; each KV head serves
+    `n_heads`; RMSNorm over each head of q and k (`qk_norm`; a model
+    without it has no such gains), then rotary positions (half-split
+    pairing) over the whole head; each KV head serves
     n_heads / n_kv_heads query heads; causal softmax.  A row carries a
     window of K and V.
   * a `minicpm4` mixer: q, k, v as above without rotary; the row keeps a
@@ -39,6 +54,9 @@ with the multipliers e, a, l (`embed_scale`, `residual_scale`,
   * the first `n_dense_layers` layers have a gated MLP W2 (silu(W1 h) *
     W3 h); every other layer has routed experts (`ops/moe.routed_experts`:
     sigmoid scores, a selection bias, top-k, renormalised, dropless).
+  * `sandwich_norm`: a second RMSNorm AFTER each sub-layer, before the
+    residual sum: x = x + a * norm'(mixer_i(norm(x))), and the same around
+    the MLP (gains `op_post_norm`, `ffn_post_norm`).
 
 No bias anywhere.  Parameters are float32, products run in `dtype`.
 """
@@ -81,6 +99,12 @@ COUNT_NAMES = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
 SPARSE_COUNT_NAMES = ("sparse_keys_visible", "sparse_keys_read",
                       "sparse_prompt_keys_visible", "sparse_prompt_keys_read",
                       "sparse_dense_steps", "linear_state_steps")
+# and of a looped model (`n_passes` > 1): decode steps of live rows, the
+# stack passes those steps ran, the sum over them of the pass at which the
+# exit gates' own distribution would leave (sum_t t * p_t: a reading, nothing
+# leaves early), and passes x true prompt tokens of prefills
+LOOP_COUNT_NAMES = ("loop_tokens", "loop_passes", "loop_exit_expected",
+                    "loop_prompt_passes")
 
 
 @dataclasses.dataclass
@@ -95,9 +119,9 @@ class StateView:
     the segment's tokens are the row's own (the rest is bucket padding):
     the convolution state and the linear-attention state are taken
     there, at the row's true length.  `valid`: `(B, S)` bool, the tokens
-    the counters count.  `counts`: what the mixers of this call counted
-    ({name of SPARSE_COUNT_NAMES: sum so far}), read once the layers
-    ran."""
+    the counters count.  `counts`: what the mixers and the pass loop of
+    this call counted ({name of SPARSE_COUNT_NAMES or LOOP_COUNT_NAMES: sum
+    so far}), read once the layers ran."""
 
     write_at: Any
     visible: Optional[jax.Array]
@@ -161,14 +185,16 @@ def short_conv(p: dict, h: jax.Array, state, view: Optional[StateView],
         return y, kept.astype(state.dtype)
 
 
-def _row_write(cache: jax.Array, update: jax.Array, slots: jax.Array):
+def _row_write(cache: jax.Array, update: jax.Array, slots: jax.Array,
+               lane=0):
     """Write `update` (B, S, ...) into `cache` (B, W, ...) from a PER-ROW
-    start slot `slots` (B,) on: vmap of the single-row
-    dynamic_update_slice over the batch axis (S is 1 for a decode step,
-    the verify segment's length under speculation)."""
-    zeros = (0,) * (cache.ndim - 2)
+    start slot `slots` (B,) on, and from head `lane` on where the cache
+    holds more heads than the update (a looped model's passes): vmap of
+    the single-row dynamic_update_slice over the batch axis (S is 1 for a
+    decode step, the verify segment's length under speculation)."""
+    zeros = (0,) * (cache.ndim - 3)
     return jax.vmap(lambda c, u, s: lax.dynamic_update_slice(
-        c, u, (s,) + zeros))(cache, update, slots)
+        c, u, (s, lane) + zeros))(cache, update, slots)
 
 
 def _grouped_attention(q, k, v, visible, scale: float):
@@ -186,22 +212,34 @@ def _grouped_attention(q, k, v, visible, scale: float):
     return out.reshape(b, s, h, d)
 
 
+def _pass_heads(cache: jax.Array, lane, n_kv_heads: int) -> jax.Array:
+    """The heads of a window leaf that one pass of a looped model keeps:
+    `n_kv_heads` from head `lane` on (None: the leaf is one window)."""
+    return cache if lane is None else lax.dynamic_slice_in_dim(
+        cache, lane, n_kv_heads, axis=2)
+
+
 def grouped_attention(p: dict, h: jax.Array, positions: jax.Array, state,
                       view: Optional[StateView], *, n_heads: int,
                       n_kv_heads: int, rope_theta: float, eps: float,
-                      dtype):
+                      dtype, qk_norm: bool = True, lane=None):
     """Grouped-KV attention over normalized `h` (B, S, d); `state` is the
-    row's (K, V) window, each (B, W, n_kv_heads, D), or None."""
+    row's (K, V) window, each (B, W, n_kv_heads, D), or None.  `lane`
+    (a looped model's pass times `n_kv_heads`, traced): the windows hold
+    every pass's heads side by side, and this call writes and reads the
+    `n_kv_heads` from head `lane` on."""
     with jax.named_scope("attn"):
         b, s, d = h.shape
         dh = d // n_heads
         q = _dot(h, p["wq"], dtype).reshape(b, s, n_heads, dh)
         k = _dot(h, p["wk"], dtype).reshape(b, s, n_kv_heads, dh)
         v = _dot(h, p["wv"], dtype).reshape(b, s, n_kv_heads, dh)
-        q = rotary(rms_norm(q, p["q_norm"], eps, dtype), positions,
-                   rope_theta)
-        k = rotary(rms_norm(k, p["k_norm"], eps, dtype), positions,
-                   rope_theta)
+        if qk_norm:
+            q = rms_norm(q, p["q_norm"], eps, dtype)
+        q = rotary(q, positions, rope_theta)
+        if qk_norm:
+            k = rms_norm(k, p["k_norm"], eps, dtype)
+        k = rotary(k, positions, rope_theta)
         scale = dh ** -0.5
         causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
         if state is None:
@@ -209,16 +247,19 @@ def grouped_attention(p: dict, h: jax.Array, positions: jax.Array, state,
             return _dot(o.reshape(b, s, d), p["wo"], dtype), None
         k_cache, v_cache = state
         at = view.write_at
+        head0 = 0 if lane is None else lane
         if jnp.ndim(at) == 0:
             k_cache = lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, at, 0, 0))
+                k_cache, k.astype(k_cache.dtype), (0, at, head0, 0))
             v_cache = lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, at, 0, 0))
+                v_cache, v.astype(v_cache.dtype), (0, at, head0, 0))
         else:
-            k_cache = _row_write(k_cache, k.astype(k_cache.dtype), at)
-            v_cache = _row_write(v_cache, v.astype(v_cache.dtype), at)
+            k_cache = _row_write(k_cache, k.astype(k_cache.dtype), at, head0)
+            v_cache = _row_write(v_cache, v.astype(v_cache.dtype), at, head0)
         if view.visible is not None:
-            o = _grouped_attention(q, k_cache, v_cache, view.visible, scale)
+            o = _grouped_attention(q, _pass_heads(k_cache, lane, n_kv_heads),
+                                   _pass_heads(v_cache, lane, n_kv_heads),
+                                   view.visible, scale)
         elif s >= PREFILL_FLASH_MIN:
             # a whole prompt from slot 0: causal attention over the
             # segment itself, so the flash kernel never builds (S, S)
@@ -340,14 +381,17 @@ def layer_params_shapes(module, i: int) -> dict:
     d = module.d_model
     dh = d // module.n_heads
     shapes = {"op_norm": (d,), "ffn_norm": (d,)}
+    if module.sandwich_norm:
+        shapes.update(op_post_norm=(d,), ffn_post_norm=(d,))
     kind = module.layer_types[i]
     if kind == CONV:
         shapes.update(conv_in=(d, 3 * d), conv_taps=(module.conv_kernel, d),
                       conv_out=(d, d))
     else:
         kv = d if kind == LIGHTNING else module.n_kv_heads * dh
-        shapes.update(wq=(d, d), wk=(d, kv), wv=(d, kv), wo=(d, d),
-                      q_norm=(dh,), k_norm=(dh,))
+        shapes.update(wq=(d, d), wk=(d, kv), wv=(d, kv), wo=(d, d))
+        if module.qk_norm:
+            shapes.update(q_norm=(dh,), k_norm=(dh,))
         if kind != ATTENTION:
             shapes.update(wg=(d, d))
         if kind == LIGHTNING:
@@ -378,10 +422,11 @@ def _gated_mixer(module, i: int, p: dict, h: jax.Array, positions, state,
 
 
 def apply_layer(module, i: int, p: dict, x: jax.Array, positions, state,
-                view: Optional[StateView]):
+                view: Optional[StateView], lane=None):
     """Layer i over the residual stream x (B, S, d): `(x, new state,
     load)`; `load` is the experts' assignment count (E,), or None for a
-    dense layer."""
+    dense layer.  `lane`: where a looped model's pass keeps its heads in
+    the layer's windows (`grouped_attention`)."""
     dtype, eps = module.dtype, module.norm_eps
     h = rms_norm(x, p["op_norm"], eps, dtype)
     if module.layer_types[i] == CONV:
@@ -392,17 +437,78 @@ def apply_layer(module, i: int, p: dict, x: jax.Array, positions, state,
         y, state = grouped_attention(
             p, h, positions, state, view, n_heads=module.n_heads,
             n_kv_heads=module.n_kv_heads, rope_theta=module.rope_theta,
-            eps=eps, dtype=dtype)
+            eps=eps, dtype=dtype, qk_norm=module.qk_norm, lane=lane)
     else:
         y, state = _gated_mixer(module, i, p, h, positions, state, view)
+    if module.sandwich_norm:
+        y = rms_norm(y, p["op_post_norm"], eps, dtype)
     x = x + module.residual_scale * y
     h = rms_norm(x, p["ffn_norm"], eps, dtype)
     if i < module.n_dense_layers:
-        return (x + module.residual_scale * gated_mlp(p, h, dtype), state,
-                None)
-    y, load = expert_mlp(p, h, None if view is None else view.valid,
-                         top_k=module.experts_per_token, dtype=dtype)
+        y, load = gated_mlp(p, h, dtype), None
+    else:
+        y, load = expert_mlp(p, h, None if view is None else view.valid,
+                             top_k=module.experts_per_token, dtype=dtype)
+    if module.sandwich_norm:
+        y = rms_norm(y, p["ffn_post_norm"], eps, dtype)
     return x + module.residual_scale * y, state, load
+
+
+def exit_distribution(gates: jax.Array) -> jax.Array:
+    """The passes' exit probabilities of their gates, both (R, ...): p_t =
+    g_t prod_{j<t} (1 - g_j), and the last pass takes what is left."""
+    stay = jnp.cumprod(1.0 - gates, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([(gates * before)[:-1], before[-1:]])
+
+
+def close_pass(module, params: dict, x: jax.Array):
+    """What ends every pass of a looped model: the final norm, whose
+    output is the next pass's input (and the head's, after the last), and
+    the exit gate read from it: `(x, gate (B, S) float32)`, zeros without
+    `exit_gate`."""
+    with jax.named_scope("loop.close"):
+        x = rms_norm(x, params["out_norm"], module.norm_eps, module.dtype)
+        if not module.exit_gate:
+            return x, jnp.zeros(x.shape[:2], jnp.float32)
+        return x, jax.nn.sigmoid(x.astype(jnp.float32) @ params["exit_w"]
+                                 + params["exit_b"])
+
+
+def looped_stack(module, params: dict, x: jax.Array, positions, state=None,
+                 view: Optional[StateView] = None):
+    """The layers `n_passes` times over the same weights, from the
+    embedded tokens `x`: `(x of the last pass, normalized; new state; the
+    passes' exit gates (R, B, S) float32, zeros without `exit_gate`)`.
+    The program holds the stack ONCE: a `lax.scan` over the passes with
+    the weights closed over; pass t's windows are the heads from t *
+    `n_kv_heads` on of each layer's K and V leaves."""
+    def one_pass(carry, t):
+        x, state = carry
+        with jax.named_scope("loop.pass"):
+            new_state = []
+            for i in range(module.n_layers):
+                x, layer_state, _ = apply_layer(
+                    module, i, params[f"layer{i}"], x, positions,
+                    None if state is None else state[i], view,
+                    lane=t * module.n_kv_heads)
+                new_state.append(layer_state)
+        x, gate = close_pass(module, params, x)
+        return (x, None if state is None else new_state), gate
+
+    (x, state), gates = lax.scan(one_pass, (x, state),
+                                 jnp.arange(module.n_passes))
+    if view is not None:
+        ones = jnp.ones(x.shape[:2], jnp.float32)
+        if jnp.ndim(view.write_at) == 0:        # a prompt segment
+            view.count("loop_prompt_passes", module.n_passes * ones)
+        else:
+            view.count("loop_tokens", ones)
+            view.count("loop_passes", module.n_passes * ones)
+            view.count("loop_exit_expected", jnp.einsum(
+                "t,tbs->bs", 1.0 + jnp.arange(module.n_passes),
+                exit_distribution(gates)))
+    return x, state, gates
 
 
 def hidden_states(module, params: dict, tokens: jax.Array, positions,
@@ -411,6 +517,10 @@ def hidden_states(module, params: dict, tokens: jax.Array, positions,
     `state` is a list of per-layer tuples (or None: a plain forward) and
     `loads` the expert layers' assignment counts, stacked (n, E)."""
     x = module.embed_scale * params["embed"][tokens].astype(module.dtype)
+    if module.n_passes > 1:
+        x, state, _ = looped_stack(module, params, x, positions, state,
+                                   view)
+        return x, state, jnp.zeros((0, module.n_experts), jnp.float32)
     new_state, loads = [], []
     for i in range(module.n_layers):
         x, layer_state, load = apply_layer(
@@ -467,7 +577,10 @@ class HybridLM(nn.Module):
     layer's mixer (`conv` | `full_attention` | `minicpm4` |
     `lightning-attn`); `max_len` caps the positions a decode may reach
     (rotary positions need no table).  The `sparse_*` numbers are those of
-    `ops/sparse_attention.py`, in tokens."""
+    `ops/sparse_attention.py`, in tokens.  `n_passes`, `exit_gate` and
+    `exit_threshold` are the looped model's of the module docstring (full
+    attention over dense MLPs only: no other mixer keeps a state a pass);
+    `sandwich_norm` and `qk_norm` say which norms a layer has."""
 
     vocab_size: int = 256
     d_model: int = 128
@@ -493,6 +606,11 @@ class HybridLM(nn.Module):
     sparse_init_blocks: int = 1
     sparse_topk: int = 64
     sparse_dense_len: int = 8192
+    n_passes: int = 1
+    sandwich_norm: bool = False
+    qk_norm: bool = True
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
     max_len: int = 2048
     dtype: Any = jnp.bfloat16
 
@@ -502,6 +620,24 @@ class HybridLM(nn.Module):
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)} "
                              f"({' | '.join(MIXERS)})")
+        plain = (set(self.layer_types) == {ATTENTION}
+                 and self.n_dense_layers >= len(self.layer_types))
+        if self.n_passes < 1 or (self.n_passes > 1 and not plain):
+            raise ValueError(
+                f"n_passes {self.n_passes}: a looped model (n_passes > 1) "
+                "takes full_attention layers over dense MLPs only")
+        if self.exit_gate and self.n_passes == 1:
+            raise ValueError("exit_gate needs n_passes > 1: one pass has "
+                             "nothing to leave early from")
+        if self.exit_threshold != 1.0:
+            raise ValueError(
+                f"exit_threshold {self.exit_threshold}: no program here "
+                "exits early; only the published 1.0 (every token runs "
+                "every pass) is served")
+        if not self.qk_norm and {SPARSE, LIGHTNING} & set(self.layer_types):
+            raise ValueError(
+                "qk_norm=False is for full_attention layers: a minicpm4 "
+                "or lightning-attn mixer always norms q and k")
         if SPARSE in self.layer_types:
             sparse.check(self.sparse_cfg)
         if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
@@ -529,6 +665,8 @@ class HybridLM(nn.Module):
                "out_norm": (self.d_model,)}
         if not self.tie_embeddings:
             top["head"] = (self.d_model, self.vocab_size)
+        if self.exit_gate:
+            top.update(exit_w=(self.d_model,), exit_b=())
         params = {}
         for i in range(self.n_layers):
             params[f"layer{i}"] = leaves(layer_params_shapes(self, i),
@@ -536,6 +674,7 @@ class HybridLM(nn.Module):
         # the top-level leaves live beside the layers' groups
         for name, shape in top.items():
             init = (nn.initializers.ones if name == "out_norm"
+                    else nn.initializers.zeros if name == "exit_b"
                     else nn.initializers.normal(
                         1.0 if name == "embed" else shape[0] ** -0.5))
             params[name] = self.param(name, init, shape, jnp.float32)
@@ -561,6 +700,15 @@ class Decoding:
     steps on as it is."""
 
     count_names = ()
+    # what a decoding may carry beyond the calls above; `DecodeEngine`
+    # refuses by name what its model's decoding says it lacks: state
+    # layouts other than the model dtype's, the speculative verify segment
+    # (`run_verify`), and a window or weights sharded over a mesh's
+    # 'model' / 'seq' axes (`run_prompt_seq`, `run_step_seq`, partition
+    # rules)
+    cache_dtypes = ("model",)
+    speculates = False
+    shards = False
 
     def __init__(self, module, *, cache_dtype: str = "model",
                  fused: bool = False, verifies: bool = False, hint=None):
@@ -590,8 +738,8 @@ class HybridDecoding(Decoding):
     """`Decoding` for a `HybridLM`: its layers' state kinds and shapes,
     and the calls its programs make.  Every call runs `hidden_states`
     above.  The state has one layout, the model dtype's, unhinted (the
-    engine refuses int8 state and a sharded window for a model with FIXED
-    layers)."""
+    engine refuses int8 state, speculation and a sharded window or
+    weights for every `HybridLM`, whatever its layers' state kinds)."""
 
     def __init__(self, module: HybridLM, **how):
         super().__init__(module, **how)
@@ -601,20 +749,22 @@ class HybridDecoding(Decoding):
         # the expert counts, and the sparse and linear layers' where the
         # model has such layers: a program counts what it can read
         self.count_names = COUNT_NAMES + (
-            SPARSE_COUNT_NAMES if {SPARSE, LIGHTNING} & set(kinds) else ())
+            SPARSE_COUNT_NAMES if {SPARSE, LIGHTNING} & set(kinds) else ()
+        ) + (LOOP_COUNT_NAMES if module.n_passes > 1 else ())
 
     def empty_state(self, rows: int, window: int,
                     resident: bool = False) -> list:
         """Zero state for `rows` rows, a layer at a time: K and V, (rows,
-        window, n_kv_heads, D) each, and under a `minicpm4` layer the
-        compressed keys beside them, (rows, window / stride, n_kv_heads,
-        D) float32; a convolution's last K-1 columns, (rows, K-1, d); a
-        linear-attention layer's S, (rows, n_heads, D, D) float32 whatever
-        the model's dtype is.  The same for a prompt and for a `resident`
-        batch."""
+        window, n_kv_heads, D) each (a looped model's `n_passes` windows
+        side by side: n_passes * n_kv_heads heads), and under a `minicpm4`
+        layer the compressed keys beside them, (rows, window / stride,
+        n_kv_heads, D) float32; a convolution's last K-1 columns, (rows,
+        K-1, d); a linear-attention layer's S, (rows, n_heads, D, D)
+        float32 whatever the model's dtype is.  The same for a prompt and
+        for a `resident` batch."""
         m = self.module
         dh = m.d_model // m.n_heads
-        kv = (rows, window, m.n_kv_heads, dh)
+        kv = (rows, window, m.n_passes * m.n_kv_heads, dh)
         if SPARSE in m.layer_types and window % m.sparse_block:
             raise ValueError(
                 f"a window of {window} slots is not whole blocks of "

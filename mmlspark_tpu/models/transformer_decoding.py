@@ -286,6 +286,10 @@ def forward_with_cache(params: dict, tokens: jax.Array, caches: list,
 class TransformerDecoding(Decoding):
     """`Decoding` for a `TransformerLM`."""
 
+    cache_dtypes = ("model", "int8")
+    speculates = True
+    shards = True
+
     def __init__(self, module, **how):
         super().__init__(module, **how)
         self.state_kinds = (WINDOW,) * module.n_layers

@@ -177,10 +177,12 @@ def _decoding_for(module, **how):
     `run_step` / `run_step_rows`, `close_prompt` / `reopen_prompt` (the
     RESIDENT layout: the one a finished prompt's state is carried in
     between calls and a segment steps on as it is; and back, for a prompt
-    resumed from donor rows) and `relayout_bytes` (what a call of one of
-    its programs re-tiles of the state).  What only some decodings
-    carry (int8 state, the speculative verify segment, the seq-sharded
-    prompt and step, partition rules) each says of itself (`cache_dtypes`,
+    resumed from donor rows), `relayout_bytes` (what a call of one of
+    its programs re-tiles of the state) and `row_writes` (the per-row
+    writes a segment makes into window leaves, and those of them that
+    loop over the rows).  What only some decodings carry (int8 state,
+    the speculative verify segment, the seq-sharded prompt and step,
+    partition rules) each says of itself (`cache_dtypes`,
     `speculates`, `shards`): `DecodeEngine.__init__` refuses the rest by
     name."""
     _check_generatable(module, tuple(_DECODINGS), "DecodeEngine")
@@ -844,6 +846,10 @@ class DecodeEngine:
         # at each dispatch from what the decoding says of the program
         # (`Decoding.relayout_bytes`: shapes alone, nothing is fetched)
         self.relayout_bytes = 0
+        # per-row writes into window leaves that the dispatched segments
+        # and speculative rounds make, and those of them in the looped
+        # form (`Decoding.row_writes`: shapes alone)
+        self.row_writes = self.row_writes_looped = 0
         self._chunk_counts: list = []
         self.max_new_tokens = max_new_tokens
         self.stop_tokens = stop_tokens
@@ -1389,7 +1395,16 @@ class DecodeEngine:
             jnp.asarray(t_row, jnp.int32), row_keys)
         self._program(*key)
         self._count_relayout("step", caches)
+        self._count_row_writes(self._decoding, "step", caches, seg_len)
         return caches, toks, tok, done
+
+    def _count_row_writes(self, decoding, program: str, state,
+                          steps: int) -> None:
+        """Add the per-row writes of the program just dispatched, as its
+        decoding counts them, to `row_writes` / `row_writes_looped`."""
+        writes, looped = decoding.row_writes(program, state, steps)
+        self.row_writes += writes
+        self.row_writes_looped += looped
 
     def _count_relayout(self, program: str, state, tokens: int = 0) -> None:
         """Add what the program just dispatched re-tiles of `state`, as
@@ -1576,6 +1591,12 @@ class DecodeEngine:
             jnp.asarray(t_row, jnp.int32),
             jnp.asarray(round_idx, jnp.int32), row_keys)
         self._program(*key)
+        # the draft's k+1 single-token steps, the target's one verify
+        # segment of k+1 slots a row
+        self._count_row_writes(self._draft_decoding, "step", out[1],
+                               self.spec_tokens + 1)
+        self._count_row_writes(self._decoding, "verify", out[0],
+                               self.spec_tokens + 1)
         return out
 
     @staticmethod

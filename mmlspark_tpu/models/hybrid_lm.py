@@ -185,16 +185,54 @@ def short_conv(p: dict, h: jax.Array, state, view: Optional[StateView],
         return y, kept.astype(state.dtype)
 
 
+def _row_write_is_flat(cache_rank: int, update_slots: int) -> bool:
+    """Whether `_row_write` of `update_slots` slots a row into a leaf of
+    rank `cache_rank` is ONE scatter on the TPU: a decode step's one slot
+    into a rank-3 or rank-4 leaf.  Else it is `vmap` of
+    `dynamic_update_slice`, which the TPU compiler expands into a `while`
+    over the rows.  `Decoding.row_writes` counts by the same rule."""
+    return update_slots == 1 and cache_rank in (3, 4)
+
+
 def _row_write(cache: jax.Array, update: jax.Array, slots: jax.Array,
                lane=0):
     """Write `update` (B, S, ...) into `cache` (B, W, ...) from a PER-ROW
     start slot `slots` (B,) on, and from head `lane` on where the cache
-    holds more heads than the update (a looped model's passes): vmap of
-    the single-row dynamic_update_slice over the batch axis (S is 1 for a
-    decode step, the verify segment's length under speculation)."""
-    zeros = (0,) * (cache.ndim - 3)
-    return jax.vmap(lambda c, u, s: lax.dynamic_update_slice(
-        c, u, (s, lane) + zeros))(cache, update, slots)
+    holds more heads than the update (a looped model's passes).  S is 1
+    for a decode step, the verify segment's length under speculation.
+
+    A decode step's write is one `lax.scatter` in which every leading
+    dimension is named and the window is the trailing dimension alone:
+    the row is a batching dimension, the indices name the slot of a
+    rank-3 leaf and (slot, head) of a rank-4 one.  The TPU compiler keeps
+    that as a single in-place op (it turns the batching dimension into an
+    index of its own), and a mesh that shards the rows still sees them as
+    parallel.  An update that carries a batched PARTIAL window (`vmap` of
+    `dynamic_update_slice`: the slot's dimension stays in the window; kept
+    for S > 1) it expands into a `while` over the rows: 34-44 us a leaf
+    where the write moves 32 KB (PERF.md section 6, PR 35).  Both clamp a
+    start past the window into it, as `dynamic_update_slice` does
+    (`mode="clip"`): a frozen row writes into its own last slot."""
+    if not _row_write_is_flat(cache.ndim, update.shape[1]):
+        zeros = (0,) * (cache.ndim - 3)
+        return jax.vmap(lambda c, u, s: lax.dynamic_update_slice(
+            c, u, (s, lane) + zeros))(cache, update, slots)
+    indices = slots.astype(jnp.int32)[:, None]                  # (B, 1)
+    if cache.ndim == 4:
+        heads = lane + jnp.arange(update.shape[2], dtype=jnp.int32)
+        indices = jnp.stack(jnp.broadcast_arrays(
+            indices, heads[None, :]), axis=-1)                  # (B, KV, 2)
+    inner = tuple(range(1, cache.ndim - 1))
+    # a row's heads in order, no two index rows alike: sorted and unique
+    return lax.scatter(
+        cache, indices, update[:, 0],
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(cache.ndim - 2,),
+            inserted_window_dims=inner,
+            scatter_dims_to_operand_dims=inner,
+            operand_batching_dims=(0,),
+            scatter_indices_batching_dims=(0,)),
+        indices_are_sorted=True, unique_indices=True, mode="clip")
 
 
 def _grouped_attention(q, k, v, visible, scale: float):
@@ -733,6 +771,28 @@ class Decoding:
         (`state_relayout_bytes`).  One layout: nothing, ever."""
         return 0
 
+    def _step_writes(self, state: list) -> list:
+        """`(leaf, times)` for every leaf of `state` that a decode step
+        writes a slot a row into through `_row_write`, `times` a step."""
+        return []
+
+    def row_writes(self, program: str, state: list, steps: int = 1) -> tuple:
+        """`(writes, looped)` of ONE call of `program` on `state`, known
+        from shapes alone: the per-row writes into window leaves, and
+        those of them that take `_row_write`'s looped form (a `while`
+        over the rows on the TPU) and not its flat one.  "step" is a
+        segment of `steps` decode steps, a slot a row at each; "verify"
+        (`run_verify`) writes `steps` slots a row at once.  The serving
+        engine sums them at each dispatch (`row_writes`,
+        `row_writes_looped`)."""
+        slots, calls = (steps, 1) if program == "verify" else (1, steps)
+        writes = looped = 0
+        for leaf, times in self._step_writes(state):
+            writes += times * calls
+            if not _row_write_is_flat(leaf.ndim, slots):
+                looped += times * calls
+        return writes, looped
+
 
 class HybridDecoding(Decoding):
     """`Decoding` for a `HybridLM`: its layers' state kinds and shapes,
@@ -751,6 +811,14 @@ class HybridDecoding(Decoding):
         self.count_names = COUNT_NAMES + (
             SPARSE_COUNT_NAMES if {SPARSE, LIGHTNING} & set(kinds) else ()
         ) + (LOOP_COUNT_NAMES if module.n_passes > 1 else ())
+
+    def _step_writes(self, state: list) -> list:
+        """K and V of every attention layer, once a pass; K and V of a
+        `minicpm4` layer (its compressed keys take `compress_row`)."""
+        m = self.module
+        return [(leaf, m.n_passes if kind == ATTENTION else 1)
+                for kind, layer in zip(m.layer_types, state)
+                if kind in (ATTENTION, SPARSE) for leaf in layer[:2]]
 
     def empty_state(self, rows: int, window: int,
                     resident: bool = False) -> list:

@@ -433,6 +433,10 @@ class TransformerDecoding(Decoding):
             return 0
         return sum(int(c.nbytes) for layer in state for c in layer)
 
+    def _step_writes(self, state: list) -> list:
+        """Every leaf once: K and V, and an int8 cache's two scales."""
+        return [(leaf, 1) for layer in state for leaf in layer]
+
     def _run_step(self, params, tok, pos, state, attend):
         logits, state = _stack(self.module, params, tok, pos, state, attend)
         return logits[:, 0], state, ()

@@ -1765,6 +1765,14 @@ class ServingEngine:
         # segment: it steps on the resident layout as it is)
         out["state_relayout_bytes"] = sum(
             eng.relayout_bytes for eng in self._engines.values())
+        # counts: the per-row writes of a decode step's new K and V into
+        # window leaves that the dispatched segments (and speculative
+        # rounds) made, and those of them that loop over the rows on the
+        # device and are not one flat scatter (0 for a decode step)
+        out["row_writes"] = sum(
+            eng.row_writes for eng in self._engines.values())
+        out["row_writes_looped"] = sum(
+            eng.row_writes_looped for eng in self._engines.values())
         if self._prefix is not None:
             out["prefix"] = self._prefix.stats()
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):

@@ -26,6 +26,10 @@ Layer map (mirrors SURVEY.md section 1 of the reference analysis):
   native/    - C++ host-side runtime pieces (decode, parse, hash)
 """
 
+import time as _time
+
+_t_import = _time.perf_counter()    # the ledger's phase `import`
+
 __version__ = "0.1.0"
 
 from mmlspark_tpu.core.params import Param, Params
@@ -48,3 +52,11 @@ from mmlspark_tpu.config import setup_compilation_cache as _setup_cc
 
 _setup_cc()
 del _setup_cc
+
+# the compile ledger (observe/compiles.py): listens to JAX's compile and
+# cache events from here on; this import is its first phase
+from mmlspark_tpu.observe import compiles as _compiles
+
+_compiles.register()
+_compiles.note_import(_t_import)
+del _compiles, _time, _t_import

@@ -30,6 +30,7 @@ from mmlspark_tpu.models.definitions import (
     build_model,
     model_config,
 )
+from mmlspark_tpu.observe.compiles import setup_phase
 
 
 def registry_name(module: nn.Module) -> str:
@@ -103,9 +104,12 @@ class ModelBundle:
                            if getattr(module, "vocab_size", None) is not None
                            else np.float32)
         x = np.zeros(input_shape, input_dtype)
-        variables = module.init(jax.random.key(seed), x)
-        # unfreeze to plain dict for serialization uniformity
-        variables = jax.tree_util.tree_map(np.asarray, _to_plain(variables))
+        # the eager flax init: every initializer is a program of its own
+        with setup_phase("bundle"):
+            variables = module.init(jax.random.key(seed), x)
+            # unfreeze to plain dict for serialization uniformity
+            variables = jax.tree_util.tree_map(np.asarray,
+                                               _to_plain(variables))
         return ModelBundle.from_module(module, variables, metadata)
 
 

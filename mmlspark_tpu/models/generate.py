@@ -84,6 +84,7 @@ from mmlspark_tpu.models.definitions import TransformerLM
 from mmlspark_tpu.models.hybrid_lm import FIXED, WINDOW, HybridDecoding, HybridLM
 from mmlspark_tpu.models.transformer_decoding import (TransformerDecoding,
                                                       forward_with_cache)
+from mmlspark_tpu.observe import compiles
 from mmlspark_tpu.observe.costmodel import capture_program_cost
 from mmlspark_tpu.observe.spans import active_timings, span_on
 from mmlspark_tpu.observe.telemetry import active_run
@@ -1307,6 +1308,9 @@ class DecodeEngine:
                 meshed(spec_round_impl, "spec_round_meshed"),
                 static_argnums=(0,))
         self._programs: set = set()
+        # a new class's `recompile` event carries what the building thread
+        # compiled since this mark, not since the process began
+        compiles.since_mark()
         self._program_costs: dict = {}  # program key -> captured cost row
         # (captured once at the recompile; replayed into every later
         # run_telemetry block so warm-engine runs still get roofline rows)
@@ -1653,8 +1657,17 @@ class DecodeEngine:
         and surfaces as a telemetry `compile` event (zero-cost inactive)."""
         if key not in self._programs:
             self._programs.add(key)
+            # with what the class cost: the compile ledger's rows that
+            # this thread closed since its last new class (the call that
+            # traced, lowered and compiled or loaded it has returned)
+            cost = compiles.since_mark()
             trace_event("recompile", cat="compile", where="decode",
-                        program=str(key))
+                        program=str(key), programs=int(cost["programs"]),
+                        trace_s=round(cost["trace_s"], 4),
+                        lower_s=round(cost["lower_s"], 4),
+                        backend_s=round(cost["backend_s"]
+                                        + cost["cache_load_s"], 4),
+                        cache_hits=int(cost["cache_hits"]))
 
     def _run_chunked_prefill(self, variables, prompts, true_len, live,
                              row_keys):

@@ -18,6 +18,7 @@ graph.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Iterator, Optional
 
 import jax
@@ -28,6 +29,7 @@ from mmlspark_tpu.core.params import Param
 from mmlspark_tpu.core.pipeline import Transformer
 from mmlspark_tpu.core.table import DataTable
 from mmlspark_tpu.models.bundle import ModelBundle, load_bundle, save_bundle
+from mmlspark_tpu.observe.compiles import setup_phase
 from mmlspark_tpu.observe.costmodel import capture_program_cost
 from mmlspark_tpu.observe.spans import active_timings, span_on
 from mmlspark_tpu.observe.telemetry import active_run
@@ -41,6 +43,10 @@ from mmlspark_tpu.parallel.partition import (UNMATCHED_REPLICATE, shard_tree,
                                              use_mesh)
 from mmlspark_tpu.data import Dataset
 from mmlspark_tpu.parallel.prefetch import OncePerTable, resolve_depth
+
+
+# what a batch of a shape class already scored runs in (`_first_call`)
+_SEEN = contextlib.nullcontext()
 
 
 class TPUModel(Transformer):
@@ -92,6 +98,8 @@ class TPUModel(Transformer):
         # (captured once at the recompile; replayed into every later
         # run_telemetry block, so a warm model's steady-state runs still
         # get roofline rows without paying a fresh AOT capture)
+        self._called_shapes: set = set()    # (shape, dtype) of the batches
+        # scored, traced or not: a NEW one enters `setup.score_program`
 
     # -- model/mesh wiring ---------------------------------------------
     def set_bundle(self, bundle: ModelBundle) -> "TPUModel":
@@ -99,6 +107,7 @@ class TPUModel(Transformer):
         self._device_vars.clear()
         self._compiled.clear()
         self._seen_shapes.clear()
+        self._called_shapes.clear()
         self._program_costs.clear()
         return self
 
@@ -111,6 +120,7 @@ class TPUModel(Transformer):
         self._device_vars.clear()
         self._compiled.clear()
         self._seen_shapes.clear()
+        self._called_shapes.clear()
         self._program_costs.clear()
         return self
 
@@ -253,6 +263,18 @@ class TPUModel(Transformer):
             self._compiled[key] = self._make_apply(mesh, variables)
         return mesh, variables, self._compiled[key]
 
+    def _first_call(self, dev):
+        """The context a batch's call runs in: for the first batch of a
+        shape class the phase `setup.score_program` (jit specializes per
+        class, so that call traces, lowers and compiles or loads a
+        program), for every later one `_SEEN`, which does nothing."""
+        cls = (dev.shape, dev.dtype)
+        if cls in self._called_shapes:
+            return _SEEN
+        self._called_shapes.add(cls)
+        return setup_phase("score_program",
+                           shape_class=f"{tuple(dev.shape)}:{dev.dtype}")
+
     def _effective_batch_size(self, mesh) -> int:
         """miniBatchSize rounded down to a data-axis multiple (floor at one
         row per data shard); all dispatch entry points must agree on it."""
@@ -333,28 +355,29 @@ class TPUModel(Transformer):
                         + [(0, 0)] * (chunk.ndim - 1)
                     chunk = jnp.pad(chunk, pad)
                 dev = reshard(chunk, sharding)  # on-device reshard
+            first_call = self._first_call(dev)
             if tracer is None:
-                with span_on(timings, "compute"):
+                with first_call, span_on(timings, "compute"):
                     out = apply_fn(variables, dev)
             else:
                 key = f"{tuple(dev.shape)}:{dev.dtype}"
-                if key not in self._seen_shapes:
-                    self._seen_shapes.add(key)
-                    tracer.event("recompile", parent=current_span_id(),
-                                 cat="compile", where="tpu_model",
-                                 shape_class=key)
-                    rec = capture_program_cost(apply_fn, (variables, dev),
-                                               where="tpu_model",
-                                               program=key, run=run,
-                                               probe=True)
-                    if rec is not None:
-                        self._program_costs[key] = rec
-                with tracer.span("score.batch",
-                                 parent=current_span_id(), cat="batch",
-                                 shape_class=key, rows=valid,
-                                 device_cached=True) as bsp, \
-                        span_on(timings, "compute"):
-                    out = apply_fn(variables, dev)
+                with first_call:    # round the probe too: it compiles
+                    if key not in self._seen_shapes:
+                        self._seen_shapes.add(key)
+                        tracer.event("recompile", parent=current_span_id(),
+                                     cat="compile", where="tpu_model",
+                                     shape_class=key)
+                        rec = capture_program_cost(
+                            apply_fn, (variables, dev), where="tpu_model",
+                            program=key, run=run, probe=True)
+                        if rec is not None:
+                            self._program_costs[key] = rec
+                    with tracer.span("score.batch",
+                                     parent=current_span_id(), cat="batch",
+                                     shape_class=key, rows=valid,
+                                     device_cached=True) as bsp, \
+                            span_on(timings, "compute"):
+                        out = apply_fn(variables, dev)
                 if run is not None:
                     # dispatch wall only (async) — the roofline uses the
                     # capture probe's synced step time instead.  The cost
@@ -504,8 +527,9 @@ class TPUModel(Transformer):
                     # cross-table pipeline)
                     drain(len(in_flight))
                 else:
+                    first_call = self._first_call(dev)
                     if tracer is None:
-                        with span_on(timings, "compute"):
+                        with first_call, span_on(timings, "compute"):
                             out = apply_fn(variables, dev)
                     else:
                         # the span walls the DISPATCH (async — no sync is
@@ -513,22 +537,25 @@ class TPUModel(Transformer):
                         # new shape class shows as a long batch span plus
                         # an explicit `compile` event
                         key = f"{tuple(dev.shape)}:{dev.dtype}"
-                        if key not in self._seen_shapes:
-                            self._seen_shapes.add(key)
-                            tracer.event("recompile", parent=score_id,
-                                         cat="compile", where="tpu_model",
-                                         shape_class=key)
-                            cost_rec = capture_program_cost(
-                                apply_fn, (variables, dev),
-                                where="tpu_model", program=key, run=run,
-                                probe=True)
-                            if cost_rec is not None:
-                                self._program_costs[key] = cost_rec
-                        with tracer.span("score.batch", parent=score_id,
-                                         cat="batch", shape_class=key,
-                                         rows=valid) as bsp, \
-                                span_on(timings, "compute"):
-                            out = apply_fn(variables, dev)
+                        with first_call:    # round the probe: it compiles
+                            if key not in self._seen_shapes:
+                                self._seen_shapes.add(key)
+                                tracer.event(
+                                    "recompile", parent=score_id,
+                                    cat="compile", where="tpu_model",
+                                    shape_class=key)
+                                cost_rec = capture_program_cost(
+                                    apply_fn, (variables, dev),
+                                    where="tpu_model", program=key, run=run,
+                                    probe=True)
+                                if cost_rec is not None:
+                                    self._program_costs[key] = cost_rec
+                            with tracer.span(
+                                    "score.batch", parent=score_id,
+                                    cat="batch", shape_class=key,
+                                    rows=valid) as bsp, \
+                                    span_on(timings, "compute"):
+                                out = apply_fn(variables, dev)
                         if run is not None:
                             # dispatch wall (async); roofline prefers the
                             # capture probe's synced step time.  The cost
@@ -658,3 +685,4 @@ class TPUModel(Transformer):
         self._device_vars = {}
         self._compiled = {}
         self._seen_shapes = set()
+        self._called_shapes = set()

@@ -47,8 +47,10 @@ into the next.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
+import time
 from typing import Optional
 
 import jax
@@ -57,6 +59,7 @@ import numpy as np
 from mmlspark_tpu import config
 from mmlspark_tpu.models.generate import (DEFAULT_CACHE_CHUNK, FIXED, WINDOW,
                                           DecodeEngine)
+from mmlspark_tpu.observe import compiles
 from mmlspark_tpu.observe.logging import get_logger
 from mmlspark_tpu.observe.metrics import inc_counter
 from mmlspark_tpu.observe.spans import monotonic
@@ -402,6 +405,14 @@ class ServingEngine:
                     f"{name} (role={self.cfg.role!r}): the handoff pages "
                     "window chunks only")
         self._cast_bytes = 0           # the gauge `weights_cast_bytes`
+        # set-up's gauges (`stats()`): seconds of `setup.place_weights`
+        # and `setup.warmup`, the `warmup_program` events, and the host's
+        # clock at `ready` (a row of the compile ledger that ends later
+        # is a program compiled in flight: `compiles_after_ready`)
+        self._place_s = 0.0
+        self._warmup_s = 0.0
+        self._warmup_programs = 0
+        self._ready_at: Optional[float] = None
         self._draft_vars = (self._place_replicated(draft_bundle)
                             if self.cfg.spec_tokens else None)
         self._variables = {"primary": self._place_variables(bundle)}
@@ -490,22 +501,27 @@ class ServingEngine:
         placed tree, so none uploads it again; it stays resident until
         the engine stops."""
         from mmlspark_tpu.parallel.bridge import place_weights
-        t0 = monotonic()
         eng = self._engines["primary" if lane == "draft" else lane]
-        placed = jax.block_until_ready(place_weights(
-            eng.resident_variables(bundle.variables, draft=lane == "draft"),
-            self._mesh, bundle.partition_rules(),
-            replicate_only=replicate_only))
+        with compiles.setup_phase("place_weights", lane=lane) as phase:
+            placed = jax.block_until_ready(place_weights(
+                eng.resident_variables(bundle.variables,
+                                       draft=lane == "draft"),
+                self._mesh, bundle.partition_rules(),
+                replicate_only=replicate_only))
+            nbytes = _device_bytes(placed)
+            if phase.span is not None:
+                phase.span.attrs["bytes"] = nbytes
         cast = sum(
             int(got.nbytes) for got, src in zip(
                 jax.tree_util.tree_leaves(placed),
                 jax.tree_util.tree_leaves(bundle.variables))
             if got.dtype != src.dtype)
         self._cast_bytes += cast
+        self._place_s += phase.seconds
         self._record_serve({"event": "weights_placed", "lane": lane,
-                            "bytes": _device_bytes(placed),
+                            "bytes": nbytes,
                             "cast_bytes": cast,
-                            "seconds": round(monotonic() - t0, 3)})
+                            "seconds": round(phase.seconds, 3)})
         return placed
 
     def _place_replicated(self, bundle):
@@ -540,20 +556,45 @@ class ServingEngine:
             return self
         engine = self._engines["primary"]
         buckets = tuple(self.cfg.warmup_buckets) or (engine.bucket_for(1),)
-        t0 = monotonic()
-        for lane, eng in self._engines.items():
-            variables = self._variables[lane]
-            for bucket in buckets:
-                self._warm_bucket(eng, variables, int(bucket))
-        self._record_serve({"event": "warmup_done",
-                            "buckets": list(map(int, buckets)),
-                            "seconds": round(monotonic() - t0, 3)})
+        buckets = list(map(int, buckets))
+        with compiles.setup_phase("warmup", buckets=buckets) as phase:
+            for lane, eng in self._engines.items():
+                variables = self._variables[lane]
+                for bucket in buckets:
+                    self._warm_bucket(eng, variables, bucket)
+        self._warmup_s = phase.seconds
+        self._record_serve({"event": "warmup_done", "buckets": buckets,
+                            "seconds": round(phase.seconds, 3),
+                            "programs": int(phase.programs),
+                            "cache_hits": int(phase.cache_hits),
+                            "cache_misses": int(phase.cache_misses)})
+        self._ready_at = time.perf_counter()
         self._state = READY
         self._record_serve({"event": "ready"})
         get_logger("serve").info(
-            "serving engine ready: buckets %s warmed in %.2fs",
-            list(buckets), monotonic() - t0)
+            "serving engine ready: buckets %s warmed in %.2fs (%d programs, "
+            "%d from the compile cache)", buckets, phase.seconds,
+            phase.programs, phase.cache_hits)
         return self
+
+    @contextlib.contextmanager
+    def _warm_program(self, kind: str, bucket: int, width: int = 0,
+                      window: int = 0):
+        """One program class of the warm-up: the phase
+        `setup.warm_program` round its call (the trace, the lowering and
+        the compile or the load happen inside it), closed with a
+        `warmup_program` event on the serve timeline.  `width` is the
+        join width (a merge's `k`), `window` the cache width."""
+        with compiles.setup_phase("warm_program", kind=kind, bucket=bucket,
+                                  width=width, window=window) as phase:
+            yield
+        self._warmup_programs += 1
+        self._record_serve({"event": "warmup_program", "kind": kind,
+                            "bucket": bucket, "width": width,
+                            "window": window,
+                            "seconds": round(phase.seconds, 3),
+                            "compiled": int(phase.programs),
+                            "cache_hits": int(phase.cache_hits)})
 
     def _warm_bucket(self, eng: DecodeEngine, variables, bucket: int) -> None:
         """Compile every shape class a full-budget batch in this bucket
@@ -581,18 +622,21 @@ class ServingEngine:
             keys = self._row_keys(np.arange(m))
             if chunks:
                 # the chunked programs are what this bucket runs live
-                state = None
-                for ci in range(chunks):
-                    state = eng.serve_prefill_chunk(variables, prompts,
-                                                    tl, ci, state)
-                tok, done, caches = eng.serve_prefill_finish(state, live,
-                                                             keys)
+                with self._warm_program("prefill_chunk", bucket, m):
+                    state = None
+                    for ci in range(chunks):
+                        state = eng.serve_prefill_chunk(
+                            variables, prompts, tl, ci, state)
+                    tok, done, caches = eng.serve_prefill_finish(
+                        state, live, keys)
             else:
-                tok, done, caches = eng.serve_prefill(variables, prompts,
-                                                      tl, live, keys)
+                with self._warm_program("prefill", bucket, m):
+                    tok, done, caches = eng.serve_prefill(
+                        variables, prompts, tl, live, keys)
             if eng.spec_tokens:
-                dcaches = eng.serve_draft_prefill(self._draft_vars,
-                                                  prompts)
+                with self._warm_program("draft_prefill", bucket, m):
+                    dcaches = eng.serve_draft_prefill(self._draft_vars,
+                                                      prompts)
             cohorts[m] = caches
             if n >= cap:
                 break
@@ -615,10 +659,11 @@ class ServingEngine:
                 m = 1
                 while m < k:
                     m *= 2
-                DecodeEngine.merge_cache_rows(
-                    resident, cohorts[min(m, cap)],
-                    list(range(k)), list(range(k)), mesh=eng.mesh,
-                    kinds=eng.state_kinds)
+                with self._warm_program("merge", bucket, k, width):
+                    DecodeEngine.merge_cache_rows(
+                        resident, cohorts[min(m, cap)],
+                        list(range(k)), list(range(k)), mesh=eng.mesh,
+                        kinds=eng.state_kinds)
 
         budget = np.full(cap, self.cfg.max_new_tokens, np.int32)
         t_row = np.zeros(cap, np.int32)
@@ -634,23 +679,27 @@ class ServingEngine:
             while t < self.cfg.max_new_tokens + k1:
                 tr = np.minimum(t_row + t, self.cfg.max_new_tokens - 1)
                 window = eng.serve_window(bucket, int(tr.max()), k1)
-                (caches, dcaches, _, _, tok, done,
-                 _) = eng.serve_spec_round(
-                    variables, self._draft_vars, caches, dcaches, tok,
-                    done, tl, budget, bucket, tr, rounds, keys, window)
+                with self._warm_program("spec_round", bucket, k1, window):
+                    (caches, dcaches, _, _, tok, done,
+                     _) = eng.serve_spec_round(
+                        variables, self._draft_vars, caches, dcaches, tok,
+                        done, tl, budget, bucket, tr, rounds, keys, window)
                 t += k1
                 rounds += 1
             return
         # as a live group's state comes to be: a segment consumes the
         # state it steps on, and the cohorts are spliced again below
-        caches = DecodeEngine.merge_cache_rows(
-            eng.empty_state(cap, bucket), caches, list(range(cap)),
-            list(range(cap)), mesh=eng.mesh, kinds=eng.state_kinds)
+        with self._warm_program("merge", bucket, cap,
+                                eng.state_window(caches)):
+            caches = DecodeEngine.merge_cache_rows(
+                eng.empty_state(cap, bucket), caches, list(range(cap)),
+                list(range(cap)), mesh=eng.mesh, kinds=eng.state_kinds)
         while t < self.cfg.max_new_tokens:
             window = eng.serve_window(bucket, t, seg)
-            caches, _, tok, done = eng.serve_step(
-                variables, caches, tok, done, tl, budget, bucket, t_row,
-                keys, seg, window)
+            with self._warm_program("segment", bucket, seg, window):
+                caches, _, tok, done = eng.serve_step(
+                    variables, caches, tok, done, tl, budget, bucket,
+                    t_row, keys, seg, window)
             t += seg
             t_row = t_row + seg
             warm_joins(caches)
@@ -662,9 +711,10 @@ class ServingEngine:
                                      seg)
             for _ in range(2):  # (last-ladder-width -> final), then the
                 # steady state (final -> final); re-runs are cache hits
-                caches, _, tok, done = eng.serve_step(
-                    variables, caches, tok, done, tl, budget, bucket,
-                    t_row, keys, seg, final)
+                with self._warm_program("segment", bucket, seg, final):
+                    caches, _, tok, done = eng.serve_step(
+                        variables, caches, tok, done, tl, budget, bucket,
+                        t_row, keys, seg, final)
                 warm_joins(caches)
 
     def begin_drain(self, reason: str = "stop") -> None:
@@ -1773,6 +1823,23 @@ class ServingEngine:
             eng.row_writes for eng in self._engines.values())
         out["row_writes_looped"] = sum(
             eng.row_writes_looped for eng in self._engines.values())
+        # counts, the PROCESS's (observe/compiles.py): programs traced,
+        # lowered and compiled or loaded from the compile cache, with
+        # their seconds; their rise over a window is the compile work
+        # inside it.  `compiles_after_ready`: programs that closed after
+        # this engine's `ready`, each a shape class the warm-up missed
+        for key, value in compiles.totals().items():
+            if key != "saved_s":
+                out["compile_" + key] = value
+        out["compiles_after_ready"] = 0.0 if self._ready_at is None else \
+            compiles.totals(since=self._ready_at)["programs"]
+        # gauges: seconds of this engine's `setup.warmup` and
+        # `setup.place_weights`, its `warmup_program` events, and the
+        # package's import (`/statz` adds the ledger's table, `programs`)
+        out["warmup_s"] = self._warmup_s
+        out["warmup_programs"] = self._warmup_programs
+        out["weights_place_s"] = self._place_s
+        out["import_s"] = compiles.import_s
         if self._prefix is not None:
             out["prefix"] = self._prefix.stats()
         for name, q in (("p50", 50), ("p95", 95), ("p99", 99)):

@@ -48,6 +48,7 @@ import http.server
 import json
 import time
 
+from mmlspark_tpu.observe import compiles
 from mmlspark_tpu.observe.logging import get_logger
 from mmlspark_tpu.serve.admission import InvalidRequest, Overloaded
 from mmlspark_tpu.serve.request import CANCELLED, OK, TIMEOUT
@@ -113,7 +114,10 @@ def make_handler(engine):
                     self._json(503, {"ready": False,
                                      "state": engine.state})
             elif path == "/statz":
-                self._json(200, engine.stats())
+                # beside the numbers, the compile ledger's table: a row a
+                # jitted function of this process (observe/compiles.py)
+                self._json(200, dict(engine.stats(),
+                                     programs=compiles.by_function()))
             elif path == "/tracez":
                 # live waterfall view of the run's slowest requests
                 # (observe/assemble): a debug surface, so the import

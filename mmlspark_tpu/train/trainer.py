@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 from mmlspark_tpu.models.bundle import ModelBundle, _to_plain
 from mmlspark_tpu.models.definitions import build_model
 from mmlspark_tpu.observe import MetricData, get_logger
+from mmlspark_tpu.observe.compiles import setup_phase
 from mmlspark_tpu.observe.costmodel import capture_program_cost
 from mmlspark_tpu.observe.metrics import inc_counter
 from mmlspark_tpu.observe.numerics import (DivergenceError, LossSpikeDetector,
@@ -591,9 +592,10 @@ class Trainer:
         steps_per_epoch = max(1, (n + bs_local - 1) // bs_local)
         total_steps = steps_per_epoch * cfg.epochs
 
-        state = self.init_state((1,) + x.shape[1:], total_steps,
-                                initial_bundle,
-                                input_dtype=np.asarray(x).dtype)
+        with setup_phase("train_state"):    # state init and placement
+            state = self.init_state((1,) + x.shape[1:], total_steps,
+                                    initial_bundle,
+                                    input_dtype=np.asarray(x).dtype)
         # the step numbering this run starts from (0, or the warm-start
         # bundle's recorded step); a resume checkpoint advances past it
         base_step = int(state.step)
@@ -825,6 +827,13 @@ class Trainer:
                     run_step = exec_step if dog is None else (
                         lambda: dog.run(exec_step, step=step_c,
                                         ckpt_dir=ckpt_dir))
+                    if first_exec:
+                        # the first call of the step this fit built: its
+                        # trace, lowering and compile (or cache load)
+                        # happen inside it
+                        def run_step(call=run_step):
+                            with setup_phase("train_step"):
+                                return call()
                     if tracer is None:
                         with span_on(timings, "compute"):
                             state, loss, metrics = run_step()

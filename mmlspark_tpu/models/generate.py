@@ -179,9 +179,10 @@ def _decoding_for(module, **how):
     RESIDENT layout: the one a finished prompt's state is carried in
     between calls and a segment steps on as it is; and back, for a prompt
     resumed from donor rows), `relayout_bytes` (what a call of one of
-    its programs re-tiles of the state) and `row_writes` (the per-row
+    its programs re-tiles of the state), `row_writes` (the per-row
     writes a segment makes into window leaves, and those of them that
-    loop over the rows).  What only some decodings carry (int8 state,
+    loop over the rows) and `read_block` (the K/V block in which a step's
+    fused read streams a window, None where steps read otherwise).  What only some decodings carry (int8 state,
     the speculative verify segment, the seq-sharded prompt and step,
     partition rules) each says of itself (`cache_dtypes`,
     `speculates`, `shards`): `DecodeEngine.__init__` refuses the rest by
@@ -539,6 +540,15 @@ def _grow_state(caches: list, window: int, kinds=None,
             else tuple(hint(_grow_cache(
                 c, window * c.shape[1] // layer[0].shape[1])) for c in layer)
             for i, layer in enumerate(caches)]
+
+
+def serve_visible(slots, true_len, bucket, slot):
+    """A serving segment's per-row mask (B, W) at one step: row r sees its
+    true prompt [0, true_len[r]) and its decode slots [bucket, slot[r]].
+    Operators alone, so the segment (`jax` arrays) and the serving
+    engine's host count of the K/V blocks it reads (`numpy`) share it."""
+    return ((slots[None, :] < true_len[:, None])
+            | ((slots[None, :] >= bucket) & (slots[None, :] <= slot[:, None])))
 
 
 def _state_window(caches: list, kinds=None) -> int:
@@ -1018,9 +1028,7 @@ class DecodeEngine:
                 t = t_row + s_off                     # (B,) per-row step
                 slot = jnp.minimum(bucket + t, window - 1)
                 pos = jnp.minimum(true_len + t, max_pos)
-                visible = ((slots_axis[None, :] < true_len[:, None])
-                           | ((slots_axis[None, :] >= bucket)
-                              & (slots_axis[None, :] <= slot[:, None])))
+                visible = serve_visible(slots_axis, true_len, bucket, slot)
                 logits, caches, new = decoding.run_step_rows(
                     params, tok, pos, slot, caches, visible, ~done)
                 nxt = sample(logits, row_keys, t + 1)
@@ -1401,6 +1409,27 @@ class DecodeEngine:
         self._count_relayout("step", caches)
         self._count_row_writes(self._decoding, "step", caches, seg_len)
         return caches, toks, tok, done
+
+    def serve_blocks(self, true_len, bucket: int, t_row, seg_len: int,
+                     window: int) -> tuple:
+        """`(listed, dense)`: the K/V blocks that the fused read of ONE
+        `serve_step` at `window` lists (`decode_attention.block_table`
+        over the segment's masks), summed over its rows and steps as one
+        layer reads them, and those a dense grid would read; `(0, 0)`
+        where the decoding's steps do not read through the kernel."""
+        from mmlspark_tpu.ops.decode_attention import block_table
+        block = self._decoding.read_block(window)
+        if block is None:
+            return 0, 0
+        true_len, t_row = np.asarray(true_len), np.asarray(t_row)
+        rows = len(true_len)
+        # the segment's steps as rows of one mask: (seg_len * B, W)
+        slot = np.minimum(bucket + t_row[None, :]
+                          + np.arange(seg_len)[:, None], window - 1)
+        visible = serve_visible(np.arange(window), np.tile(true_len, seg_len),
+                                bucket, slot.reshape(-1))
+        listed = int(block_table(visible, block)[1].sum())
+        return listed, rows * seg_len * (window // block)
 
     def _count_row_writes(self, decoding, program: str, state,
                           steps: int) -> None:

@@ -776,6 +776,12 @@ class Decoding:
         writes a slot a row into through `_row_write`, `times` a step."""
         return []
 
+    def read_block(self, window: int):
+        """The K/V block in which a decode step's fused read
+        (`ops/decode_attention.py`) streams a `window`; None where steps
+        read the windows otherwise."""
+        return None
+
     def row_writes(self, program: str, state: list, steps: int = 1) -> tuple:
         """`(writes, looped)` of ONE call of `program` on `state`, known
         from shapes alone: the per-row writes into window leaves, and

@@ -437,6 +437,15 @@ class TransformerDecoding(Decoding):
         """Every leaf once: K and V, and an int8 cache's two scales."""
         return [(leaf, 1) for layer in state for leaf in layer]
 
+    def read_block(self, window: int):
+        """`Decoding.read_block`: the compiled kernel's block for a window
+        of the cache's dtype, where steps read through it (`fused`)."""
+        if not self.fused:
+            return None
+        from mmlspark_tpu.ops.decode_attention import read_block
+        return read_block(window, jnp.int8 if self.cache_dtype == "int8"
+                          else self.module.dtype)
+
     def _run_step(self, params, tok, pos, state, attend):
         logits, state = _stack(self.module, params, tok, pos, state, attend)
         return logits[:, 0], state, ()

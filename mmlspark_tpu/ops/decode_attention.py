@@ -32,6 +32,17 @@ weights are expanded back through its transpose.  Scores and statistics
 live in a 128-lane tile (one lane per head, padded with NEG_INF), so
 H <= 128.
 
+Which blocks: only those that hold at least one visible slot of the row.
+`block_table` lists them from the mask alone, ascending, and the grid's
+index maps read the list (scalar-prefetched), so a block the mask hides
+whole — a serving row's hole between its prompt and its bucket, the
+slots past its last decode step, the prompt of a released row — is never
+DMA'd.  A fully-masked block is an exact no-op in the online-softmax
+fold, so the listed blocks give the dense grid's result bit for bit
+(tests/test_decode_attention.py, NaN in every unlisted block included):
+the result does not depend on the list, only the bytes read do.  A mask
+whose every block holds a visible slot reads every block, as before.
+
 On the CPU, for windows with no sublane-aligned divisor, or inside a
 shard_map manual region, the wrapper falls back to the reference — the
 engine's CPU tier-1 path exercises exactly that path, while parity tests
@@ -45,6 +56,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,16 +111,20 @@ def _scale_pad(n_heads: int):
     return (rows == cols).astype(jnp.float32)
 
 
-def _sqa_kernel(q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref, out_refs,
-                acc_ref, m_ref, l_ref, *, scale: float, n_heads: int,
-                head_dim: int, block_k: int, emit_stats: bool = False):
-    """One (batch row, k-block) grid step.
+def _sqa_kernel(count_ref, q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref,
+                out_refs, acc_ref, m_ref, l_ref, *, scale: float,
+                n_heads: int, head_dim: int, block_k: int,
+                emit_stats: bool = False):
+    """One (batch row, listed block) grid step.
 
-    The grid's inner dimension walks the window's K/V blocks; the
-    online-softmax state (acc, running max m, normalizer l) persists in
-    VMEM scratch across those steps (TPU grids execute minor-to-major on
-    one core), so VMEM holds one K/V block at a time and the window is
-    bounded by HBM, not VMEM.
+    The grid's inner dimension walks the row's LISTED K/V blocks
+    (`block_table`: those that hold a visible slot, ascending, then the
+    last one repeated); the online-softmax state (acc, running max m,
+    normalizer l) persists in VMEM scratch across those steps (TPU grids
+    execute minor-to-major on one core), so VMEM holds one K/V block at a
+    time and the window is bounded by HBM, not VMEM.  Steps past the
+    row's count fold nothing, and their repeated block index issues no
+    DMA.
 
     `out_refs` is `(o_ref,)` for the normalized read, or — with
     `emit_stats` — `(acc_out, m_out, l_out)`: the final block then writes
@@ -124,6 +140,37 @@ def _sqa_kernel(q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref, out_refs,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
+    @pl.when(j < count_ref[pl.program_id(0)])
+    def _():
+        _fold(q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref, acc_ref, m_ref,
+              l_ref, scale=scale, n_heads=n_heads, head_dim=head_dim,
+              block_k=block_k)
+
+    @pl.when(j == nk - 1)
+    def _():
+        if emit_stats:
+            oa_ref, om_ref, ol_ref = out_refs
+            oa_ref[0] = acc_ref[:][0:1].astype(oa_ref.dtype)
+            om_ref[0] = m_ref[:][0:1].astype(om_ref.dtype)
+            ol_ref[0] = l_ref[:][0:1].astype(ol_ref.dtype)
+            return
+        (o_ref,) = out_refs
+        l_fin = l_ref[:][0:1]
+        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+        l_exp = jax.lax.dot_general(l_safe, _head_selector(n_heads,
+                                                           head_dim),
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        # a fully-masked row (l == 0 in every lane) divides 0 by 1 -> 0;
+        # l_exp of a pad lane is 0 only where acc is also 0
+        l_exp = jnp.where(l_exp == 0.0, 1.0, l_exp)
+        o_ref[0] = (acc_ref[:][0:1] / l_exp).astype(o_ref.dtype)
+
+
+def _fold(q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref, acc_ref, m_ref,
+          l_ref, *, scale: float, n_heads: int, head_dim: int,
+          block_k: int):
+    """Fold one K/V block into the online-softmax state."""
     sel = _head_selector(n_heads, head_dim)
 
     q = q_ref[0].astype(jnp.float32) * scale            # (1, H*D)
@@ -172,25 +219,29 @@ def _sqa_kernel(q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref, out_refs,
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == nk - 1)
-    def _():
-        if emit_stats:
-            oa_ref, om_ref, ol_ref = out_refs
-            oa_ref[0] = acc_ref[:][0:1].astype(oa_ref.dtype)
-            om_ref[0] = m_ref[:][0:1].astype(om_ref.dtype)
-            ol_ref[0] = l_ref[:][0:1].astype(ol_ref.dtype)
-            return
-        (o_ref,) = out_refs
-        l_fin = l_ref[:][0:1]
-        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        l_exp = jax.lax.dot_general(l_safe, _head_selector(n_heads,
-                                                           head_dim),
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        # a fully-masked row (l == 0 in every lane) divides 0 by 1 -> 0;
-        # l_exp of a pad lane is 0 only where acc is also 0
-        l_exp = jnp.where(l_exp == 0.0, 1.0, l_exp)
-        o_ref[0] = (acc_ref[:][0:1] / l_exp).astype(o_ref.dtype)
+
+def block_table(visible, block_k: int):
+    """The K/V blocks a fused read streams, from the mask alone: `(table
+    (B, L // block_k) int32, count (B,) int32)`.  Row i's blocks that
+    hold at least one visible slot come first, ascending, then its last
+    listed block repeated (block L // block_k - 1 for a row that sees
+    nothing); `count[i]` of them are listed.
+
+    One rule for the kernel (`jax` arrays) and the serving engine's host
+    count of `decode_blocks_read` (`numpy` arrays), so that the two cannot
+    drift: the ops below are methods and operators of both."""
+    xp = jnp if isinstance(visible, jax.Array) else np
+    b, l = visible.shape
+    nb = l // block_k
+    seen = visible.reshape(b, nb, block_k).any(axis=2)          # (B, nb)
+    count = seen.sum(axis=1)
+    # the (j+1)-th listed block is the number of blocks before the one at
+    # which the running count of visible blocks passes j
+    upto = xp.minimum(xp.arange(nb)[None, :],
+                      xp.maximum(count - 1, 0)[:, None])
+    table = (seen.cumsum(axis=1)[:, None, :] <= upto[:, :, None]).sum(-1)
+    return (xp.minimum(table, nb - 1).astype(xp.int32),
+            count.astype(xp.int32))
 
 
 def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
@@ -199,6 +250,7 @@ def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
     b, h, d = q.shape
     l = k_cache.shape[1]
     hd = h * d
+    nk = l // block_k
     # head-fold views: free for a window handed over folded; of a
     # (B, L, H, D) window the TPU makes a re-tiled copy
     q3 = q.reshape(b, 1, hd)
@@ -206,61 +258,70 @@ def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
     v3 = v_cache.reshape(b, l, hd)
     vis3 = visible.astype(jnp.int32).reshape(b, l, 1)
     quantized = k_scale is not None
+    table, count = block_table(visible, block_k)
+
+    # the index maps read the row's block list (scalar-prefetched into
+    # SMEM): a block that holds no visible slot is never DMA'd
+    def row(i, j, table, count):
+        return (i, 0, 0)
+
+    def listed(i, j, table, count):
+        return (i, table[i * nk + j], 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, block_k, hd), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, block_k, 1), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((1, 1, hd), row),
+        pl.BlockSpec((1, block_k, hd), listed),
+        pl.BlockSpec((1, block_k, hd), listed),
+        pl.BlockSpec((1, block_k, 1), listed),
     ]
     args = [q3, k3, v3, vis3]
     if quantized:
-        in_specs += [pl.BlockSpec((1, block_k, h), lambda i, j: (i, j, 0)),
-                     pl.BlockSpec((1, block_k, h), lambda i, j: (i, j, 0))]
+        in_specs += [pl.BlockSpec((1, block_k, h), listed),
+                     pl.BlockSpec((1, block_k, h), listed)]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
     n_out = 3 if emit_stats else 1
 
-    def kernel(q_ref, k_ref, v_ref, vis_ref, *rest):
+    def kernel(table_ref, count_ref, q_ref, k_ref, v_ref, vis_ref, *rest):
         if quantized:
             ks_ref, vs_ref, rest = rest[0], rest[1], rest[2:]
         else:
             ks_ref, vs_ref = None, None
         out_refs, (acc_ref, m_ref, l_ref) = rest[:n_out], rest[n_out:]
-        _sqa_kernel(q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref, out_refs,
-                    acc_ref, m_ref, l_ref, scale=scale, n_heads=h,
+        _sqa_kernel(count_ref, q_ref, k_ref, v_ref, vis_ref, ks_ref, vs_ref,
+                    out_refs, acc_ref, m_ref, l_ref, scale=scale, n_heads=h,
                     head_dim=d, block_k=block_k, emit_stats=emit_stats)
 
     if emit_stats:
         # raw statistics: acc on the folded lanes, m/l one lane per head
-        out_specs = [pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0)),
-                     pl.BlockSpec((1, 1, _STATS_LANES),
-                                  lambda i, j: (i, 0, 0)),
-                     pl.BlockSpec((1, 1, _STATS_LANES),
-                                  lambda i, j: (i, 0, 0))]
+        out_specs = [pl.BlockSpec((1, 1, hd), row),
+                     pl.BlockSpec((1, 1, _STATS_LANES), row),
+                     pl.BlockSpec((1, 1, _STATS_LANES), row)]
         out_shape = [jax.ShapeDtypeStruct((b, 1, hd), jnp.float32),
                      jax.ShapeDtypeStruct((b, 1, _STATS_LANES),
                                           jnp.float32),
                      jax.ShapeDtypeStruct((b, 1, _STATS_LANES),
                                           jnp.float32)]
     else:
-        out_specs = pl.BlockSpec((1, 1, hd), lambda i, j: (i, 0, 0))
+        out_specs = pl.BlockSpec((1, 1, hd), row)
         out_shape = jax.ShapeDtypeStruct((b, 1, hd), jnp.float32)
 
     out = pl.pallas_call(
         kernel,
-        grid=(b, l // block_k),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nk),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((8, hd), jnp.float32),            # acc (lanes)
+                pltpu.VMEM((8, _STATS_LANES), jnp.float32),  # max / head
+                pltpu.VMEM((8, _STATS_LANES), jnp.float32),  # norm / head
+            ]),
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((8, hd), jnp.float32),            # acc (folded lanes)
-            pltpu.VMEM((8, _STATS_LANES), jnp.float32),  # running max / head
-            pltpu.VMEM((8, _STATS_LANES), jnp.float32),  # normalizer / head
-        ],
         interpret=interpret,
         name="decode_sqa",
-    )(*args)
+    )(table.reshape(-1), count, *args)
     if emit_stats:
         acc, m, lsum = out
         return (acc.reshape(b, h, d), m[:, 0, :h], lsum[:, 0, :h])
@@ -272,7 +333,9 @@ def _fused_forward(q, k_cache, v_cache, visible, scale, k_scale, v_scale,
 # at its default chunk — odd multiples of 128 included (384, 640, 1152 …)
 # — tiles with ONE block shape.  A fully-masked block is an exact no-op in
 # the online-softmax fold, so with one block shape a row's result does
-# not depend on how wide a window its batch neighbours forced.
+# not depend on how wide a window its batch neighbours forced, nor on
+# which of its blocks `block_table` lists.  64 reads fewer masked slots
+# and is slower on the chip, all-visible or not (PERF.md section 6, PR 37).
 DEFAULT_BLOCK_K = 128
 
 
@@ -287,6 +350,20 @@ def _fit_block_k(window: int, block_k: int, sublane: int) -> Optional[int]:
     return None
 
 
+def _sublane(dtype) -> int:
+    """Mosaic's sublane tile of a cache dtype: (8, 128) f32, (16, 128)
+    bf16, (32, 128) int8."""
+    return {jnp.int8.dtype: 32, jnp.bfloat16.dtype: 16}.get(
+        jnp.dtype(dtype), 8)
+
+
+def read_block(window: int, dtype,
+               block_k: int = DEFAULT_BLOCK_K) -> Optional[int]:
+    """The K/V block the compiled kernel streams a `window` of cache
+    `dtype` in (None: it hands such a window to the reference)."""
+    return _fit_block_k(window, block_k, _sublane(dtype))
+
+
 def _plan(q, k_cache, k_scale, v_scale, block_k: int, interpret: bool):
     """(block_k, None) when the fused kernel can read this window, else
     (None, reason) — the one fallback ladder both wrappers share."""
@@ -299,10 +376,8 @@ def _plan(q, k_cache, k_scale, v_scale, block_k: int, interpret: bool):
         return None, "mixed quantization (k_scale xor v_scale)"
     if h > _STATS_LANES:
         return None, f"n_heads {h} exceeds the {_STATS_LANES}-lane stats tile"
-    # mosaic sublane tiles: (8, 128) f32 / (16, 128) bf16 / (32, 128) int8
-    # — the K/V block's sublane dim is block_k (the interpreter takes any)
-    sub = 1 if interpret else {jnp.int8.dtype: 32, jnp.bfloat16.dtype: 16
-                               }.get(k_cache.dtype, 8)
+    # the K/V block's sublane dim is block_k (the interpreter takes any)
+    sub = 1 if interpret else _sublane(k_cache.dtype)
     fit = _fit_block_k(l, block_k, sub)
     if fit is None:
         return None, (f"window {l} has no divisor <= block_k {block_k} "
@@ -361,7 +436,9 @@ def fused_single_query_attention(q: jax.Array, k_cache: jax.Array,
     entry for any `pallas_call` site in ops/.
 
     `block_k` is an upper bound: the kernel streams the window in the
-    largest block under it that tiles the window (`_fit_block_k`).
+    largest block under it that tiles the window (`_fit_block_k`), and
+    of those only the blocks that hold a visible slot of the row
+    (`block_table`).
     `interpret=None` resolves by platform: an accelerator compiles the
     kernel, the CPU takes the reference path (the interpreter inside a
     decode scan would be pure overhead — tier-1 CPU runs cover that
@@ -398,5 +475,5 @@ def fused_single_query_attention_stats(q: jax.Array, k_cache: jax.Array,
                  block_k, interpret, emit_stats=True)
 
 
-__all__ = ["fused_single_query_attention",
-           "fused_single_query_attention_stats"]
+__all__ = ["block_table", "fused_single_query_attention",
+           "fused_single_query_attention_stats", "read_block"]

@@ -1613,8 +1613,8 @@ class ServingEngine:
         live = g.live_slots()
         max_t = int(g.t_row[live].max()) if live else 0
         window = eng.serve_window(g.bucket, max_t, seg)
-        # the segment reads the whole cache width for every slot, whatever
-        # the masks (a resident cache never shrinks: `serve_step`)
+        # the segment steps at the whole cache width, whatever the masks
+        # (a resident cache never shrinks: `serve_step`)
         read = max(window, eng.state_window(g.caches))
         # overlapped: behind another program of this engine that nobody
         # has fetched yet (a prefill is fetched where it is dispatched, so
@@ -1662,6 +1662,10 @@ class ServingEngine:
             self._run.observe_hist("serve.inter_token_s", own / seg)
         g.tok = tok_h.astype(np.int32)
         g.done = done_h.astype(bool)
+        # the segment's masks are its dispatch's rows: count before the
+        # rows move on
+        blocks_read, blocks_dense = eng.serve_blocks(
+            g.true_len, g.bucket, g.t_row, seg, f.read)
         # a row is live for the steps whose tokens it keeps: one that
         # stops or spends its budget inside the segment is frozen for
         # the rest.  At its s-th step it sees its prompt and
@@ -1688,7 +1692,9 @@ class ServingEngine:
             "slot_steps_live": steps_live,
             "slot_steps_capacity": g.capacity * seg,
             "decode_keys_live": keys_live,
-            "decode_keys_read": g.capacity * seg * f.read})
+            "decode_keys_read": g.capacity * seg * f.read,
+            "decode_blocks_read": blocks_read,
+            "decode_blocks_dense": blocks_dense})
         if self._run is not None:
             self._run.gauge("serve.queue_depth", self.admission.pending())
             self._run.gauge("serve.in_flight", self.in_flight())

@@ -261,3 +261,159 @@ def test_fused_stats_fully_masked_shard_is_identity():
     assert float(jnp.max(jnp.abs(acc))) == 0.0
     assert float(jnp.max(lsum)) == 0.0
     assert bool(jnp.all(m <= -1e30))
+
+
+# ---- the visible-block read (ops/decode_attention.block_table) ----------
+
+def _dense_table(visible, block_k):
+    """The parent's grid: every block of every row, in order."""
+    b, l = visible.shape
+    nb = l // block_k
+    table = jnp.broadcast_to(jnp.arange(nb, dtype=jnp.int32), (b, nb))
+    return table, jnp.full((b,), nb, jnp.int32)
+
+
+def _serving_mask(true_len, bucket, slot, window):
+    """The serving segment's two intervals a row: [0, true_len) and
+    [bucket, slot] (models/generate.serve_visible)."""
+    from mmlspark_tpu.models.generate import serve_visible
+    return serve_visible(np.arange(window), np.asarray(true_len), bucket,
+                         np.asarray(slot))
+
+
+# window, bucket, rows' (true_len, slot): live rows with bucket holes, an
+# all-visible row, a released row (slot 0 and the decode tail), a row that
+# sees nothing
+SERVING = {
+    640: (512, [(300, 517), (512, 639), (1, 519), (0, -1), (257, 600)]),
+    1152: (1024, [(600, 1030), (1024, 1151), (1, 1031), (0, -1),
+                  (513, 1100)]),
+}
+
+
+def _serving_case(window, seed, dtype=jnp.float32, h=2, d=64):
+    bucket, rows = SERVING[window]
+    true_len, slot = zip(*rows)
+    rng = np.random.default_rng(seed)
+    b = len(rows)
+    q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, window, h * d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, window, h * d)), dtype)
+    return q, k, v, jnp.asarray(_serving_mask(true_len, bucket, slot,
+                                              window))
+
+
+def _read(q, k, v, visible, stats=False, **kw):
+    from mmlspark_tpu.ops.decode_attention import (
+        fused_single_query_attention_stats)
+    fn = (fused_single_query_attention_stats if stats
+          else fused_single_query_attention)
+    return fn(q, k, v, visible, block_k=128, interpret=INTERPRET, **kw)
+
+
+def _dense_read(monkeypatch, *args, **kw):
+    from mmlspark_tpu.ops import decode_attention
+    with monkeypatch.context() as m:
+        m.setattr(decode_attention, "block_table", _dense_table)
+        return _read(*args, **kw)
+
+
+def _equal(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("stats", [False, True], ids=["normalized", "stats"])
+@pytest.mark.parametrize("quant", [False, True], ids=["model", "int8"])
+@pytest.mark.parametrize("window", [640, 1152])
+def test_listed_blocks_read_the_dense_grids_result_bit_for_bit(
+        window, quant, stats, monkeypatch):
+    """Skipping the blocks that hold no visible slot folds the same blocks
+    in the same order as the dense grid: bit for bit, on the serving
+    engine's two-interval masks with bucket holes, an all-visible row, a
+    released row and a row that sees nothing; int8 scales follow the same
+    list; the stats variant too."""
+    q, k, v, visible = _serving_case(window, seed=20 + window)
+    kw = {}
+    if quant:
+        h, d = q.shape[1:]
+        (k, ks), (v, vs) = (quantize_kv(c.reshape(c.shape[:2] + (h, d)))
+                            for c in (k, v))
+        k, v = (c.reshape(c.shape[:2] + (-1,)) for c in (k, v))
+        kw = dict(k_scale=ks, v_scale=vs)
+    got = _read(q, k, v, visible, stats=stats, **kw)
+    want = _dense_read(monkeypatch, q, k, v, visible, stats=stats, **kw)
+    _equal(got, want)
+    # and the read is the reference's where a row sees anything (the
+    # kernel reads a row that sees nothing as 0, the reference as the mean)
+    if not stats:
+        h, d = q.shape[1:]
+        unfold = lambda c: c.reshape(c.shape[:2] + (h, d))
+        ref = single_query_attention(q, unfold(k), unfold(v), visible, **kw)
+        sees = np.asarray(visible).any(1)
+        np.testing.assert_allclose(np.asarray(got)[sees],
+                                   np.asarray(ref)[sees], **TOL)
+        assert float(jnp.abs(got[~sees]).max()) == 0.0
+
+
+@pytest.mark.parametrize("window", [640, 1152])
+def test_blocks_with_no_visible_slot_are_never_read(window, monkeypatch):
+    """Every block that holds no visible slot is NaN: the listed read stays
+    finite and equal to the read of the clean window, where the dense
+    grid's fold takes in the NaNs (0 x NaN)."""
+    from mmlspark_tpu.ops.decode_attention import block_table
+    q, k, v, visible = _serving_case(window, seed=40 + window)
+    table, count = block_table(visible, 128)
+    nb = window // 128
+    listed = np.zeros((q.shape[0], nb), bool)
+    for i, c in enumerate(np.asarray(count)):
+        listed[i, np.asarray(table)[i, :c]] = True
+    poison = jnp.asarray(np.repeat(~listed, 128, axis=1))[..., None]
+    kp, vp = (jnp.where(poison, jnp.nan, c) for c in (k, v))
+    clean = _read(q, k, v, visible)
+    got = _read(q, kp, vp, visible)
+    assert bool(jnp.isfinite(got).all())
+    _equal(got, clean)
+    dense = _dense_read(monkeypatch, q, kp, vp, visible)
+    assert not bool(jnp.isfinite(dense).all())
+
+
+def _reference_table(visible, block_k):
+    """`block_table` written out a row at a time."""
+    b, l = visible.shape
+    nb = l // block_k
+    table = np.zeros((b, nb), np.int32)
+    count = np.zeros(b, np.int32)
+    for i in range(b):
+        ids = [j for j in range(nb)
+               if visible[i, j * block_k:(j + 1) * block_k].any()]
+        count[i] = len(ids)
+        ids = ids or [nb - 1]
+        table[i] = ids + [ids[-1]] * (nb - len(ids))
+    return table, count
+
+
+@pytest.mark.parametrize("density", [0.0, 0.002, 0.02, 0.3, 1.0])
+def test_block_table_matches_a_row_by_row_reference(density):
+    from mmlspark_tpu.ops.decode_attention import block_table
+    rng = np.random.default_rng(int(density * 1000))
+    for block_k, l in ((128, 1152), (128, 640), (16, 96), (64, 64)):
+        visible = rng.random((7, l)) < density
+        want = _reference_table(visible, block_k)
+        for table, count in (block_table(visible, block_k),
+                             jax.jit(block_table, static_argnums=1)(
+                                 jnp.asarray(visible), block_k)):
+            np.testing.assert_array_equal(np.asarray(table), want[0])
+            np.testing.assert_array_equal(np.asarray(count), want[1])
+            assert np.asarray(table).dtype == np.int32
+
+
+def test_read_block_is_the_compiled_kernels_block():
+    from mmlspark_tpu.ops.decode_attention import read_block
+    for window in (640, 1152):
+        assert read_block(window, jnp.bfloat16) == 128
+        assert read_block(window, jnp.int8) == 128
+    assert read_block(96, jnp.float32) == 96
+    assert read_block(48, "bfloat16") == 48
+    assert read_block(48, jnp.int8) is None

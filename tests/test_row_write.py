@@ -308,11 +308,13 @@ def _two_layers(name: str):
     return cfg["architecture"], dict(c, n_layers=2), 96, 128, 1152
 
 
-def _compiled_segment(name: str, one_chip):
+def _compiled_segment(name: str, one_chip, window=None):
     """`DecodeEngine._serve_segment`, 8 rows x 8 steps, compiled by the
-    TPU's own compiler from shapes on the described chip: `(text, leaf
-    elements, temporaries' bytes)`."""
-    arch, c, new, chunk, window = _two_layers(name)
+    TPU's own compiler from shapes on the described chip (at `window`, or
+    the configuration's own): `(text, leaf elements, temporaries'
+    bytes)`."""
+    arch, c, new, chunk, own = _two_layers(name)
+    window = window or own
     module = build_model(arch, c)
     eng = DecodeEngine(module, new, chunk=chunk)
     on = lambda tree: jax.tree_util.tree_map(
@@ -378,3 +380,49 @@ def test_the_compiled_segment_holds_no_loop_a_leaf(name, one_chip,
     assert len(re.findall(r" scatter\(", text)) == 4
     assert _window_sized_copies(text, elements) == []
     assert temporaries <= parent_temporaries
+
+
+# -- the fused read of the listed blocks (ops/decode_attention.py) ------------
+
+def _operand(text: str, name: str) -> str:
+    """The line that defines instruction `%name`."""
+    return next(line for line in text.splitlines()
+                if line.lstrip().startswith(f"%{name} = "))
+
+
+def _source(text: str, name: str) -> str:
+    """`name`, or what an asynchronous copy of it copies, followed back."""
+    line = _operand(text, name)
+    m = re.search(r" copy-(?:done|start)\(%([\w.\-]+)\)", line)
+    return _source(text, m.group(1)) if m else name
+
+
+@pytest.mark.parametrize("window", [640, 1152])
+def test_the_compiled_segment_reads_its_windows_from_hbm_through_one_table(
+        window, one_chip, monkeypatch):
+    """The cgpt segment cut to 2 layers, compiled for a described v5e: one
+    `decode_sqa` a layer; the block table and counts it reads are built
+    once a step and shared by every layer's call (24 calls, one table, in
+    the whole model); no K or V window reaches the kernel through a
+    `copy-done` or from the chip's fast memory (`S(1)`), where XLA would
+    have streamed it whole whatever the kernel lists."""
+    from mmlspark_tpu.ops import decode_attention
+    monkeypatch.setattr(decode_attention, "_auto_interpret", lambda: False)
+    text, elements, _ = _compiled_segment("cerebras-gpt-1.3b", one_chip,
+                                          window)
+    calls = [re.search(r"custom-call\(([^)]*)\)", line).group(1)
+             for line in text.splitlines()
+             if re.match(r"\s*%decode_sqa[.\d]* = ", line)]
+    assert len(calls) == 2
+    operands = [[o.strip().split("*/")[-1].lstrip("%")
+                 for o in c.split(",")] for c in calls]
+    # (table, count, q, k, v, visible): the first two are one pair, or
+    # copies of it
+    assert len({tuple(_source(text, n) for n in o[:2])
+                for o in operands}) == 1
+    for o in operands:
+        for name in o[3:5]:
+            line = _operand(text, name)
+            assert "copy-done(" not in line and "S(1)" not in line.split(
+                " = ")[1].split(" ")[0], line[:160]
+    assert _window_sized_copies(text, elements) == []

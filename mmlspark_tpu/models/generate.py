@@ -574,6 +574,13 @@ def _merge_cache_rows_jit(dst_caches, src_caches, di, si, kinds=None):
     return merged
 
 
+@jax.jit
+def _seat_rows_jit(tok, done, src_tok, src_done, dst):
+    # a `dst` entry past the batch is a cohort's pad row: dropped
+    return (tok.at[dst].set(src_tok, mode="drop"),
+            done.at[dst].set(src_done, mode="drop"))
+
+
 _PAGE_LAYOUT = None  # lazy structs for the KV-page wire layout
 
 
@@ -1409,6 +1416,24 @@ class DecodeEngine:
         self._count_relayout("step", caches)
         self._count_row_writes(self._decoding, "step", caches, seg_len)
         return caches, toks, tok, done
+
+    def serve_seat(self, tok, done, cohort_tok, cohort_done,
+                   dst_rows: list):
+        """The `tok` / `done` a segment starts from after a join, made on
+        the device: the resident batch's (B,) vectors with row
+        `dst_rows[j]` taking the cohort's row j (its first token and
+        `done`, as `serve_prefill` left them), so the cohort's tokens
+        need not reach the host before the segment is dispatched.  One
+        program a (batch, cohort width): the cohort's rows past
+        `dst_rows` are its pads and land nowhere."""
+        b, n = int(tok.shape[0]), int(cohort_tok.shape[0])
+        dst = np.full(n, b, np.int32)
+        dst[:len(dst_rows)] = dst_rows
+        with use_mesh(self.mesh):
+            out = _seat_rows_jit(jnp.asarray(tok), jnp.asarray(done),
+                                 cohort_tok, cohort_done, jnp.asarray(dst))
+        self._program("seat", b, n)
+        return out
 
     def serve_blocks(self, true_len, bucket: int, t_row, seg_len: int,
                      window: int) -> tuple:

@@ -39,9 +39,11 @@ Groups take TURNS inside a pass: harvest the group's segment in flight,
 cancel, join, dispatch its next segment — and the fetch of that segment
 waits for the group's next turn, so with two live groups the device
 always has the other group's program queued or running while the host
-works on this one (`_Flight`).  `_tick()` still ends with every segment
-it dispatched harvested; only the loop thread carries them from one pass
-into the next.
+works on this one (`_Flight`).  A whole-prompt join dispatches its
+prefill, merge and the group's segment back to back and fetches its first
+tokens after them (`_join`), so the device never waits for the splice's
+host work.  `_tick()` still ends with every segment it dispatched
+harvested; only the loop thread carries them from one pass into the next.
 """
 
 from __future__ import annotations
@@ -611,7 +613,7 @@ class ServingEngine:
         request's deadline, the first time a join lands late."""
         cap = self.cfg.max_batch
         seg = self.cfg.segment_steps
-        cohorts = {}
+        cohorts, firsts = {}, {}
         chunks = eng.serve_prefill_chunks(bucket)
         n = 1
         while True:
@@ -638,6 +640,7 @@ class ServingEngine:
                     dcaches = eng.serve_draft_prefill(self._draft_vars,
                                                       prompts)
             cohorts[m] = caches
+            firsts[m] = tok, done
             if n >= cap:
                 break
             n *= 2
@@ -645,6 +648,14 @@ class ServingEngine:
             # a prefill-tier engine never decodes or merges: the cohort
             # prefill programs above are its whole compiled surface
             return
+        if not eng.spec_tokens and not chunks:
+            # a queued join's segment starts from its cohort's first
+            # tokens on the device (`_join`): one program a cohort width
+            for m, (first, first_done) in firsts.items():
+                with self._warm_program("seat", bucket, m):
+                    eng.serve_seat(np.zeros(cap, np.int32),
+                                   np.ones(cap, bool), first, first_done,
+                                   list(range(m)))
         warmed_widths: set = set()
 
         def warm_joins(resident) -> None:
@@ -903,7 +914,8 @@ class ServingEngine:
         return own
 
     def _fetch(self, eng: DecodeEngine, *arrays, counts: list,
-               dispatched: float, flight: Optional[_Flight] = None) -> tuple:
+               dispatched: float, flight: Optional[_Flight] = None,
+               after: Optional[_Flight] = None) -> tuple:
         """Bring a program's results to the host: where the scheduler
         thread waits for the device.  The enclosing prefill span's self
         time is then dispatch and argument upload, and this span
@@ -913,14 +925,17 @@ class ServingEngine:
         in the same fetch and goes to the engine's counters.
 
         Returns (host arrays, the program's own seconds).  A segment
-        still in flight that was dispatched before this program
-        (`flight`: the segment being harvested; none: a program just
-        dispatched) ends before it: its end is stamped first, so each
-        program's time is its own and not the queue's before it."""
+        still in flight that was dispatched before this program ends
+        before it: its end is stamped first, so each program's time is
+        its own and not the queue's before it.  Those are the segments
+        in flight ahead of `flight` (the segment being harvested) or of
+        `after` (the first one dispatched after this program: a queued
+        join's, which the fetch does not wait for); with neither, every
+        segment in flight."""
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.fetch", cat="serve"):
             for f in self._flights:
-                if f is flight:
+                if f is flight or f is after:
                     break
                 if f.own_s is None:
                     jax.block_until_ready(f.done)
@@ -986,7 +1001,7 @@ class ServingEngine:
             for i in g.live_slots():
                 if g.rows[i] is req:
                     req.finish(CANCELLED, self.now(), detail)
-                    if not any(f.group is g for f in list(self._flights)):
+                    if not self._in_flight(g):
                         g.release(i)
                     # else the row state is the segment's in flight: its
                     # harvest frees the slot of a finished request
@@ -1188,10 +1203,15 @@ class ServingEngine:
         for job in [j for j in self._pending if j["group"] is g]:
             self._advance_prefill(job)
             worked = True
-        if g.live_slots():
+        # a queued join has dispatched the group's segment already
+        if g.live_slots() and not self._in_flight(g):
             self._advance(g, lane)
             worked = True
         return worked
+
+    def _in_flight(self, g: _Group) -> bool:
+        # list(): `cancel_request` asks from other threads
+        return any(f.group is g for f in list(self._flights))
 
     def _take(self, bucket: int, n: int, lane: str) -> list:
         """A turn's pull from the admission queue; the wait of each
@@ -1233,22 +1253,49 @@ class ServingEngine:
         return prompts, true_len, live, ids
 
     def _join(self, g: _Group, lane: str, reqs: list, slots: list) -> None:
-        """Prefill a join cohort and splice it into the resident batch."""
+        """Prefill a join cohort and splice it into the resident batch.
+
+        Queued: the merge and the group's next segment are dispatched
+        right behind the prefill, the segment starting from the cohort's
+        first tokens and `done` where the prefill left them on the device
+        (`serve_seat`), and the first tokens are fetched and emitted after
+        that, while the device works through the three.  Otherwise the
+        first tokens are fetched before the splice, and the segment waits
+        for the turn's end."""
         eng = self._engines[lane]
         variables = self._variables[lane]
         prompts, true_len, live, ids = self._cohort(g, reqs)
+        src = list(range(len(reqs)))
+        # queued unless the host needs the first tokens first: a
+        # speculative lane's round drafts from them, a prefill-tier
+        # engine exports them
+        queued = not (eng.spec_tokens or self.role == "prefill")
         t0 = monotonic()
         with span_on_tracer(self._tracer, "serve.prefill", cat="serve",
                             bucket=g.bucket, cohort=len(ids),
                             joins=len(reqs), lane=lane):
             tok, done, caches = eng.serve_prefill(
                 variables, prompts, true_len, live, self._row_keys(ids))
-            [tok_h], own = self._fetch(
-                eng, tok, counts=self._take_counts(eng), dispatched=t0)
-        self._count("prefill_s", monotonic() - t0)
+            counts = self._take_counts(eng)
+            if not queued:
+                [tok_h], own = self._fetch(eng, tok, counts=counts,
+                                           dispatched=t0)
+        if not queued:
+            self._count("prefill_s", monotonic() - t0)
+            self.estimator.observe_prefill(g.bucket, own)
+            self._splice(g, lane, reqs, slots, src, tok_h, caches, prompts)
+            return
+        dispatch_s = monotonic() - t0
+        self._splice(g, lane, reqs, slots, src, None, caches, prompts)
+        self._advance(g, lane, (tok, done, slots))
+        t1 = monotonic()
+        [tok_h], own = self._fetch(eng, tok, counts=counts, dispatched=t0,
+                                   after=self._flights[-1])
+        self._count("prefill_s", dispatch_s + monotonic() - t1)
         self.estimator.observe_prefill(g.bucket, own)
-        self._splice(g, lane, reqs, slots, list(range(len(reqs))),
-                     tok_h, caches, prompts)
+        with span_on_tracer(self._tracer, "serve.emit", cat="serve",
+                            bucket=g.bucket, rows=len(reqs)):
+            self._welcome(g, lane, reqs, slots, src, tok_h, caches)
 
     def _join_prefix_hits(self, g: _Group, lane: str, reqs: list,
                           slots: list) -> tuple:
@@ -1438,7 +1485,11 @@ class ServingEngine:
         finished cohort's caches go to the handoff bus instead of a
         resident slot, and each engine request ends `handoff` — the
         router's fleet request stays open until a decode replica splices
-        the shipped rows and finishes the decode attempt."""
+        the shipped rows and finishes the decode attempt.
+
+        `tok_h` None: a queued join (`_join`), whose first tokens are
+        still on the device; the rows are seated without them, and
+        `_welcome` emits them once fetched."""
         with span_on_tracer(self._tracer, "serve.splice", cat="serve",
                             bucket=g.bucket, lane=lane, joins=len(reqs)):
             eng = self._engines[lane]
@@ -1467,29 +1518,45 @@ class ServingEngine:
                     g.draft_caches, dc, slots, src, mesh=eng.mesh)
             for j, (req, slot) in zip(src, zip(reqs, slots)):
                 g.rows[slot] = req
-                g.tok[slot] = tok_h[j]
+                if tok_h is not None:
+                    g.tok[slot] = tok_h[j]
                 g.true_len[slot] = req.true_len
                 g.budget[slot] = req.max_new_tokens
                 g.t_row[slot] = 0
                 g.row_ids[slot] = req.id
                 g.done[slot] = False
-                trace_event("serve.join", cat="serve", request=req.id,
-                            bucket=g.bucket, slot=slot, lane=lane,
-                            **self._trace_fields(req))
-                self._record_serve({"event": "join", "request": req.id,
-                                    "bucket": g.bucket, "slot": slot,
-                                    "lane": lane, **self._trace_fields(req)})
-                # attempt-level TTFT: arrival at THIS engine to its first
-                # emitted token (the fleet-level TTFT, arrival at the
-                # router to the decode-tier splice, lands in handoff.py)
-                ttft = self.now() - req.arrival
-                self._ttfts.append(ttft)
-                if self._run is not None:
-                    self._run.observe_hist("serve.ttft_s", ttft)
-                self._emit(g, slot, [int(tok_h[j])])
-            if self._prefix is not None and lane == "primary":
-                self._insert_prefix_rows(reqs, src, caches)
-                self._gauge_prefix()
+            self._count_all({"join_cohorts": 1,
+                             "join_cohorts_queued": int(tok_h is None)})
+            if tok_h is not None:
+                self._welcome(g, lane, reqs, slots, src, tok_h, caches)
+
+    def _welcome(self, g: _Group, lane: str, reqs: list, slots: list,
+                 src: list, tok_h, caches) -> None:
+        """A spliced cohort's first tokens reach its rows (`src[i]` the
+        cohort row of `reqs[i]`, seated at `slots[i]`), and its prompts
+        go to the prefix pool."""
+        for j, (req, slot) in zip(src, zip(reqs, slots)):
+            if req.finished:
+                # withdrawn between a queued join's dispatch and this
+                # fetch: the segment's harvest frees the slot
+                continue
+            trace_event("serve.join", cat="serve", request=req.id,
+                        bucket=g.bucket, slot=slot, lane=lane,
+                        **self._trace_fields(req))
+            self._record_serve({"event": "join", "request": req.id,
+                                "bucket": g.bucket, "slot": slot,
+                                "lane": lane, **self._trace_fields(req)})
+            # attempt-level TTFT: arrival at THIS engine to its first
+            # emitted token (the fleet-level TTFT, arrival at the
+            # router to the decode-tier splice, lands in handoff.py)
+            ttft = self.now() - req.arrival
+            self._ttfts.append(ttft)
+            if self._run is not None:
+                self._run.observe_hist("serve.ttft_s", ttft)
+            self._emit(g, slot, [int(tok_h[j])])
+        if self._prefix is not None and lane == "primary":
+            self._insert_prefix_rows(reqs, src, caches)
+            self._gauge_prefix()
 
     def _insert_prefix_rows(self, reqs: list, src: list, caches) -> None:
         """Pool each freshly spliced request's prompt-prefix slots: the
@@ -1580,7 +1647,9 @@ class ServingEngine:
 
     def _emit(self, g: _Group, slot: int, tokens: list) -> None:
         """Append emitted tokens to a row's request, honoring its budget
-        and stop tokens; completes (and frees) the row when finished."""
+        and stop tokens; completes the request when finished, and frees
+        its row unless the group's segment is in flight (a queued join's
+        first token: the harvest frees it)."""
         req = g.rows[slot]
         stopped = False
         appended = False
@@ -1594,16 +1663,19 @@ class ServingEngine:
                 break
         if stopped or len(req.tokens) >= req.max_new_tokens:
             self._complete(req, OK)
-            g.release(slot)
+            if not self._in_flight(g):
+                g.release(slot)
         elif appended:
             # segment-boundary flush point: wake any streaming reader
             # (finish() notifies on its own for the completed case)
             req.note_tokens()
 
-    def _advance(self, g: _Group, lane: str) -> None:
+    def _advance(self, g: _Group, lane: str, joined=None) -> None:
         """Dispatch one mixed-age segment for a group, its harvest left
         to the group's next turn (`_harvest`) — or, on speculative lanes,
-        run one whole draft-verify round."""
+        run one whole draft-verify round.  `joined`: a queued join's
+        (first tokens, `done`, slots), still on the device, which the
+        segment starts from in those rows (`serve_seat`)."""
         if self._engines[lane].spec_tokens:
             self._advance_spec(g, lane)
             return
@@ -1625,10 +1697,13 @@ class ServingEngine:
                             bucket=g.bucket, lane=lane, seg_len=seg,
                             window=window, occupancy=round(
                                 len(live) / g.capacity, 3)):
+            if joined is None:
+                tok, done = np.asarray(g.tok), np.asarray(g.done)
+            else:
+                tok, done = eng.serve_seat(g.tok, g.done, *joined)
             g.caches, toks, tok, done = eng.serve_step(
-                variables, g.caches, np.asarray(g.tok),
-                np.asarray(g.done), g.true_len, g.budget, g.bucket,
-                g.t_row, self._group_keys(g), seg, window)
+                variables, g.caches, tok, done, g.true_len, g.budget,
+                g.bucket, g.t_row, self._group_keys(g), seg, window)
         self._flights.append(_Flight(
             g, lane, toks, tok, done, self._take_counts(eng), live, read,
             t0))

@@ -274,7 +274,8 @@ def test_a_warmed_engines_stats_and_events(bundle):
     # numbers only: the table by jitted function is `/statz`'s
     assert all(not isinstance(v, list) for v in stats.values())
     assert stats["warmup_programs"] == len(programs) > 0
-    assert {e["kind"] for e in programs} == {"prefill", "merge", "segment"}
+    assert {e["kind"] for e in programs} == {"prefill", "merge", "seat",
+                                             "segment"}
     assert all(e["bucket"] == 8 and e["seconds"] >= 0.0 for e in programs)
     # one clock: the event's seconds are the gauge's, the phase's own
     assert done["seconds"] == round(stats["warmup_s"], 3) > 0.0
@@ -300,9 +301,10 @@ def test_a_second_engine_takes_its_merges_from_memory(bundle):
     rose = {r["fun_name"]: r["programs"]
             for r in compiles.by_function(since=mark)}
     # an engine's prefill and segment are `jax.jit`s of its own closures,
-    # so a second engine traces and compiles them again; the merge is a
-    # module's function and comes from jit's in-memory cache
+    # so a second engine traces and compiles them again; the merge and
+    # the seat are a module's functions and come from jit's in-memory cache
     assert "jit(_merge_cache_rows_jit)" not in rose
+    assert "jit(_seat_rows_jit)" not in rose
     assert rose["jit(prefill_meshed)"] >= 2
     assert second.stats()["compile_programs"] \
         == compiles.totals()["programs"]

@@ -19,6 +19,7 @@ from mmlspark_tpu.models.generate import DecodeEngine
 from mmlspark_tpu.resilience.clock import VirtualClock
 from mmlspark_tpu.serve import ServeConfig, ServingEngine
 from mmlspark_tpu.serve import engine as engine_mod
+from mmlspark_tpu.zoo import truncated_draft_bundle
 
 LM = {"vocab_size": 64, "d_model": 32, "n_heads": 4, "n_layers": 2,
       "max_len": 64}
@@ -157,6 +158,12 @@ class Recorder:
         [tok] = self._handles(("prefill", prompts.shape[1]),
                               self.prefill_s, tok)
         return tok, done, caches
+
+    def serve_seat(self, tok, done, cohort_tok, cohort_done, dst_rows):
+        # a queued join's first tokens stay on the device: no fetch
+        self.device.run(("seat", len(dst_rows)), 0.0)
+        return self.eng.serve_seat(tok, done, cohort_tok.value, cohort_done,
+                                   dst_rows)
 
     def serve_step(self, variables, caches, tok, done, true_len, budget,
                    bucket, *args):
@@ -464,3 +471,188 @@ def test_the_loop_thread_serves_while_other_threads_submit_and_cancel(
             assert req.tokens == want[:len(req.tokens)]
     stats = engine.stats()
     assert stats["ok"] >= 16 and stats["segments_overlapped"] > 0
+
+
+# -- (f) a join turn queues its merge and segment behind its prefill ---------
+
+def _merges_on(device, monkeypatch):
+    """Log each merge's dispatch on the device's queue."""
+    merge = DecodeEngine.merge_cache_rows
+
+    def logged(dst, src, dst_rows, *args, **kw):
+        device.run(("merge", len(dst_rows)), 0.0)
+        return merge(dst, src, dst_rows, *args, **kw)
+    monkeypatch.setattr(DecodeEngine, "merge_cache_rows",
+                        staticmethod(logged))
+
+
+def _joined(stats) -> tuple:
+    return stats["join_cohorts"], stats["join_cohorts_queued"]
+
+
+def test_a_join_turn_dispatches_merge_and_segment_before_its_fetch(
+        lm, oracle, monkeypatch):
+    engine = make_engine(lm)
+    device = on_device(engine, monkeypatch)
+    _merges_on(device, monkeypatch)
+    reqs = [engine.submit(p, (3, NEW, NEW)[i])
+            for i, p in enumerate(prompts(6)[::2])]   # one bucket, 2 slots
+    carried(engine)
+    turn = [("dispatch", ("prefill", SHORT)), ("dispatch", ("merge", 2)),
+            ("dispatch", ("seat", 2)), ("dispatch", ("segment", SHORT)),
+            ("fetch", ("prefill", SHORT))]
+    assert device.log == turn
+    assert [len(r.tokens) for r in reqs] == [1, 1, 0]
+    # the first request spends its budget in that segment: the next turn
+    # harvests, then the third joins its slot the same way
+    del device.log[:]
+    carried(engine)
+    assert device.log == [("fetch", ("segment", SHORT))] * 3 + [
+        ("dispatch", ("prefill", SHORT)), ("dispatch", ("merge", 1)),
+        ("dispatch", ("seat", 1)), ("dispatch", ("segment", SHORT)),
+        ("fetch", ("prefill", SHORT))]
+    assert reqs[0].status == "ok" and len(reqs[2].tokens) == 1
+    serve_all(engine, reqs, carried)
+    assert [r.tokens for r in reqs] == [
+        oracle(r.prompt)[:r.max_new_tokens] for r in reqs]
+    assert _joined(engine.stats()) == (2, 2)
+
+
+@pytest.mark.parametrize("passes", ["ticked", "carried"])
+def test_greedy_streams_with_many_late_joins_equal_the_offline_tokens(
+        lm, oracle, passes):
+    engine = make_engine(lm)
+    reqs = [engine.submit(p, (2, 5, NEW, 3)[i % 4])
+            for i, p in enumerate(prompts(14, seed=5))]
+    serve_all(engine, reqs,
+              carried if passes == "carried" else lambda e: e._tick())
+    engine._tick()
+    for r in reqs:
+        assert r.status == "ok"
+        assert r.tokens == oracle(r.prompt)[:r.max_new_tokens]
+    cohorts, queued = _joined(engine.stats())
+    assert cohorts == queued >= 7       # every slot refilled at least once
+    assert not engine._flights and engine.in_flight() == 0
+
+
+def test_the_prefill_fetch_waits_for_what_is_ahead_of_it_only(
+        lm, monkeypatch):
+    engine = make_engine(lm)
+    device = on_device(engine, monkeypatch, segment_s=2.0, prefill_s=0.5)
+    prefills = []
+    monkeypatch.setattr(engine.estimator, "observe_prefill",
+                        lambda b, s: prefills.append((b, s)))
+    fetched_at = []
+    fetch = engine._fetch
+
+    def timed(*args, **kw):
+        out = fetch(*args, **kw)
+        fetched_at.append(device.now)
+        return out
+    engine._fetch = timed
+    [engine.submit(p, NEW) for p in prompts(4)]       # 2 rows a group
+    carried(engine)
+    short, long_ = engine._flights
+    # the short group's prefill ends at 0.5 and its segment at 2.5: its
+    # fetch came back at 0.5.  The long group's prefill queues behind
+    # that segment (2.5 -> 3.0): its fetch stamped the segment first,
+    # and did not wait for its own group's segment, queued after it
+    assert fetched_at == [0.5, 3.0] and device.free == 5.0
+    assert short.own_s == 2.0 and long_.own_s is None
+    assert prefills == [(SHORT, 0.5), (LONG, 0.5)]
+    assert engine._device_free == 3.0
+
+
+def test_a_row_that_ends_at_its_first_token_runs_one_frozen_segment(
+        lm, oracle, monkeypatch):
+    ps = prompts(6)[::2]
+    stop = oracle(ps[1])[0]
+    engine = make_engine(lm, stop_tokens=(stop,))
+    g_rows = lambda: engine._groups[(SHORT, "primary")].rows
+    one = engine.submit(ps[0], 1)               # its budget is one token
+    ends = engine.submit(ps[1], NEW)            # its first token stops it
+    waiting = engine.submit(ps[2], NEW)
+    carried(engine)
+    # both finish at the fetch, their rows stay seated for the segment
+    # in flight, and the harvest frees them for the request that waits
+    assert (one.status, ends.status) == ("ok", "ok")
+    assert one.tokens == oracle(ps[0])[:1] and ends.tokens == [stop]
+    assert g_rows() == [one, ends] and not waiting.tokens
+    carried(engine)
+    assert g_rows()[0] is waiting and g_rows()[1] is None
+    serve_all(engine, [waiting], carried)
+    want = oracle(ps[2])
+    cut = want.index(stop) + 1 if stop in want else len(want)
+    assert waiting.tokens == want[:cut]
+
+
+def test_a_cancel_between_the_dispatch_and_the_fetch(lm, oracle,
+                                                     monkeypatch):
+    engine = make_engine(lm)
+    ps = prompts(6)[::2]
+    victim, kept, waiting = (engine.submit(p, NEW) for p in ps)
+    fetch = engine._fetch
+
+    def cancelling(*args, after=None, **kw):
+        if after is not None:                   # a queued join's prefill
+            engine.cancel_request(victim)
+        return fetch(*args, after=after, **kw)
+    monkeypatch.setattr(engine, "_fetch", cancelling)
+    carried(engine)
+    g = engine._groups[(SHORT, "primary")]
+    assert victim.status == "cancelled" and victim.tokens == []
+    assert g.rows[0] is victim and len(kept.tokens) == 1
+    monkeypatch.setattr(engine, "_fetch", fetch)
+    carried(engine)                             # the harvest frees it
+    assert g.rows[0] is waiting
+    serve_all(engine, [kept, waiting], carried)
+    assert kept.tokens == oracle(kept.prompt)
+    assert waiting.tokens == oracle(waiting.prompt)
+    assert engine.stats()["cancelled_external"] == 1
+
+
+def test_joins_after_the_warmup_compile_nothing(lm):
+    engine = make_engine(lm, warmup_buckets=(SHORT, LONG),
+                         warmup_joins=True)
+    before = engine.stats()
+    reqs = [engine.submit(p, (3, NEW, 5)[i % 3])
+            for i, p in enumerate(prompts(12, seed=9))]
+    serve_all(engine, reqs, carried)
+    engine._tick()
+    after = engine.stats()
+    assert after["join_cohorts_queued"] >= 6
+    assert after["compile_programs"] == before["compile_programs"]
+    assert after["compiles_after_ready"] == 0
+
+
+def _old_order_engine(lm, kind):
+    if kind == "speculative":
+        return ServingEngine(lm, ServeConfig(
+            max_new_tokens=NEW, max_batch=2, queue_capacity=8,
+            segment_steps=SEG, default_deadline_s=100.0, cache_chunk=16,
+            spec_tokens=2), draft_bundle=truncated_draft_bundle(
+                lm, n_layers=1), clock=VirtualClock()).warmup()
+    if kind == "chunked":       # bucket 16 in chunks of 8; bucket 8 whole
+        return make_engine(lm, prefill_chunk=8)
+    return make_engine(lm, prefix_cache=True, prefix_max_rows=8)
+
+
+@pytest.mark.parametrize("kind", ["chunked", "prefix_hit", "speculative"])
+def test_joins_that_keep_the_old_order_count_as_not_queued(lm, oracle,
+                                                           kind):
+    engine = _old_order_engine(lm, kind)
+    if kind == "prefix_hit":
+        donor = np.arange(1, 21, dtype=np.int32)
+        first = engine.submit(donor, NEW)
+        serve_all(engine, [first], carried)
+        reqs = [first, engine.submit(donor.copy(), NEW)]
+        serve_all(engine, reqs, carried)
+        assert engine.stats()["prefix_hits"] == 1
+        want = (2, 1)           # the donor's whole-prompt join is queued
+    else:
+        reqs = [engine.submit(p, NEW) for p in prompts(4)]
+        serve_all(engine, reqs, carried)
+        # the chunked engine's bucket-8 cohort joins whole, and queued
+        want = {"chunked": (2, 1), "speculative": (2, 0)}[kind]
+    assert _joined(engine.stats()) == want
+    assert [r.tokens for r in reqs] == [oracle(r.prompt) for r in reqs]
